@@ -42,6 +42,10 @@ class ViTEmbedder:
         batch_bucket: int = 128,
         use_flash_attention: Optional[bool] = None,
     ) -> None:
+        # the owning replica's chip lease (``bioengine_device_ids``),
+        # set by the deployment before the first load; None = every
+        # local device (stand-alone use)
+        self.device_ids: Optional[list[int]] = None
         self.weights_path = weights_path
         self.batch_bucket = batch_bucket
         self.use_flash_attention = use_flash_attention
@@ -104,12 +108,20 @@ class ViTEmbedder:
                 "(pipeline-shape mode, embeddings are not DINOv2)"
             )
 
-        n_dev = jax.local_device_count()
+        # the mesh is built from the replica's lease, never from
+        # jax.devices()[:dp]: two replicas on one host would otherwise
+        # share the first chips while the controller books them apart
+        if self.device_ids:
+            from bioengine_tpu.runtime.engine import resolve_devices
+
+            devices = resolve_devices(self.device_ids)
+        else:
+            devices = jax.local_devices()
         # dp over the largest power of two that divides the bucket
         dp = 1
-        while dp * 2 <= n_dev and self.batch_bucket % (dp * 2) == 0:
+        while dp * 2 <= len(devices) and self.batch_bucket % (dp * 2) == 0:
             dp *= 2
-        mesh = make_mesh({"dp": dp}, jax.devices()[:dp])
+        mesh = make_mesh({"dp": dp}, devices[:dp])
         repl = NamedSharding(mesh, P())
         data_sh = NamedSharding(mesh, P("dp"))
         params = jax.device_put(params, repl)

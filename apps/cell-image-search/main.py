@@ -53,6 +53,8 @@ class CellImageSearch:
     # ---- lifecycle hooks --------------------------------------------------
 
     async def async_init(self):
+        # the replica lifecycle injects the chip lease before async_init
+        self.embedder.device_ids = getattr(self, "bioengine_device_ids", None)
         await self._try_load_index()
 
     async def test_deployment(self):

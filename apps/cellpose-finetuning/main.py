@@ -487,13 +487,22 @@ class CellposeFinetune:
             )
         tile = (tile // divisor) * divisor
 
-        # dp over every local chip that divides the batch
-        n_dev = jax.local_device_count()
+        # dp over every LEASED chip that divides the batch — the mesh
+        # is built from the replica's lease, never jax.devices()[:dp]
+        # (two replicas on one host would share the first chips while
+        # the controller books them apart); no lease = stand-alone use
+        lease = getattr(self, "bioengine_device_ids", None)
+        if lease:
+            from bioengine_tpu.runtime.engine import resolve_devices
+
+            devices = resolve_devices(list(lease))
+        else:
+            devices = jax.local_devices()
         batch = cfg["batch_size"]
         dp = 1
-        while dp * 2 <= n_dev and batch % (dp * 2) == 0:
+        while dp * 2 <= len(devices) and batch % (dp * 2) == 0:
             dp *= 2
-        mesh = make_mesh({"dp": dp}, jax.devices()[:dp])
+        mesh = make_mesh({"dp": dp}, devices[:dp])
 
         rng = np.random.default_rng(cfg["seed"])
         start_epoch = 0

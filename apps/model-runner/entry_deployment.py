@@ -425,8 +425,13 @@ class EntryDeployment:
         models = await self.model_cache.source.list_models()
         assert isinstance(models, list)
 
-    async def check_health(self):
-        await self._check_runtime_available()
+    # No check_health: this deployment's health is its own liveness.
+    # Probing the runtime from here rides the runtime replica's bounded
+    # request queue, so the probe times out whenever the runtime is busy
+    # with a first compile (tens of seconds on a TPU) — the controller
+    # then restarts THIS replica, marks the app UNHEALTHY and the worker
+    # drops the public service mid-traffic (seen on the chip, ISSUE 21).
+    # The controller health-checks the runtime replica itself.
 
     async def _check_runtime_available(self):
         status = await asyncio.wait_for(

@@ -14,8 +14,10 @@ an XLA design:
   * ``jax_params``  — TPU-native extension: an .npz pytree + a registry
     architecture name; runs jitted on the MXU in bf16/f32.
   * ``pytorch_state_dict`` — the RDF's architecture source is executed
-    with torch (CPU/torch-xla) and the state dict loaded into it.
-  * ``torchscript`` — host torch fallback behind the same interface.
+    with torch on the HOST CPU and the state dict loaded into it.
+  * ``torchscript`` — the same host-CPU torch fallback.
+  Neither torch format touches the replica's chip; building such a
+  pipeline logs one warning that says so.
 - Test reports are cached next to the package keyed on weight mtimes
   (ref runtime_deployment.py:345-364 ``.test_cache.json``).
 - XLA RESOURCE_EXHAUSTED is normalized to RuntimeError the way the
@@ -25,6 +27,7 @@ an XLA design:
 import asyncio
 import hashlib
 import json
+import logging
 import time
 from collections import OrderedDict
 from pathlib import Path
@@ -186,6 +189,15 @@ class Pipeline:
 
         from bioengine_tpu.runtime.torch_fallback import TorchFallbackRunner
 
+        if self.weights_format in ("torchscript", "pytorch_state_dict"):
+            logging.getLogger(__name__).warning(
+                "model '%s' has no jax_params weights: its '%s' weights "
+                "run with torch on the host CPU, not on the replica's "
+                "leased chip(s) %s",
+                self._model_key(),
+                self.weights_format,
+                [d.id for d in self.devices] if self.devices else [],
+            )
         if self.weights_format == "torchscript":
             runner = TorchFallbackRunner(
                 torchscript_path=str(self._resolve(entry["source"]))

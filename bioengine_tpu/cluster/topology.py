@@ -61,10 +61,15 @@ class TpuTopology:
 
 
 def detect_topology() -> TpuTopology:
-    """Enumerate the visible accelerator topology via JAX."""
+    """Enumerate the visible accelerator topology via JAX. A CPU
+    topology is returned only when CPU was asked for by name
+    (utils/devices.py); a silent fallback raises NoAcceleratorError."""
     import jax
 
+    from bioengine_tpu.utils.devices import require_accelerator
+
     devices = jax.devices()
+    require_accelerator(devices[0].platform, "detect_topology")
     chips = []
     for d in devices:
         hbm = used = None

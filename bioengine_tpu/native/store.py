@@ -7,15 +7,19 @@ bytes. Pins (refcounts) taken at get-time keep the object from being
 LRU-evicted while a view is live — release views promptly or use the
 ``pinned`` context manager.
 
-The library auto-builds from source with ``make`` on first use (the
-worker image ships g++); a pure-Python in-process fallback with the
-same API keeps environments without a toolchain working (no sharing
+The library is brought up to date with ``make`` on first use in every
+process (incremental: a no-op when ``native/build`` already matches
+``object_store.cpp``), so a stale or foreign build directory is never
+loaded on trust. Without ``make`` or a compiler the binding is
+unavailable and says so once in the log; a pure-Python in-process
+fallback with the same API keeps such environments working (no sharing
 across processes there).
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import mmap
 import os
 import subprocess
@@ -27,6 +31,7 @@ from typing import Optional
 _NATIVE_DIR = Path(__file__).resolve().parent.parent.parent / "native"
 _LIB_PATH = _NATIVE_DIR / "build" / "libbioengine_store.so"
 _build_lock = threading.Lock()
+logger = logging.getLogger(__name__)
 
 
 class BesStats(ctypes.Structure):
@@ -42,7 +47,8 @@ class BesStats(ctypes.Structure):
 
 
 def _ensure_lib() -> Optional[ctypes.CDLL]:
-    """Build (once) and load the native library; None if unavailable.
+    """Bring the native library up to date with ``make`` and load it;
+    None (with one warning) when it cannot be built or loaded.
 
     ``BIOENGINE_STORE_LIB`` overrides the library path without
     triggering a build — how the CI sanitizer job (and the slow test in
@@ -57,19 +63,20 @@ def _ensure_lib() -> Optional[ctypes.CDLL]:
             # run go green while exercising zero native code
             lib = ctypes.CDLL(override)
             return _bind_abi(lib)
-        if not _LIB_PATH.exists():
-            if not (_NATIVE_DIR / "Makefile").exists():
-                return None
-            try:
-                subprocess.run(
-                    ["make"], cwd=_NATIVE_DIR, check=True,
-                    capture_output=True, timeout=120,
-                )
-            except Exception:
-                return None
         try:
+            subprocess.run(
+                ["make"], cwd=_NATIVE_DIR, check=True,
+                capture_output=True, text=True, timeout=120,
+            )
             lib = ctypes.CDLL(str(_LIB_PATH))
-        except OSError:
+        except (OSError, subprocess.SubprocessError) as e:
+            detail = (getattr(e, "stderr", None) or "").strip()[-500:]
+            logger.warning(
+                "native object store unavailable — `make -C %s` or the "
+                "load failed (%s%s); the RPC shm fast path and "
+                "SharedObjectStore are off",
+                _NATIVE_DIR, e, f": {detail}" if detail else "",
+            )
             return None
     return _bind_abi(lib)
 

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from bioengine_tpu.parallel.mesh import get_shard_map
-
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def topk_inner_product(
@@ -85,10 +83,8 @@ class ShardedKnnIndex:
         shard_n = self.corpus.shape[0] // n_shards
         k_local = min(k, shard_n)
 
-        shard_map = get_shard_map()
-
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=self.mesh,
             in_specs=(P(self.axis, None), P()),
             out_specs=(P(self.axis), P(self.axis)),

@@ -16,8 +16,9 @@ last kv step. Sequence padding (to the block size) and the causal
 option are handled with ``broadcasted_iota`` masks; fully-masked
 causal blocks skip their matmuls via ``pl.when``.
 
-On non-TPU backends (hermetic CPU tests) the kernel runs in
-interpreter mode automatically.
+The kernel runs in interpreter mode only where the CPU platform was
+asked for by name (the hermetic tests); any other non-TPU backend is an
+error, not a quiet interpreter run.
 """
 
 from __future__ import annotations
@@ -29,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from bioengine_tpu.utils.devices import require_accelerator
 
 NEG_INF = -1e30
 
@@ -195,7 +198,9 @@ def flash_attention(
     VJP (XLA-recompute backward).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        backend = jax.default_backend()
+        require_accelerator(backend, "flash_attention")
+        interpret = backend == "cpu"
     return _flash_attention(q, k, v, causal, block_q, block_k, interpret)
 
 

@@ -116,26 +116,3 @@ def local_device_mesh(n: int = 1, axis: str = "dp") -> Mesh:
     """A mesh over the first ``n`` local devices (single-replica case)."""
     return make_mesh({axis: n}, jax.devices()[:n])
 
-
-def get_shard_map():
-    """The ``shard_map`` entry point across jax versions.
-
-    jax >= 0.8 promotes it to the top level; older versions keep it in
-    ``jax.experimental``. One shim so callers don't each carry the
-    ladder (sibling of ``named_axis_size`` below)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as fn
-    return fn
-
-
-def named_axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis from inside ``shard_map``.
-
-    ``jax.lax.axis_size`` where it exists; on older jax a ``psum`` of
-    the literal 1 over the axis, which the tracer folds to a plain int
-    (usable in Python loops building ppermute rings)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)

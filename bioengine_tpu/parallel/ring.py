@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from bioengine_tpu.parallel.mesh import get_shard_map, named_axis_size
-
 
 def _block_attn(q, k, v, m_prev, l_prev, o_prev, scale):
     """One streaming-softmax update. q/k/v: (B, H, Nq, d)/(B, H, Nk, d)."""
@@ -50,7 +48,7 @@ def ring_attention(
     blocks. Returns (B, H, N_local, d). Non-causal (bidirectional —
     images/embedding workloads); a causal variant can mask per-step.
     """
-    n = named_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     scale = q.shape[-1] ** -0.5
     B, H, Nq, d = q.shape
     m0 = jnp.full((B, H, Nq), -jnp.inf, jnp.float32)
@@ -58,12 +56,7 @@ def ring_attention(
     o0 = jnp.zeros((B, H, Nq, d), jnp.float32)
     # Accumulators must carry the same device-varying type as the loop
     # body's outputs (which derive from the sp-sharded q/k/v blocks).
-    # jax >= 0.8 renames pvary -> pcast(..., to='varying').
-    if hasattr(jax.lax, "pcast"):
-        m0, l0, o0 = jax.lax.pcast((m0, l0, o0), axis_name, to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        m0, l0, o0 = jax.lax.pvary((m0, l0, o0), axis_name)
-    # jax < 0.5 has neither: accumulators are implicitly device-varying
+    m0, l0, o0 = jax.lax.pcast((m0, l0, o0), axis_name, to="varying")
 
     qf = q.astype(jnp.float32)
 
@@ -105,12 +98,10 @@ def make_ring_attention(mesh: Mesh, axis: str = "sp"):
     Drop-in for ``bioengine_tpu.models.vit.Attention(attn_fn=...)`` when
     a replica owns a multi-chip sub-mesh and sequences exceed one chip.
     """
-    shard_map = get_shard_map()
-
     spec = P(None, None, axis, None)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,

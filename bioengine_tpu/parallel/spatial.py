@@ -24,8 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from bioengine_tpu.parallel.mesh import get_shard_map, named_axis_size
-
 
 def halo_exchange(x: jax.Array, halo: int, axis_name: str) -> jax.Array:
     """Pad a block sharded on array axis 1 with ``halo`` slices from
@@ -39,7 +37,7 @@ def halo_exchange(x: jax.Array, halo: int, axis_name: str) -> jax.Array:
     if halo == 0:
         return x
     idx = jax.lax.axis_index(axis_name)
-    n = named_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     top_rows = x[:, :halo]          # my first rows -> neighbour below...
     bot_rows = x[:, -halo:]         # my last rows -> neighbour above
     # Send my bottom rows DOWN the ring (shard i -> i+1) so each shard
@@ -84,13 +82,11 @@ def spatial_shard_apply(
     ``halo`` must not exceed the local shard extent (global size /
     n_shards): ppermute reaches immediate ring neighbours only.
     """
-    shard_map = get_shard_map()
-
     spec = _axis1_spec(axis, rank)
     n_shards = mesh.shape[axis]
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), spec),
         out_specs=spec,

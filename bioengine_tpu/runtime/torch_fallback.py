@@ -2,9 +2,10 @@
 
 SURVEY.md §7 "Hard parts": weight conversion for *arbitrary* zoo
 architectures can't be guaranteed; the pragmatic fallback keeps those
-models runnable behind the same engine interface. On a TPU VM this path
-can route through torch-xla when present; otherwise it executes on the
-host CPU (torch in this image is CPU-only) — correct, just not fast.
+models runnable behind the same engine interface. It executes on the
+HOST CPU (torch here is CPU-only) while the replica holds a chip lease
+it does not use — correct, just not accelerated, and the caller says so
+loudly when it picks this path (model-runner ``Pipeline``).
 """
 
 from __future__ import annotations
@@ -35,17 +36,6 @@ class TorchFallbackRunner:
                 raise ValueError("need a module or a torchscript path")
             module = torch.jit.load(torchscript_path, map_location="cpu")
         self.module = module.eval()
-        self.device = self._pick_device()
-        self.module.to(self.device)
-
-    def _pick_device(self):
-        torch = self._torch
-        try:
-            import torch_xla.core.xla_model as xm  # type: ignore
-
-            return xm.xla_device()
-        except ImportError:
-            return torch.device("cpu")
 
     def predict(self, images: np.ndarray) -> np.ndarray:
         """Channels-last in/out; handles (B, H, W, C) images and
@@ -57,7 +47,7 @@ class TorchFallbackRunner:
             to_cf, to_cl = (0, 3, 1, 2), (0, 2, 3, 1)
         x = torch.from_numpy(np.ascontiguousarray(images)).permute(*to_cf)
         with torch.no_grad():
-            y = self.module(x.to(self.device))
+            y = self.module(x)
         if isinstance(y, (list, tuple)):
             y = y[0]
         return y.detach().cpu().permute(*to_cl).numpy()

@@ -263,7 +263,7 @@ class Replica(ReplicaStateMixin):
         except Exception as e:
             self.last_error = "".join(traceback.format_exception(e))[-2000:]
             self.state = ReplicaState.UNHEALTHY
-            self._log(f"replica start failed: {e}")
+            self._log(f"replica start failed: {type(e).__name__}: {e}")
             flight.record(
                 "replica.error",
                 severity="error",
@@ -324,7 +324,8 @@ class Replica(ReplicaStateMixin):
             except Exception as e:
                 self.last_error = str(e)
                 self.state = ReplicaState.UNHEALTHY
-                self._log(f"user check_health failed: {e}")
+                # the type too: a timeout's str() is empty
+                self._log(f"user check_health failed: {type(e).__name__}: {e}")
         return self.state
 
     async def drain(self, timeout_s: Optional[float] = None) -> bool:

@@ -20,10 +20,14 @@ the controller's tier at join time and publish its own compiles back
 controller-side store). Only ``*-cache`` payload files ride the tier —
 ``*-atime`` bookkeeping files are local-only.
 
-One call, safe anywhere: failures (read-only FS, old jax) degrade to a
-warning, never an error — and the VERDICT is cached either way, so a
-host with a read-only filesystem logs once instead of retrying the
-mkdir on every call.
+Where the cache lives is decided from OUTSIDE the program: when
+``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and this
+module sets no directory at all; otherwise the cache sits at one fixed
+path inside the checkout (``<repo>/.cache/xla``, git-ignored). The path
+is part of what makes a cache reusable across runs, so it is never
+built from a temp name, a pid or a time. A cache that cannot be enabled
+raises — a worker that silently recompiles every program on every
+start is not a degraded mode worth hiding.
 """
 
 from __future__ import annotations
@@ -38,12 +42,8 @@ from bioengine_tpu.utils import metrics
 
 logger = logging.getLogger(__name__)
 
-_DEFAULT = "~/.cache/bioengine-tpu/xla"
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".cache" / "xla"
 _enabled_dir: str | None = None
-# failure verdict cache: once an attempt fails, every later call
-# returns None immediately instead of re-trying the mkdir/config (a
-# read-only FS would otherwise pay — and log — the attempt per call)
-_failed = False
 
 # the suffix jax gives entry payload files; its sibling "-atime" files
 # are local LRU bookkeeping and never ride the tier
@@ -67,65 +67,53 @@ TIER_PUBLISH_BYTES = metrics.counter(
 )
 
 
-def enable_persistent_compilation_cache(path: str | None = None) -> str | None:
-    """Point jax's persistent compilation cache at ``path`` (default
-    ``$BIOENGINE_COMPILE_CACHE`` or ``~/.cache/bioengine-tpu/xla``).
-    Idempotent; returns the cache dir, or None when disabled/failed.
-    Both verdicts are cached: a failed first attempt (read-only FS, old
-    jax) is logged ONCE and never retried.
-
-    Set ``BIOENGINE_COMPILE_CACHE=off`` to opt out entirely.
+def enable_persistent_compilation_cache(path: str | None = None) -> str:
+    """Turn on jax's persistent compilation cache and return its
+    directory. ``$JAX_COMPILATION_CACHE_DIR`` wins over ``path`` wins
+    over the in-checkout default; with the variable set this function
+    sets no directory (jax already read it). Idempotent: the first
+    call's directory is the process's directory. Raises ``OSError``
+    when the directory cannot be created.
     """
-    global _enabled_dir, _failed
-    env = os.environ.get("BIOENGINE_COMPILE_CACHE")
-    if env and env.lower() in ("off", "0", "false", "none"):
-        return None
+    global _enabled_dir
     if _enabled_dir is not None:
         return _enabled_dir
-    if _failed and path is None:
-        # the cached verdict covers the default/env directory; an
-        # EXPLICIT path is a different target and deserves its own
-        # attempt (e.g. a bench worker pointing at a writable tmpdir
-        # after the home-dir default failed read-only)
-        return None
-    target = Path(path or env or _DEFAULT).expanduser()
-    try:
-        target.mkdir(parents=True, exist_ok=True)
-        import jax
+    import jax
 
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    target = Path(env or path or DEFAULT_CACHE_DIR).expanduser()
+    target.mkdir(parents=True, exist_ok=True)
+    if not env:
         jax.config.update("jax_compilation_cache_dir", str(target))
-        # default min-compile-time (1 s) skips exactly the small jits a
-        # serving replica re-traces most; cache everything non-trivial
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-        # jax >=0.4.36 defaults to colocating XLA's GPU autotune cache
-        # under the compilation cache dir — and that PATH lands in the
-        # compile-cache key, so two hosts with different local dirs
-        # compute different keys for the same program and the shared
-        # tier can never hit. Disable the colocated GPU sub-caches
-        # (irrelevant on TPU/CPU) so keys are path-independent.
-        if hasattr(jax.config, "jax_persistent_cache_enable_xla_caches"):
-            jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
-        _enabled_dir = str(target)
-        logger.info("persistent XLA compilation cache at %s", target)
-        return _enabled_dir
-    except Exception as exc:  # noqa: BLE001 — never fail the caller
-        _failed = True
-        logger.warning(
-            "compilation cache unavailable (will not retry): %s", exc
-        )
-        return None
+        # jax latches "is the cache used" at the process's first
+        # compile; one that ran before this call latched "no"
+        from jax.experimental.compilation_cache import compilation_cache
+
+        compilation_cache.reset_cache()
+    # default min-compile-time (1 s) skips exactly the small jits a
+    # serving replica re-traces most; cache everything non-trivial
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    # jax colocates XLA's GPU autotune cache under the compilation
+    # cache dir by default — and that PATH lands in the compile-cache
+    # key, so two hosts with different local dirs compute different
+    # keys for the same program and the shared tier can never hit.
+    # Disable the colocated GPU sub-caches (irrelevant on TPU/CPU) so
+    # keys are path-independent.
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
+    _enabled_dir = str(target)
+    logger.info("persistent XLA compilation cache at %s", target)
+    return _enabled_dir
 
 
 def enabled_dir() -> Optional[str]:
-    """The active cache dir, or None when disabled/failed/not enabled."""
+    """The active cache dir, or None before the cache is enabled."""
     return _enabled_dir
 
 
 def reset_for_tests() -> None:
-    """Drop the cached verdict so a test can exercise both paths."""
-    global _enabled_dir, _failed
+    """Forget the enabled directory so a test can enable another."""
+    global _enabled_dir
     _enabled_dir = None
-    _failed = False
 
 
 # ---- tier entry I/O (file-level; the RPC side lives in worker_host /

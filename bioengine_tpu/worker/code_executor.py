@@ -190,6 +190,30 @@ async def run_payload_subprocess(
     }
 
 
+class ChipHeldError(RuntimeError):
+    """A subprocess was asked to run on chips its parent process holds."""
+
+
+def require_spawnable_chips(platform: str) -> None:
+    """Refuse to start a chip subprocess from a process that holds the
+    chips itself. ``platform`` is the platform of the topology the
+    calling process detected through JAX. An accelerator belongs to one
+    process at a time, and a process that has enumerated its chips
+    holds all of them: on a TPU v5e the child then dies at backend
+    init with "ABORTED: Internal error when accessing libtpu
+    multi-process lockfile" (measured, ISSUE 21). The CPU platform has
+    no such owner, so drills and tests on it pass through."""
+    if platform != "cpu":
+        raise ChipHeldError(
+            f"run_code with num_chips cannot start a subprocess on "
+            f"{platform} chips from this process: it holds every chip "
+            "it enumerated through JAX, and an accelerator belongs to "
+            "one process at a time (the child would fail in libtpu at "
+            "backend init). Run chip code inside a deployment, or on a "
+            "host whose chips no serving process holds."
+        )
+
+
 def chip_env(device_ids: list[int]) -> dict[str, str]:
     """Env restricting a subprocess to its leased chips (the TPU analog
     of Ray's per-task GPU assignment, ref code_executor.py:469-476)."""
@@ -300,6 +324,7 @@ class CodeExecutor:
 
         # Local placement when this host has the chips free.
         if self.cluster_state.free_chips() >= num_chips:
+            require_spawnable_chips(self.cluster_state.topology.platform)
             device_ids = self.cluster_state.acquire_chips(lease_id, num_chips)
             try:
                 env = {

@@ -868,9 +868,12 @@ class WorkerHost:
         ``visibility: protected`` so only admin callers reach it."""
         from bioengine_tpu.worker.code_executor import (
             chip_env,
+            require_spawnable_chips,
             run_payload_subprocess,
         )
 
+        if device_ids:
+            require_spawnable_chips(self.topology.platform)
         env = {
             **os.environ,
             "BIOENGINE_HOST_ID": self.host_id,

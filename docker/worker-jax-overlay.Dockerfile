@@ -10,9 +10,9 @@
 # Build:
 #   docker build \
 #       --build-arg BIOENGINE_IMAGE=ghcr.io/OWNER/bioengine-tpu-worker:latest \
-#       --build-arg JAX_VERSION=0.4.38 \
+#       --build-arg JAX_VERSION=0.9.0 \
 #       -f docker/worker-jax-overlay.Dockerfile \
-#       -t bioengine-tpu-worker:jax0.4.38 .
+#       -t bioengine-tpu-worker:jax0.9.0 .
 #
 # BIOENGINE_IMAGE: the published image used as the base.
 # JAX_VERSION:     the exact jax release to swap in; libtpu resolves to
@@ -21,7 +21,7 @@
 ARG BIOENGINE_IMAGE=ghcr.io/aicell-lab/bioengine-tpu-worker:latest
 FROM ${BIOENGINE_IMAGE}
 
-ARG JAX_VERSION=0.4.35
+ARG JAX_VERSION=0.9.0
 RUN pip install --no-cache-dir "jax[tpu]==${JAX_VERSION}" \
     -f https://storage.googleapis.com/jax-releases/libtpu_releases.html
 

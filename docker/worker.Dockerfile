@@ -46,10 +46,10 @@ RUN make -C /app/native
 # pair (they must match) rebuilds only this layer, mirroring the
 # reference's Ray-last layering trick (ref docker/worker.Dockerfile).
 #
-#   docker build --build-arg JAX_VERSION=0.4.35 \
+#   docker build --build-arg JAX_VERSION=0.9.0 \
 #     -f docker/worker.Dockerfile -t bioengine-tpu-worker:dev .
 # ---------------------------------------------------------------------------
-ARG JAX_VERSION=0.4.35
+ARG JAX_VERSION=0.9.0
 RUN pip install "jax[tpu]==${JAX_VERSION}" \
     -f https://storage.googleapis.com/jax-releases/libtpu_releases.html
 

@@ -52,7 +52,7 @@ root = tempfile.mkdtemp(prefix="coldstart-dryrun-")
 dir_a = os.path.join(root, "xla-a")
 dir_b = os.path.join(root, "xla-b")
 os.makedirs(dir_b)
-os.environ["BIOENGINE_COMPILE_CACHE"] = dir_a
+os.environ["JAX_COMPILATION_CACHE_DIR"] = dir_a
 # 8 virtual host devices so each in-process "host" can lease 3 chips
 # (same forced layout the test suite runs under)
 _flags = os.environ.get("XLA_FLAGS", "")

@@ -54,6 +54,30 @@ class TestTopology:
         assert set(d) == {"platform", "n_chips", "n_hosts", "chips"}
         assert len(d["chips"]) == d["n_chips"]
 
+    def test_implicit_cpu_fallback_refused(self, cpu_not_asked_for, tmp_path):
+        """CPU devices without CPU having been asked for by name means
+        the accelerator failed to initialise: neither the detector nor
+        the cluster manager carries on (and the workspace lock is given
+        back). The suite's own explicit JAX_PLATFORMS=cpu is accepted —
+        every other test in this file."""
+        import jax
+
+        from bioengine_tpu.utils.devices import NoAcceleratorError
+
+        with pytest.raises(NoAcceleratorError, match="JAX_PLATFORMS=cpu"):
+            detect_topology()
+        # a fallback entry after the accelerator does not make it explicit
+        jax.config.update("jax_platforms", "tpu,cpu")
+        with pytest.raises(NoAcceleratorError):
+            detect_topology()
+        cluster = TpuCluster(
+            mode="single-machine", workspace_dir=tmp_path, log_file="off"
+        )
+        with pytest.raises(NoAcceleratorError):
+            cluster.start()
+        assert not cluster.is_ready
+        assert not (tmp_path / "cluster.lock").exists()
+
 
 class TestClusterState:
     def test_snapshot_and_history_ring(self):

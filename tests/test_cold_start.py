@@ -2,7 +2,7 @@
 loading, and the preemption-tolerant warm pool.
 
 Covers the three coordinated pieces end to end:
-- utils/compile_cache.py — failure-verdict caching and the tier entry
+- utils/compile_cache.py — loud failure and the tier entry
   file protocol (list/read/atomic-write, unsafe names rejected);
 - serving/compile_tier.py + worker_host sync — hosts publish compiled
   programs at join/replica-start and a later host FETCHES them, with
@@ -92,45 +92,25 @@ def _make_package(root: Path, with_manifest: bool = True) -> Path:
 
 
 # ---------------------------------------------------------------------------
-# compile_cache: failure-verdict caching + tier entry file protocol
+# compile_cache: loud failure + tier entry file protocol
 # ---------------------------------------------------------------------------
 
 
 class TestCompileCache:
-    def test_failure_verdict_cached_and_logged_once(
-        self, tmp_path, monkeypatch, caplog
-    ):
+    def test_unusable_directory_raises(self, tmp_path, monkeypatch):
+        """A cache that cannot be enabled is an error, not a warning: a
+        worker that recompiles everything on every start must not come
+        up looking healthy."""
         blocker = tmp_path / "a-file"
         blocker.write_text("not a directory")
         monkeypatch.setenv(
-            "BIOENGINE_COMPILE_CACHE", str(blocker / "sub" / "dir")
+            "JAX_COMPILATION_CACHE_DIR", str(blocker / "sub" / "dir")
         )
         compile_cache.reset_for_tests()
         try:
-            import logging
-
-            with caplog.at_level(
-                logging.WARNING, logger="bioengine_tpu.utils.compile_cache"
-            ):
-                assert compile_cache.enable_persistent_compilation_cache() is None
-                assert compile_cache.enable_persistent_compilation_cache() is None
-                assert compile_cache.enable_persistent_compilation_cache() is None
-            warnings = [
-                r for r in caplog.records if "unavailable" in r.getMessage()
-            ]
-            # the verdict is cached: one attempt, one warning — not one
-            # mkdir+warning per call on a read-only FS
-            assert len(warnings) == 1
-            assert compile_cache._failed is True
-        finally:
-            compile_cache.reset_for_tests()
-
-    def test_off_switch(self, monkeypatch):
-        monkeypatch.setenv("BIOENGINE_COMPILE_CACHE", "off")
-        compile_cache.reset_for_tests()
-        try:
-            assert compile_cache.enable_persistent_compilation_cache() is None
-            assert compile_cache._failed is False  # off is not a failure
+            with pytest.raises(OSError):
+                compile_cache.enable_persistent_compilation_cache()
+            assert compile_cache.enabled_dir() is None
         finally:
             compile_cache.reset_for_tests()
 

@@ -1,7 +1,8 @@
 """Pallas kernel correctness vs. plain-XLA reference implementations.
 
-Runs in interpreter mode on the CPU backend (conftest pins
-JAX_PLATFORMS=cpu) — the same kernel code compiles via Mosaic on TPU.
+Runs in interpreter mode because conftest names the CPU platform
+(JAX_PLATFORMS=cpu) — the same kernel code compiles via Mosaic on TPU,
+which chip_smoke.py checks on the chip.
 """
 
 import jax
@@ -45,6 +46,16 @@ class TestFlashAttention:
         out = flash_attention(q, k, v, causal=True)
         ref = ref_attention(q, k, v, causal=True)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+    def test_interprets_only_under_explicit_cpu(self, cpu_not_asked_for):
+        """Every other test here runs the interpreter because the suite
+        names the CPU platform; a CPU backend nobody asked for is a
+        failed accelerator, and the kernel refuses to stand in for it."""
+        from bioengine_tpu.utils.devices import NoAcceleratorError
+
+        q = jnp.zeros((1, 1, 136, 64), jnp.float32)  # a shape no test traced
+        with pytest.raises(NoAcceleratorError, match="flash_attention"):
+            flash_attention(q, q, q)
 
     def test_bf16(self):
         rng = np.random.default_rng(2)

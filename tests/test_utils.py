@@ -355,45 +355,79 @@ class TestTracing:
         assert get_spans(name="a")[-1]["attrs"] == {"i": 4}
 
 
-def test_persistent_compilation_cache(tmp_path):
-    """enable_persistent_compilation_cache fills the cache dir and a
-    second process reuses it (subprocess: jax config is process-global
-    and must not leak into other tests)."""
+def _run_cache_prog(prog: str, env_dir) -> None:
+    """jax config is process-global and must not leak into other tests:
+    each compile-cache case runs in its own interpreter."""
+    import os
     import subprocess
     import sys
 
-    prog = f"""
-import jax; jax.config.update("jax_platforms", "cpu")
-from bioengine_tpu.utils.compile_cache import enable_persistent_compilation_cache
-d = enable_persistent_compilation_cache({str(tmp_path)!r})
-assert d == {str(tmp_path)!r}, d
-# idempotent
-assert enable_persistent_compilation_cache("/elsewhere") == d
-import jax.numpy as jnp
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-jax.jit(lambda x: (x @ x).sum())(jnp.ones((64, 64))).block_until_ready()
-"""
-    for _ in range(2):
-        r = subprocess.run(
-            [sys.executable, "-c", prog], capture_output=True, text=True
-        )
-        assert r.returncode == 0, r.stderr[-1500:]
-    assert any(tmp_path.iterdir()), "cache dir stayed empty"
-
-    # explicit opt-out
-    import os
-
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
     r = subprocess.run(
-        [sys.executable, "-c", (
-            "import jax; jax.config.update('jax_platforms', 'cpu')\n"
-            "from bioengine_tpu.utils.compile_cache import "
-            "enable_persistent_compilation_cache\n"
-            "assert enable_persistent_compilation_cache() is None"
-        )],
-        capture_output=True, text=True,
-        env={**os.environ, "BIOENGINE_COMPILE_CACHE": "off"},
+        [sys.executable, "-c", prog], capture_output=True, text=True, env=env
     )
     assert r.returncode == 0, r.stderr[-1500:]
+
+
+def test_compile_cache_placed_by_the_environment(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the code sets no directory at all
+    (an explicit path= yields too), enabled_dir() reports the variable's
+    directory, the cache fills, and a second process reuses it."""
+    prog = f"""
+import jax
+calls = []
+real_update = jax.config.update
+jax.config.update = lambda k, v: (calls.append(k), real_update(k, v))
+from bioengine_tpu.utils import compile_cache
+d = compile_cache.enable_persistent_compilation_cache("/elsewhere")
+assert d == {str(tmp_path)!r} == compile_cache.enabled_dir(), d
+assert compile_cache.enable_persistent_compilation_cache() == d  # idempotent
+assert "jax_compilation_cache_dir" not in calls, calls
+assert jax.config.jax_compilation_cache_dir == d
+import jax.numpy as jnp
+real_update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.jit(lambda x: (x @ x).sum())(jnp.ones((64, 64))).block_until_ready()
+print(len(compile_cache.list_entries()))
+"""
+    _run_cache_prog(prog, tmp_path)
+    entries = sorted(p.name for p in tmp_path.iterdir())
+    assert entries, "cache dir stayed empty"
+    _run_cache_prog(prog, tmp_path)
+    assert sorted(p.name for p in tmp_path.iterdir()) == entries  # all hits
+
+
+def test_compile_cache_default_is_fixed_in_checkout(tmp_path):
+    """Variable unset: one fixed directory inside the checkout, never a
+    temp name; an explicit path= is honoured; and enabling after the
+    process's first compile still takes effect (jax latches "cache
+    unused" at that first compile)."""
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parent.parent
+    _run_cache_prog(
+        f"""
+import jax
+from bioengine_tpu.utils import compile_cache
+d = compile_cache.enable_persistent_compilation_cache()
+assert d == {str(repo / ".cache" / "xla")!r} == compile_cache.enabled_dir(), d
+assert jax.config.jax_compilation_cache_dir == d
+""",
+        None,
+    )
+    _run_cache_prog(
+        f"""
+import jax, jax.numpy as jnp
+jax.jit(lambda x: x + 1)(jnp.ones(4)).block_until_ready()  # latches "unused"
+from bioengine_tpu.utils import compile_cache
+assert compile_cache.enable_persistent_compilation_cache({str(tmp_path)!r}) == {str(tmp_path)!r}
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.jit(lambda x: (x @ x).sum())(jnp.ones((64, 64))).block_until_ready()
+assert compile_cache.list_entries(), "compile after enabling was not cached"
+""",
+        None,
+    )
 
 
 def test_full_jitter_delay_windows_and_overflow():

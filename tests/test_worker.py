@@ -114,6 +114,37 @@ async def test_run_code_env_vars(executor):
     assert result["result"] == "on"
 
 
+async def test_run_code_chips_refused_from_a_process_that_holds_them():
+    """One process per chip: a worker that enumerated TPU chips through
+    JAX holds all of them, so a chip subprocess placed locally would die
+    in libtpu at backend init. It is refused up front with a typed
+    error, and no lease is taken."""
+    from bioengine_tpu.cluster.state import ClusterState
+    from bioengine_tpu.cluster.topology import ChipInfo, TpuTopology
+    from bioengine_tpu.worker.code_executor import ChipHeldError
+
+    state = ClusterState(
+        TpuTopology(
+            chips=(ChipInfo(0, "tpu", "TPU v5 lite", 0),),
+            n_hosts=1,
+            platform="tpu",
+        )
+    )
+    executor = CodeExecutor(admin_users=["admin"], cluster_state=state)
+    with pytest.raises(ChipHeldError, match="one process at a time"):
+        await executor.run_code(
+            code="def main():\n    return 1\n",
+            remote_options={"num_chips": 1},
+            context=ADMIN_CTX,
+        )
+    assert state.free_chips() == 1
+    # chip-free code is unaffected
+    result = await executor.run_code(
+        code="def main():\n    return 1\n", context=ADMIN_CTX
+    )
+    assert result["result"] == 1
+
+
 async def test_run_code_requires_admin(executor):
     with pytest.raises(PermissionError):
         await executor.run_code(code="def main():\n    return 1\n", context=ANON_CTX)
