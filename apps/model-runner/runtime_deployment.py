@@ -280,14 +280,24 @@ class Pipeline:
 
         Arrays arrive in the RDF's declared axes, are canonicalized to
         NHWC for the engine, and returned in the declared output axes.
+        The host work on either side of the engine call, with the
+        device empty, is the ``runtime.preprocess`` and
+        ``runtime.postprocess`` stages.
         """
         spec = self.input_spec
-        x = to_nhwc(self.extract_array(inputs), spec.axes)
-        x = apply_processing(x, spec.preprocessing)
+        with tracing.stage("runtime.preprocess") as pre:
+            x = to_nhwc(self.extract_array(inputs), spec.axes)
+            x = apply_processing(x, spec.preprocessing)
         y = self.engine.predict(x)  # InferenceEngine and TorchFallbackRunner share .predict
         out_spec = self.output_spec
-        y = apply_processing(y, out_spec.postprocessing)
-        y = from_nhwc(y, out_spec.axes)
+        with tracing.stage("runtime.postprocess") as post:
+            y = apply_processing(y, out_spec.postprocessing)
+            y = from_nhwc(y, out_spec.axes)
+        stats = getattr(self.engine, "pipeline_stats", None)
+        if stats is not None:
+            stats.add(
+                preprocess_seconds=pre.seconds, postprocess_seconds=post.seconds
+            )
         return {out_spec.name: y}
 
     async def predict_async(self, inputs) -> dict[str, np.ndarray]:
@@ -298,10 +308,11 @@ class Pipeline:
         without spawning a thread per request via asyncio.to_thread.
         The torch fallback has no dispatch thread; it keeps to_thread."""
         if self.backend == "xla":
-            # carry a sampled trace context onto the dispatch thread so
-            # engine.predict's stage span lands in the request's tree
-            fn = tracing.carry(tracing.current_trace(), self.predict)
-            return await asyncio.wrap_future(self.engine.submit(fn, inputs))
+            # submit() runs the task in a copy of this context, so the
+            # stages on the dispatch thread land in a sampled request's tree
+            return await asyncio.wrap_future(
+                self.engine.submit(self.predict, inputs)
+            )
         return await asyncio.to_thread(self.predict, inputs)
 
     def pipeline_stats(self) -> dict:
@@ -462,15 +473,16 @@ class RuntimeDeployment:
         pipeline = payloads[0][0]
         arrays = [a for _, a in payloads]
         sizes = [len(a) for a in arrays]
-        with tracing.trace_span("batch.assemble", requests=len(arrays)):
+        with tracing.stage("runtime.assemble", requests=len(arrays)):
             merged = np.concatenate(arrays, axis=0)
         result = await pipeline.predict_async(merged)
-        out_name, y = next(iter(result.items()))
-        outs = []
-        start = 0
-        for n in sizes:
-            outs.append({out_name: y[start : start + n]})
-            start += n
+        with tracing.stage("runtime.split"):
+            out_name, y = next(iter(result.items()))
+            outs = []
+            start = 0
+            for n in sizes:
+                outs.append({out_name: y[start : start + n]})
+                start += n
         return outs
 
     async def check_health(self):

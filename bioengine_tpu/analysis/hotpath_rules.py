@@ -153,7 +153,7 @@ _ADVICE = {
     "entropy": (
         "request/call ids need uniqueness, not crypto randomness — "
         "mint with `random.getrandbits` (~40 us cheaper per id; see "
-        "utils/tracing._new_id)"
+        "utils/tracing.new_id)"
     ),
     "relabel": (
         "resolve the labeled child once at construction "

@@ -34,9 +34,11 @@ bit-identical to pre-mesh behavior.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import itertools
 import os
+import re
 import threading
 import time
 import warnings
@@ -445,8 +447,26 @@ class InferenceEngine:
         ``concurrent.futures.Future``. The building block behind
         ``predict_async`` for callers that wrap extra host work
         (pre/post processing) around the engine — one thread serializes
-        device access instead of a fresh ``to_thread`` per request."""
-        return self._dispatcher.submit(fn, *args, **kwargs)
+        device access instead of a fresh ``to_thread`` per request.
+
+        The task runs in a copy of the submitter's context (a sampled
+        trace and the chip-seconds accumulator cross with it). The wait
+        for the dispatch thread is the ``engine.queue`` stage, the task
+        whole the ``engine.request`` stage: while one is open the engine
+        has a request in hand."""
+        submitted = time.time_ns()
+        stats = self.pipeline_stats
+
+        def task():
+            tracing.begin_request()
+            queued = tracing.record_stage(
+                "engine.queue", submitted, time.time_ns()
+            )
+            stats.add(requests=1, queue_seconds=queued)
+            with tracing.stage("engine.request"):
+                return fn(*args, **kwargs)
+
+        return self._dispatcher.submit(contextvars.copy_context().run, task)
 
     # ---- program management -------------------------------------------------
 
@@ -465,10 +485,20 @@ class InferenceEngine:
         )
 
         def build():
+            # jit names a program after its function: one name per model
+            # and shape, so a profiler's "XLA Modules" line tells this
+            # engine's programs apart (they were all ``jit__lambda_``)
+            def forward(params, images):
+                return self.apply_fn(params, images)
+
+            forward.__name__ = forward.__qualname__ = re.sub(
+                r"\W", "_",
+                f"engine_{self.model_id}_{'x'.join(map(str, shape))}",
+            )
             fn = (
-                jax.jit(self.apply_fn, donate_argnums=(1,))
+                jax.jit(forward, donate_argnums=(1,))
                 if donate
-                else jax.jit(self.apply_fn)
+                else jax.jit(forward)
             )
             # Trigger compilation now so the first request doesn't pay it
             # inside the hot path accounting. The dummy must be COMMITTED
@@ -540,46 +570,56 @@ class InferenceEngine:
         overlapped pipeline; ``pipeline_depth=0`` falls back to the
         serial path.
 
-        Under a sampled request trace the whole prediction records an
-        ``engine.predict`` span whose attrs carry the PipelineStats
-        per-stage delta (h2d put / dispatch / compute / readback /
-        stitch seconds) — the device-side half of the request's latency
-        breakdown — plus the prediction's ``chip_seconds`` (wall
-        seconds x mesh width). Chip-seconds ALSO feed the request-
-        scoped accounting accumulator (utils/tracing.py) on every
-        call, sampled or not: cost is exact, only spans are sampled."""
-        ctx = tracing.current_trace()
+        The whole prediction is the ``engine.predict`` stage. Under a
+        sampled request trace its span's attrs carry ``stage_seconds``,
+        the sums of this request's own stages (cut / h2d put / dispatch
+        / device_wait / d2h / stitch, ``readback`` = device_wait + d2h,
+        and the ``compute`` estimate) — the device-side half of the
+        request's latency breakdown — plus the prediction's
+        ``chip_seconds`` (wall seconds x mesh width). Chip-seconds ALSO
+        feed the request-scoped accounting accumulator
+        (utils/tracing.py) on every call, sampled or not: cost is
+        exact, only spans are sampled."""
         width = len(self.devices)
-        t0 = time.monotonic()
-        if ctx is None or not ctx.sampled:
-            try:
-                return self._predict_impl(images)
-            finally:
-                tracing.add_chip_seconds((time.monotonic() - t0) * width)
-        before = self.pipeline_stats.as_dict()
+        stats = self.pipeline_stats
+        # the dispatch thread runs one prediction at a time, so the
+        # engine-wide estimate moves by this request's alone
+        compute_before = stats.compute_seconds
+        whole = tracing.stage(
+            "engine.predict",
+            model=self.model_id,
+            batch=len(images),
+            mesh=self._mesh_key,
+            devices=width,
+        )
         try:
-            with tracing.span(
-                "engine.predict",
-                model=self.model_id,
-                batch=int(np.asarray(images).shape[0]),
-                mesh=self._mesh_key,
-                devices=width,
-            ) as record:
+            with whole:
                 out = self._predict_impl(images)
-                after = self.pipeline_stats.as_dict()
-                record["attrs"]["stage_seconds"] = {
-                    k.removesuffix("_seconds"): round(after[k] - before[k], 6)
-                    for k in (
-                        "cut_seconds", "put_seconds", "dispatch_seconds",
-                        "compute_seconds", "readback_seconds", "stitch_seconds",
+                if whole.span is not None:
+                    whole.attrs["stage_seconds"] = self._stage_seconds(
+                        whole.span, stats.compute_seconds - compute_before
                     )
-                }
-                record["attrs"]["chip_seconds"] = round(
-                    (time.monotonic() - t0) * width, 6
-                )
             return out
         finally:
-            tracing.add_chip_seconds((time.monotonic() - t0) * width)
+            chip_seconds = whole.seconds * width
+            if whole.span is not None:
+                whole.attrs["chip_seconds"] = round(chip_seconds, 6)
+            tracing.add_chip_seconds(chip_seconds)
+
+    @staticmethod
+    def _stage_seconds(span: dict, compute: float) -> dict:
+        """``engine.predict``'s ``stage_seconds``: this request's own
+        ``engine.*`` stages summed by name, without the prefix."""
+        own = tracing.child_stage_seconds(span)
+        sums = {
+            name: round(own.get(f"engine.{name}", 0.0), 6)
+            for name in (
+                "cut", "put", "dispatch", "device_wait", "d2h", "stitch"
+            )
+        }
+        sums["readback"] = round(sums["device_wait"] + sums["d2h"], 6)
+        sums["compute"] = round(compute, 6)
+        return sums
 
     def _predict_impl(self, images: np.ndarray) -> np.ndarray:
         images = self._validate(images)
@@ -587,10 +627,15 @@ class InferenceEngine:
         if self._needs_tiling(images, specs):
             if self.config.pipeline_depth > 0:
                 return self._predict_tiled_pipelined(images, specs)
-            return np.stack(
-                [self._predict_tiled(item, specs) for item in images]
-            )
+            return self._predict_tiled_serial(images, specs)
+        self.pipeline_stats.add(items=len(images))
         return self._predict_direct(images, specs)
+
+    def _predict_tiled_serial(
+        self, images: np.ndarray, specs: list["_AxisSpec"]
+    ) -> np.ndarray:
+        self.pipeline_stats.add(items=len(images))
+        return np.stack([self._predict_tiled(item, specs) for item in images])
 
     def predict_serial(self, images: np.ndarray) -> np.ndarray:
         """The strictly serial pre-pipeline path: one chunk cut, put,
@@ -600,9 +645,8 @@ class InferenceEngine:
         images = self._validate(images)
         specs = self._axis_specs(images.ndim)
         if self._needs_tiling(images, specs):
-            return np.stack(
-                [self._predict_tiled(item, specs) for item in images]
-            )
+            return self._predict_tiled_serial(images, specs)
+        self.pipeline_stats.add(items=len(images))
         return self._predict_direct(images, specs)
 
     async def predict_async(self, images: np.ndarray) -> np.ndarray:
@@ -615,15 +659,16 @@ class InferenceEngine:
         while the pipeline's own staging/stitch threads overlap it)."""
         import asyncio
 
-        # contextvars don't cross into the dispatch thread on their
-        # own — carry() re-activates a sampled trace there (and is the
-        # identity function when unsampled)
-        fn = tracing.carry(tracing.current_trace(), self.predict)
-        return await asyncio.wrap_future(self.submit(fn, images))
+        # submit() runs the task in a copy of this context: a sampled
+        # trace and the chip accounting cross with it
+        return await asyncio.wrap_future(self.submit(self.predict, images))
 
     def _predict_direct(self, x: np.ndarray, specs: list["_AxisSpec"]) -> np.ndarray:
         """Bucket every spatial axis, pad into a reusable staging
-        buffer, run the compiled program, crop back."""
+        buffer, run the compiled program, crop back. One chunk of the
+        same six stages as the pipelined path, one after the other
+        (``cut`` is the fill, ``stitch`` the crop), into the same
+        ``PipelineStats`` fields."""
         B = x.shape[0]
         C = x.shape[-1]
         spatial = x.shape[1:-1]
@@ -635,27 +680,81 @@ class InferenceEngine:
         bb = bucket_batch(B, multiple_of=self.dp)
         staged = self._staging_pool.acquire((bb, *buckets, C), x.dtype)
         try:
-            fill_bucketed(staged, x)
-            program = self._program(staged.shape, staged.dtype)
+            with tracing.stage("engine.cut") as cut:
+                fill_bucketed(staged, x)
+            flight = self._dispatch_chunk(staged, B)
+            out = self._force_chunk(flight)
+        finally:
+            self._staging_pool.release(staged)
+        with tracing.stage("engine.stitch") as crop:
+            out = out[:B]
+            if out.ndim == len(spatial) + 2:
+                out = crop_to(out, spatial, axes=axes)
+            elif buckets != spatial:
+                raise ValueError(
+                    f"model '{self.model_id}' returns a global output "
+                    f"(shape {out.shape}) but the input {spatial} was "
+                    f"padded to bucket {buckets} — padding corrupts global "
+                    f"outputs. Resize inputs to a bucket size."
+                )
+        self.pipeline_stats.add(
+            chunks=1,
+            cut_seconds=cut.seconds,
+            stitch_seconds=crop.seconds,
+            # serial: the device has the chunk from the end of the
+            # dispatch to the end of the wait for it
+            compute_seconds=(flight.ready_ns - flight.dispatched_ns) / 1e9,
+            wall_seconds=(crop.end_ns - cut.start_ns) / 1e9,
+        )
+        return out
+
+    # ---- one chunk on the device (every path's put/dispatch/wait/d2h) -------
+
+    def _dispatch_chunk(self, buf: np.ndarray, n: int) -> "_InFlight":
+        """Hand one staged chunk (``n`` useful rows) to the device
+        WITHOUT blocking: ``engine.put`` is the ``device_put`` call (H2D
+        enqueue and linearize), ``engine.dispatch`` the program lookup,
+        the params gate and the jitted call."""
+        with tracing.stage("engine.put", bytes=buf.nbytes) as put:
+            # staged host chunks become sharded arrays on a mesh engine
+            # (single-device put on 1 chip)
+            dev = self._put(buf)
+        with tracing.stage("engine.dispatch") as dispatch:
+            program = self._program(buf.shape, buf.dtype)
             # the gate sits AFTER compile: under streamed loading the
             # first request's compile overlaps the weight transfer, and
             # only the real execution waits for residency (an eager
             # engine pays one Event.is_set() here)
             self._wait_params_ready()
-            out = np.asarray(program(self.params, self._put(staged)))
-        finally:
-            self._staging_pool.release(staged)
-        out = out[:B]
-        if out.ndim == len(spatial) + 2:
-            out = crop_to(out, spatial, axes=axes)
-        elif buckets != spatial:
-            raise ValueError(
-                f"model '{self.model_id}' returns a global output "
-                f"(shape {out.shape}) but the input {spatial} was padded to "
-                f"bucket {buckets} — padding corrupts global outputs. "
-                f"Resize inputs to a bucket size."
-            )
-        return out
+            out = program(self.params, dev)
+        self.pipeline_stats.add(
+            put_seconds=put.seconds,
+            dispatch_seconds=dispatch.seconds,
+            h2d_bytes=buf.nbytes,
+            rows_executed=buf.shape[0],
+            rows_useful=n,
+        )
+        return _InFlight(out, n, dispatch.end_ns)
+
+    def _force_chunk(self, flight: "_InFlight") -> np.ndarray:
+        """Block until a dispatched chunk is on the host:
+        ``engine.device_wait`` is the wait for the device,
+        ``engine.d2h`` the copy of the ready result. ``readback_seconds``
+        keeps its meaning, the two together. Returns every row, padding
+        included."""
+        out = flight.out
+        with tracing.stage("engine.device_wait") as wait:
+            out.block_until_ready()
+        with tracing.stage("engine.d2h", bytes=out.nbytes) as d2h:
+            host = np.asarray(out)
+        flight.ready_ns = wait.end_ns
+        self.pipeline_stats.add(
+            device_wait_seconds=wait.seconds,
+            d2h_seconds=d2h.seconds,
+            readback_seconds=wait.seconds + d2h.seconds,
+            d2h_bytes=host.nbytes,
+        )
+        return host
 
     # ---- tiling geometry (shared by the serial and pipelined paths) ---------
 
@@ -703,23 +802,30 @@ class InferenceEngine:
         acc = None
         weight = np.zeros((*spatial, 1), np.float32)
         for i in range(0, len(coords), chunk):
-            batch = np.stack([cut(s) for s in coords[i : i + chunk]])
+            with tracing.stage("engine.cut") as tiles:
+                batch = np.stack([cut(s) for s in coords[i : i + chunk]])
             out = self._predict_direct(batch, specs)
             if out.ndim != len(spatial) + 2:
                 raise ValueError(
                     f"tiled prediction requires dense spatial outputs, "
                     f"model '{self.model_id}' returned {out.shape}"
                 )
-            if acc is None:
-                acc = np.zeros((*spatial, out.shape[-1]), np.float32)
-            for tile_out, start in zip(out, coords[i : i + chunk]):
-                dst = tuple(
-                    slice(s0, min(s0 + t, size))
-                    for s0, t, size in zip(start, tsizes, spatial)
-                )
-                src = tuple(slice(0, s.stop - s.start) for s in dst)
-                acc[dst] += tile_out[src] * ramp[src]
-                weight[dst] += ramp[src]
+            with tracing.stage("engine.stitch") as blend:
+                if acc is None:
+                    acc = np.zeros((*spatial, out.shape[-1]), np.float32)
+                for tile_out, start in zip(out, coords[i : i + chunk]):
+                    dst = tuple(
+                        slice(s0, min(s0 + t, size))
+                        for s0, t, size in zip(start, tsizes, spatial)
+                    )
+                    src = tuple(slice(0, s.stop - s.start) for s in dst)
+                    acc[dst] += tile_out[src] * ramp[src]
+                    weight[dst] += ramp[src]
+            self.pipeline_stats.add(
+                cut_seconds=tiles.seconds,
+                stitch_seconds=blend.seconds,
+                wall_seconds=tiles.seconds + blend.seconds,
+            )
         return acc / np.maximum(weight, 1e-8)
 
     def _predict_tiled_pipelined(
@@ -796,26 +902,13 @@ class InferenceEngine:
 
         def dispatch(desc, staged):
             buf, n = staged
-            t0 = time.perf_counter()
-            # staged host chunks become sharded arrays on a mesh engine
-            # (single-device put on 1 chip) — staging/dispatch/stitch
-            # semantics, donation, and double buffering are unchanged
-            dev = self._put(buf)
-            t1 = time.perf_counter()
-            program = self._program(buf.shape, buf.dtype)
-            self._wait_params_ready()  # streamed loading: see _predict_direct
-            out = program(self.params, dev)
-            stats.add(
-                put_seconds=t1 - t0,
-                dispatch_seconds=time.perf_counter() - t1,
-            )
-            return out, buf, n
+            return self._dispatch_chunk(buf, n), buf
 
         def force(handle):
-            out, buf, n = handle
-            host = np.asarray(out)
+            flight, buf = handle
+            host = self._force_chunk(flight)
             pool.release(buf)
-            return host[:n]
+            return host[: flight.n]
 
         def stitch(desc, host):
             b, i0, i1 = desc
@@ -844,6 +937,18 @@ class InferenceEngine:
         )
         stats.add(items=B)
         return state["acc"] / np.maximum(weight, 1e-8)
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """One chunk handed to the device: its output (a future until
+    forced), its useful rows, and when the dispatch ended and the wait
+    for it ended (``time.time_ns()``)."""
+
+    out: jax.Array
+    n: int
+    dispatched_ns: int
+    ready_ns: int = 0
 
 
 @dataclasses.dataclass(frozen=True)

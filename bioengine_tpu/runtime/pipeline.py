@@ -16,7 +16,10 @@ so the fix is structural, not a kernel change:
 (fill, dispatch, force, stitch) stage functions, keeps at most
 ``depth`` chunks in flight on the device (bounding HBM), at most
 ``prefetch`` staged chunks on the host (bounding RAM), and accounts
-every stage in a ``PipelineStats``.
+every stage in a ``PipelineStats``. Each stage is measured once, by
+``tracing.stage`` (utils/tracing.py): the interval the sums receive is
+the one the process-wide stage timeline, a sampled request's span tree
+and a profiler trace show.
 
 ``StagingPool`` recycles the host-side staging buffers per
 (shape, dtype) so steady-state tiled inference stops paying a fresh
@@ -28,6 +31,7 @@ instead of spawning a thread per prediction via ``asyncio.to_thread``.
 
 from __future__ import annotations
 
+import contextvars
 import queue
 import threading
 import time
@@ -37,7 +41,7 @@ from typing import Any, Callable, Iterable, Optional
 
 import numpy as np
 
-from bioengine_tpu.utils import metrics
+from bioengine_tpu.utils import metrics, tracing
 
 
 def _collect_pipelines(instances: list) -> list:
@@ -45,15 +49,10 @@ def _collect_pipelines(instances: list) -> list:
     metrics plane — the same objects Replica.describe reads per
     replica, summed to the device-busy/overlap signal a scheduler
     wants per worker."""
-    fields = (
-        "runs", "chunks", "items", "cut_seconds", "put_seconds",
-        "dispatch_seconds", "compute_seconds", "readback_seconds",
-        "stitch_seconds", "wall_seconds",
-    )
-    totals = dict.fromkeys(fields, 0.0)
+    totals = dict.fromkeys(PipelineStats._FIELDS, 0.0)
     for st in instances:
         with st._lock:
-            for f in fields:
+            for f in totals:
                 totals[f] += getattr(st, f)
     return [
         metrics.Sample(
@@ -78,19 +77,40 @@ class PipelineStats:
     force completes. ``overlap_efficiency`` = device-busy / wall — 1.0
     means the device never waited on the host. On CPU backends XLA
     dispatch is near-synchronous, so the numbers are informational.
+
+    Every ``*_seconds`` sum but ``compute`` and ``wall`` is fed by one
+    ``tracing.stage`` of the same name (``cut_seconds`` by
+    ``engine.cut``, ``queue_seconds`` by ``engine.queue``,
+    ``preprocess_seconds`` by ``runtime.preprocess``, ...);
+    ``readback_seconds`` is ``device_wait_seconds`` + ``d2h_seconds``
+    (mostly the wait for the device, not host work). The direct and
+    serial paths feed the same fields as the pipelined one, one chunk
+    per program call; ``runs`` counts ``run_pipeline`` runs alone.
+    ``rows_executed`` are the batch rows of every program call, padding
+    rows included; ``rows_useful`` the tiles or items asked for.
     """
 
     _FIELDS = (
         "runs",
         "chunks",
         "items",
+        "requests",
+        "queue_seconds",
+        "preprocess_seconds",
+        "postprocess_seconds",
         "cut_seconds",
         "put_seconds",
         "dispatch_seconds",
         "compute_seconds",
+        "device_wait_seconds",
+        "d2h_seconds",
         "readback_seconds",
         "stitch_seconds",
         "wall_seconds",
+        "h2d_bytes",
+        "d2h_bytes",
+        "rows_executed",
+        "rows_useful",
     )
 
     def __init__(self, depth: int = 0):
@@ -217,7 +237,7 @@ def run_pipeline(
     - ``dispatch(desc, staged)`` (caller thread): hand the chunk to the
       device, return a future-like handle WITHOUT blocking.
     - ``force(handle)`` (caller thread): block until the device result
-      is on the host, return it.
+      is on the host, return it (and account its own wait and copy).
     - ``stitch(desc, host)`` (stitch thread): fold the result into the
       caller's accumulator.
 
@@ -246,9 +266,9 @@ def run_pipeline(
             for desc in descs:
                 if stop.is_set():
                     return
-                t0 = time.perf_counter()
-                staged = fill(desc)
-                stats.add(cut_seconds=time.perf_counter() - t0)
+                with tracing.stage("engine.cut") as cut:
+                    staged = fill(desc)
+                stats.add(cut_seconds=cut.seconds)
                 if not _put(cut_q, (desc, staged)):
                     return
             _put(cut_q, _DONE)
@@ -266,16 +286,23 @@ def run_pipeline(
                 if item is _DONE:
                     return
                 desc, host = item
-                t0 = time.perf_counter()
-                stitch(desc, host)
-                stats.add(stitch_seconds=time.perf_counter() - t0)
+                with tracing.stage("engine.stitch") as blend:
+                    stitch(desc, host)
+                stats.add(stitch_seconds=blend.seconds)
         except BaseException as exc:  # noqa: BLE001 — re-raised in caller
             errors.append(exc)
             stop.set()
 
-    cut_t = threading.Thread(target=cut_worker, name="pipeline-cut", daemon=True)
+    # each worker runs in a copy of the caller's context: its stages
+    # carry the caller's request number and land in a sampled request's
+    # tree (a Context can be entered by one thread at a time)
+    cut_t = threading.Thread(
+        target=contextvars.copy_context().run, args=(cut_worker,),
+        name="pipeline-cut", daemon=True,
+    )
     stitch_t = threading.Thread(
-        target=stitch_worker, name="pipeline-stitch", daemon=True
+        target=contextvars.copy_context().run, args=(stitch_worker,),
+        name="pipeline-stitch", daemon=True,
     )
     cut_t.start()
     stitch_t.start()
@@ -287,16 +314,12 @@ def run_pipeline(
     def force_oldest() -> None:
         nonlocal last_force_done
         desc, handle, dispatched_at = window.popleft()
-        t0 = time.perf_counter()
         host = force(handle)
         done = time.perf_counter()
         busy_from = dispatched_at
         if last_force_done is not None and last_force_done > busy_from:
             busy_from = last_force_done
-        stats.add(
-            readback_seconds=done - t0,
-            compute_seconds=max(done - busy_from, 0.0),
-        )
+        stats.add(compute_seconds=max(done - busy_from, 0.0))
         last_force_done = done
         _put(stitch_q, (desc, host))
 

@@ -38,7 +38,9 @@ DEFAULT_MAX_WAIT_MS = 10.0
 class PendingRequest:
     payload: Any
     future: asyncio.Future
-    enqueued_at: float = field(default_factory=time.monotonic)
+    # time.time_ns(): the wait is a stage on the process timeline
+    # (utils/tracing.py), on the profiler's clock
+    enqueued_ns: int = field(default_factory=time.time_ns)
     # sampled-trace identity captured at submit: queue-wait is only
     # measurable at flush time, so the span is recorded retroactively
     # against the submitter's trace (None when unsampled — free).
@@ -50,15 +52,19 @@ class PendingRequest:
 
 def _collect_batchers(instances: list) -> list:
     """Fold live ContinuousBatcher stats into process metrics: request
-    and batch counters plus queue-wait quantiles. The stats dict stays
-    the one bookkeeper; this is a scrape-time reader."""
+    and batch counters, the queue wait as a sum since the batchers'
+    birth (read by delta, over ``batcher_requests_total``) and as
+    quantiles of the recent waits. The stats dict stays the one
+    bookkeeper; this is a scrape-time reader."""
     requests = batches = batched = 0
+    wait_seconds = 0.0
     waits: list[float] = []
     occupancy: list[int] = []
     for b in instances:
         requests += b._stats["requests"]
         batches += b._stats["batches"]
         batched += b._stats["batched_requests"]
+        wait_seconds += b._stats["queue_wait_seconds"]
         waits.extend(b._wait_samples)
         occupancy.extend(b._occupancy_samples)
     out = [
@@ -73,6 +79,11 @@ def _collect_batchers(instances: list) -> list:
         metrics.Sample(
             "batcher_batched_requests_total", batched, kind="counter",
             help="requests served through a batched flush",
+        ),
+        metrics.Sample(
+            "batcher_queue_wait_seconds_total", round(wait_seconds, 6),
+            kind="counter",
+            help="summed queue wait before flush, of the flushed requests",
         ),
     ]
     if waits:
@@ -150,7 +161,10 @@ class ContinuousBatcher:
         self._groups: dict[Hashable, list[PendingRequest]] = {}
         self._flush_tasks: dict[Hashable, asyncio.Task] = {}
         self._inflight_flushes: set[asyncio.Task] = set()
-        self._stats = {"requests": 0, "batches": 0, "batched_requests": 0}
+        self._stats = {
+            "requests": 0, "batches": 0, "batched_requests": 0,
+            "queue_wait_seconds": 0.0,
+        }
         # queue-wait samples (seconds), recorded per request at group
         # flush; bounded so stats cost stays flat under load
         self._wait_samples: deque[float] = deque(maxlen=1024)
@@ -237,19 +251,24 @@ class ContinuousBatcher:
         self._stats["batches"] += 1
         self._stats["batched_requests"] += len(group)
         self._occupancy_samples.append(len(group))
-        now = time.monotonic()
-        now_wall = time.time()
-        self._wait_samples.extend(now - r.enqueued_at for r in group)
+        now_ns = time.time_ns()
         for r in group:
+            # one measurement per request: the timeline's
+            # runtime.batch_wait stage, the sum, the recent samples and,
+            # when sampled, the request's batch.queue span
+            wait = tracing.record_stage(
+                "runtime.batch_wait", r.enqueued_ns, now_ns, span=False
+            )
+            self._stats["queue_wait_seconds"] += wait
+            self._wait_samples.append(wait)
             if r.trace_ctx is not None:
-                wait = now - r.enqueued_at
                 # parent = the submitter's enclosing span, started_at
                 # back-dated to the enqueue — the span sorts where the
                 # wait actually happened in the tree
                 tracing.record_span(
                     "batch.queue",
                     wait,
-                    started_at=now_wall - wait,
+                    started_at=r.enqueued_ns / 1e9,
                     parent_id=r.parent_span,
                     ctx=r.trace_ctx,
                     batch_size=len(group),
@@ -277,11 +296,13 @@ class ContinuousBatcher:
             self._cancel_timer(signature)
             await self._flush(signature)
         # drain flushes already in flight — close() is a real barrier,
-        # not a fire-and-forget (results land before shutdown proceeds)
-        while self._inflight_flushes:
-            await asyncio.gather(
-                *list(self._inflight_flushes), return_exceptions=True
-            )
+        # not a fire-and-forget (results land before shutdown proceeds).
+        # Only the unfinished ones: a flush that has finished stays in
+        # the set until its done-callback runs, and gather() over
+        # finished tasks returns without yielding to the loop that
+        # would run it (a close() right after the last result spun here)
+        while pending := [t for t in self._inflight_flushes if not t.done()]:
+            await asyncio.gather(*pending, return_exceptions=True)
 
     @property
     def stats(self) -> dict:
@@ -290,7 +311,7 @@ class ContinuousBatcher:
             s["batched_requests"] / s["batches"] if s["batches"] else 0.0
         )
         # how long requests sat in the queue before their group flushed
-        # (from PendingRequest.enqueued_at) — the latency cost of
+        # (from PendingRequest.enqueued_ns) — the latency cost of
         # batching, observable next to the throughput win
         waits = sorted(self._wait_samples)
         if waits:

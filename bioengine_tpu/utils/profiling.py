@@ -14,11 +14,22 @@ from typing import Optional
 
 
 def start_trace(
-    workspace_dir, trace_dir: Optional[str], active: Optional[str]
+    workspace_dir,
+    trace_dir: Optional[str],
+    active: Optional[str],
+    host_tracer_level: int = 1,
+    python_tracer_level: int = 0,
 ) -> str:
     """Start a jax.profiler trace; returns the trace dir. ``active``
     is the caller's currently-active dir (None when idle) — a second
-    start raises instead of silently nesting."""
+    start raises instead of silently nesting.
+
+    The defaults keep a serving process serving: host level 1 holds the
+    program's own stage annotations (``engine.*``, ``runtime.*``,
+    utils/tracing.py) and the runtime's transfer events; the Python
+    tracer is off. jax's own defaults (host level 2, Python tracer on)
+    recorded 1.3 million host events in 8 s on a serving v5e and cost a
+    quarter of its requests. Level 0 traces the device alone."""
     import jax
 
     if active:
@@ -27,7 +38,10 @@ def start_trace(
         Path(workspace_dir) / "profiles" / time.strftime("%Y%m%d-%H%M%S")
     )
     Path(trace_dir).mkdir(parents=True, exist_ok=True)
-    jax.profiler.start_trace(trace_dir)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = int(host_tracer_level)
+    options.python_tracer_level = int(python_tracer_level)
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
     return trace_dir
 
 

@@ -28,16 +28,34 @@ RPC RESULT frame and absorbed into the caller's buffer, so
 ``get_traces(trace_id=...)`` reconstructs ONE cross-process span tree
 with a per-stage latency breakdown.
 
-Timing discipline: durations come from ``time.monotonic()`` (wall
+**Stages** (engine and runtime hot path)::
+
+    with stage("engine.put", bytes=buf.nbytes) as st:
+        ...
+    stats.add(put_seconds=st.seconds)
+
+are measured ONCE, with ``time.time_ns()`` at both ends (the clock
+``jax.profiler`` stamps its traces with, so a stage can be laid beside
+the device's operations), and that one interval goes to every sink: an
+always-on bounded process-wide timeline (:func:`get_stages`), the
+caller's counters (``st.seconds``), a child span in the request's tree
+when the request is sampled, and a ``jax.profiler.TraceAnnotation`` so
+that a host-level trace shows the program's own stage names.
+:func:`record_stage` is the after-the-fact form for waits known only at
+their end. Stages are chunk-grained, never per tile or per op.
+
+Timing discipline: span durations come from ``time.monotonic()`` (wall
 ``time.time()`` deltas jump under NTP slew); ``started_at`` stays wall
-time for display. Spans are appended to the buffer when they OPEN, so
-``get_spans(include_open=True)`` shows in-flight work (a wedged
-request is visible while it hangs, not after).
+time for display. A stage's span carries the stage's own
+``time_ns()`` interval instead: one measurement. Spans are appended to
+the buffer when they OPEN, so ``get_spans(include_open=True)`` shows
+in-flight work (a wedged request is visible while it hangs, not after).
 """
 
 from __future__ import annotations
 
 import contextvars
+import itertools
 import os
 import random
 import threading
@@ -55,7 +73,7 @@ _lock = threading.Lock()
 # The whole per-request tracing state rides ONE contextvar holding an
 # immutable (trace_context, current_span_id, chip_accumulator) triple.
 # Contextvar reads are the per-request tax tracing charges even when
-# disabled; fusing the triple means carry()/activate()/to_wire() and
+# disabled; fusing the triple means activate()/to_wire() and
 # the scheduler's submit path pay one read where they used to pay two
 # or three. Every mutation allocates a fresh 3-tuple — cheap, and only
 # sampled requests / chip-accounted executions mutate at all.
@@ -73,10 +91,6 @@ def new_id() -> str:
     sandboxed kernels — minted per request on the serve hot path.
     The rpc layer uses this for call ids too (BE-PERF-302)."""
     return f"{random.getrandbits(64):016x}"
-
-
-# internal callers predate the public name
-_new_id = new_id
 
 
 def _new_trace_id() -> str:
@@ -211,38 +225,6 @@ def sampled() -> bool:
     return ctx is not None and ctx.sampled
 
 
-def carry(ctx: Optional[TraceContext], fn):
-    """Wrap ``fn`` so it runs with ``ctx`` (and the chip-seconds
-    accumulator, when one is active) installed — the bridge into worker
-    threads (engine dispatch thread, pipeline stages) where asyncio's
-    automatic contextvar propagation does not reach. Chip accounting
-    crosses even for unsampled requests: cost is accounting, not
-    sampled telemetry."""
-    st = _state.get()
-    acc = st[2]
-    is_sampled = ctx is not None and ctx.sampled
-    if not is_sampled and acc is None:
-        return fn
-
-    parent = st[1]
-
-    def wrapped(*args, **kwargs):
-        here = _state.get()
-        token = _state.set(
-            (
-                ctx if is_sampled else here[0],
-                parent if is_sampled else here[1],
-                acc if acc is not None else here[2],
-            )
-        )
-        try:
-            return fn(*args, **kwargs)
-        finally:
-            _state.reset(token)
-
-    return wrapped
-
-
 # ---------------------------------------------------------------------------
 # chip-seconds accounting (request-scoped device-cost accumulator)
 # ---------------------------------------------------------------------------
@@ -251,9 +233,10 @@ def carry(ctx: Optional[TraceContext], fn):
 class ChipSecondsAccumulator:
     """Mutable per-request device-cost sink. The replica installs one
     around instance execution; every engine ``predict`` underneath
-    (including on the dispatch thread, via :func:`carry`) adds its
-    wall seconds x mesh width. Unlike spans this is NOT sampled —
-    chip-seconds are the billing/scheduling signal and must be exact."""
+    (including on the dispatch thread, which runs each task in a copy
+    of the submitter's context) adds its wall seconds x mesh width.
+    Unlike spans this is NOT sampled — chip-seconds are the
+    billing/scheduling signal and must be exact."""
 
     __slots__ = ("seconds",)
 
@@ -293,7 +276,7 @@ def span(name: str, **attrs: Any):
     Appended to the buffer at OPEN (visible in-flight), completed in
     place at close. When a sampled trace context is active the span
     carries its trace_id and feeds the context's collector."""
-    span_id = _new_id()
+    span_id = new_id()
     st = _state.get()
     ctx, parent = st[0], st[1]
     token = _state.set((ctx, span_id, st[2]))
@@ -375,7 +358,7 @@ def record_span(
     if ctx is None or not ctx.sampled:
         return None
     record = {
-        "span_id": _new_id(),
+        "span_id": new_id(),
         "parent_id": parent_id if parent_id is not None else ctx.span_id,
         "name": name,
         "attrs": attrs,
@@ -406,6 +389,194 @@ def absorb_spans(spans: list) -> int:
             _spans.append(dict(s))
             added += 1
     return added
+
+
+# ---------------------------------------------------------------------------
+# stages: one measurement, every sink
+# ---------------------------------------------------------------------------
+
+# two minutes of the densest traffic the engine serves (about 30 batches
+# a second x 8 stages), a few megabytes
+MAX_STAGES = 32768
+
+# (name, start_ns, end_ns, thread, request_seq, attrs or None), in the
+# order the stages ENDED. deque.append is atomic: the hot path takes no
+# lock
+_stages: deque[tuple] = deque(maxlen=MAX_STAGES)
+_request_seq: contextvars.ContextVar[int] = contextvars.ContextVar(
+    "bioengine_request_seq", default=0
+)
+_request_counter = itertools.count(1)
+_annotation_cls: Any = None
+
+
+def begin_request() -> int:
+    """Number the engine request this context now has in hand; every
+    stage recorded under the context (the pipeline's own threads copy
+    it) carries the number as ``request_seq`` (0 outside a request)."""
+    seq = next(_request_counter)
+    _request_seq.set(seq)
+    return seq
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``: the stage's name on the
+    host plane of a profiler trace (host level >= 1), a flag check while
+    none runs. Imported at the first stage, not with this module: the
+    control plane traces spans without jax."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation as _annotation_cls
+    return _annotation_cls(name)
+
+
+class stage:
+    """Context manager around one stage. After the block ``seconds``,
+    ``start_ns`` and ``end_ns`` hold the one measurement, which the
+    caller hands to its counters; ``attrs`` may be filled inside the
+    block; ``span`` is the stage's span record when the request is
+    sampled (spans opened inside chain under it), else None."""
+
+    __slots__ = (
+        "name", "attrs", "start_ns", "end_ns", "span",
+        "_annotation", "_ctx", "_token",
+    )
+
+    def __init__(self, name: str, **attrs: Any):
+        self.name = name
+        self.attrs = attrs
+        self.start_ns = self.end_ns = 0
+        self.span: Optional[dict] = None
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e9
+
+    def __enter__(self) -> "stage":
+        st = _state.get()
+        ctx = st[0]
+        self._annotation = annotation = _annotation(self.name)
+        annotation.__enter__()
+        self.start_ns = time.time_ns()
+        if ctx is not None and ctx.sampled:
+            span_id = new_id()
+            self._ctx = ctx
+            self._token = _state.set((ctx, span_id, st[2]))
+            self.span = record = {
+                "span_id": span_id,
+                "parent_id": st[1],
+                "name": self.name,
+                "attrs": self.attrs,
+                "started_at": self.start_ns / 1e9,
+                "trace_id": ctx.trace_id,
+            }
+            with _lock:
+                _spans.append(record)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.end_ns = end = time.time_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
+        _stages.append(
+            (
+                self.name, self.start_ns, end,
+                threading.current_thread().name, _request_seq.get(),
+                self.attrs or None,
+            )
+        )
+        record = self.span
+        if record is not None:
+            _state.reset(self._token)
+            if exc is not None:
+                record["error"] = f"{type(exc).__name__}: {exc}"
+            record["duration_s"] = round((end - self.start_ns) / 1e9, 6)
+            if self._ctx.collector is not None:
+                self._ctx.collector.append(record)
+        return False
+
+
+def record_stage(
+    name: str, start_ns: int, end_ns: int, span: bool = True, **attrs: Any
+) -> float:
+    """After-the-fact stage for a wait known only at its end (both ends
+    ``time.time_ns()``); returns its seconds for the caller's counters.
+    Also a span under the current one when the current trace is sampled,
+    unless ``span`` is false (the caller then records one of its own
+    from the same interval, against another request's context)."""
+    _stages.append(
+        (
+            name, start_ns, end_ns, threading.current_thread().name,
+            _request_seq.get(), attrs or None,
+        )
+    )
+    seconds = (end_ns - start_ns) / 1e9
+    if span:
+        ctx, parent = current_trace_and_span()
+        if ctx is not None and ctx.sampled:
+            record_span(
+                name, seconds, started_at=start_ns / 1e9, parent_id=parent,
+                ctx=ctx, **attrs,
+            )
+    return seconds
+
+
+def get_stages(
+    since_ns: Optional[int] = None,
+    until_ns: Optional[int] = None,
+    name: Optional[str] = None,
+    max_stages: Optional[int] = None,
+) -> list[dict]:
+    """The timeline's stages that overlap [``since_ns``, ``until_ns``]
+    (``time.time_ns()``), oldest end first, shaped like spans (``name``,
+    ``started_at``, ``duration_s``, ``attrs``) with the exact
+    ``start_ns``/``end_ns``, the recording ``thread`` and the
+    ``request_seq`` beside them."""
+    out = []
+    for s_name, start, end, thread, seq, attrs in tuple(_stages):
+        if name is not None and s_name != name:
+            continue
+        if since_ns is not None and end < since_ns:
+            continue
+        if until_ns is not None and start > until_ns:
+            continue
+        out.append(
+            {
+                "name": s_name,
+                "started_at": start / 1e9,
+                "duration_s": (end - start) / 1e9,
+                "start_ns": start,
+                "end_ns": end,
+                "thread": thread,
+                "request_seq": seq,
+                "attrs": dict(attrs) if attrs else {},
+            }
+        )
+    return out[-max_stages:] if max_stages else out
+
+
+def child_stage_seconds(record: dict) -> dict:
+    """Name -> summed seconds of the closed spans directly under
+    ``record``, a span of the current trace: a sampled request's own
+    stages (a handful of records in the context's collector; the ring
+    is the fallback)."""
+    parent = record["span_id"]
+    sums: dict[str, float] = {}
+    ctx = _state.get()[0]
+    if ctx is not None and ctx.collector is not None:
+        spans = list(ctx.collector)
+    else:
+        with _lock:
+            spans = list(_spans)
+    for s in spans:
+        if s.get("parent_id") == parent and "duration_s" in s:
+            sums[s["name"]] = sums.get(s["name"], 0.0) + s["duration_s"]
+    return sums
+
+
+def clear_stages() -> int:
+    n = len(_stages)
+    _stages.clear()
+    return n
 
 
 # ---------------------------------------------------------------------------

@@ -427,16 +427,25 @@ class BioEngineWorker:
     # ---- profiling (SURVEY §5.1: jax.profiler surface) ----------------------
 
     def start_profiling(
-        self, trace_dir: Optional[str] = None, context: Optional[dict] = None
+        self,
+        trace_dir: Optional[str] = None,
+        host_tracer_level: int = 1,
+        python_tracer_level: int = 0,
+        context: Optional[dict] = None,
     ) -> dict:
         """Start a jax.profiler trace covering everything the worker's
         process executes (serving replicas included — they run
-        in-process). Inspect with tensorboard/xprof. Admin-only."""
+        in-process). Inspect with tensorboard/xprof. The defaults (host
+        level 1, Python tracer off) record the program's stage
+        annotations without slowing the requests they time
+        (utils/profiling.py). Admin-only."""
         check_permissions(context, self.admin_users, "start_profiling")
         from bioengine_tpu.utils import profiling
 
         self._profile_dir = profiling.start_trace(
-            self.workspace_dir, trace_dir, getattr(self, "_profile_dir", None)
+            self.workspace_dir, trace_dir, getattr(self, "_profile_dir", None),
+            host_tracer_level=host_tracer_level,
+            python_tracer_level=python_tracer_level,
         )
         self.logger.info(f"profiling started -> {self._profile_dir}")
         return {"trace_dir": self._profile_dir, "profiling": True}
@@ -457,6 +466,8 @@ class BioEngineWorker:
         replica_id: Optional[str] = None,
         action: str = "start",
         trace_dir: Optional[str] = None,
+        host_tracer_level: int = 1,
+        python_tracer_level: int = 0,
         context: Optional[dict] = None,
     ) -> dict:
         """Profile ONE replica of a live deployment: resolves the
@@ -506,11 +517,14 @@ class BioEngineWorker:
                 "stop": "stop_profiling",
                 "memory": "memory_profile",
             }[action]
-            kwargs = (
-                {"trace_dir": trace_dir}
-                if action == "start" and trace_dir
-                else {}
-            )
+            kwargs = {}
+            if action == "start":
+                kwargs = {
+                    "host_tracer_level": host_tracer_level,
+                    "python_tracer_level": python_tracer_level,
+                }
+                if trace_dir:
+                    kwargs["trace_dir"] = trace_dir
             if getattr(replica, "is_mesh", False):
                 # a mesh replica spans hosts; jax.profiler is
                 # process-global per host, so profile every shard host
@@ -545,7 +559,12 @@ class BioEngineWorker:
             return {**target, "host_id": replica.host_id, **result}
         # local replica: it runs in THIS process
         if action == "start":
-            result = self.start_profiling(trace_dir=trace_dir, context=context)
+            result = self.start_profiling(
+                trace_dir=trace_dir,
+                host_tracer_level=host_tracer_level,
+                python_tracer_level=python_tracer_level,
+                context=context,
+            )
         elif action == "stop":
             result = self.stop_profiling(context=context)
         else:
@@ -560,19 +579,34 @@ class BioEngineWorker:
         include_open: bool = False,
         limit: Optional[int] = None,
         since: Optional[float] = None,
+        stages: bool = False,
         context: Optional[dict] = None,
     ) -> Any:
         """Recent spans (control-plane events + sampled request
         traces), newest last. With ``trace_id`` returns that request's
         reconstructed cross-process span tree (remote spans arrive
         piggybacked on RPC results) with a per-stage latency rollup.
-        Paginate with ``limit`` (caps the returned spans; alias of
-        ``max_spans``) and ``since`` (wall-clock ``started_at`` cursor:
-        pass the newest span's ``started_at`` from the previous pull) —
-        repeated polling never re-ships the whole buffer. Admin-only."""
+        With ``stages`` returns the always-on stage timeline of every
+        request, sampled or not (``engine.*`` / ``runtime.*`` records
+        shaped like spans, with ``thread`` and ``request_seq``;
+        ``name``, ``limit`` and ``since`` apply). Paginate with
+        ``limit`` (caps the returned spans; alias of ``max_spans``) and
+        ``since`` (wall-clock ``started_at`` cursor: pass the newest
+        span's ``started_at`` from the previous pull) — repeated
+        polling never re-ships the whole buffer. Admin-only."""
         check_permissions(context, self.admin_users, "get_traces")
-        from bioengine_tpu.utils.tracing import build_trace_tree, get_spans
+        from bioengine_tpu.utils.tracing import (
+            build_trace_tree,
+            get_spans,
+            get_stages,
+        )
 
+        if stages:
+            return get_stages(
+                since_ns=None if since is None else int(since * 1e9),
+                name=name,
+                max_stages=limit if limit is not None else max_spans,
+            )
         if trace_id is not None:
             return build_trace_tree(trace_id)
         return get_spans(

@@ -923,14 +923,23 @@ class WorkerHost:
     # PR 5 RTLD_DEEPBIND codec fix makes jax.profiler safe to enable
     # in a serving process) ------------------------------------------------
 
-    def start_profiling(self, trace_dir: Optional[str] = None) -> dict:
+    def start_profiling(
+        self,
+        trace_dir: Optional[str] = None,
+        host_tracer_level: int = 1,
+        python_tracer_level: int = 0,
+    ) -> dict:
         """Start a jax.profiler trace covering everything this host
         process executes (its replicas included). One trace at a time
-        per process — jax.profiler is process-global."""
+        per process — jax.profiler is process-global. The defaults
+        (host level 1, Python tracer off) record the program's stage
+        annotations without slowing the requests they time."""
         from bioengine_tpu.utils import profiling
 
         self._profile_dir = profiling.start_trace(
-            self.workspace_dir, trace_dir, getattr(self, "_profile_dir", None)
+            self.workspace_dir, trace_dir, getattr(self, "_profile_dir", None),
+            host_tracer_level=host_tracer_level,
+            python_tracer_level=python_tracer_level,
         )
         self.logger.info(f"profiling started -> {self._profile_dir}")
         return {

@@ -644,3 +644,242 @@ class TestSlowRequestLog:
         assert "app=obs-app" in msg
         assert "deployment=obs_dep" in msg
         assert re.search(r"duration_ms=\d+", msg)
+
+
+# ---------------------------------------------------------------------------
+# stages: the engine's timeline, one measurement for every sink
+# ---------------------------------------------------------------------------
+
+
+def _stage_engine():
+    import numpy as np
+
+    from bioengine_tpu.runtime.engine import EngineConfig, InferenceEngine
+    from bioengine_tpu.runtime.program_cache import CompiledProgramCache
+
+    return InferenceEngine(
+        "stage-toy",
+        lambda params, x: x * params,
+        np.float32(2.0),
+        config=EngineConfig(max_tile=16, tile=8, tile_overlap=2, tile_batch=4),
+        cache=CompiledProgramCache(),
+    )
+
+
+class TestStages:
+    async def test_sampled_tree_holds_the_stages_under_engine_predict(self):
+        """A sampled request's tree: engine.queue and engine.request
+        under the caller's span, engine.predict inside the request,
+        the per-chunk stages (the cut and stitch threads' too) under
+        engine.predict, whose stage_seconds is their sums."""
+        import numpy as np
+
+        eng = _stage_engine()
+        x = np.ones((1, 40, 40, 1), np.float32)
+        try:
+            await eng.predict_async(x)  # compile
+            ctx = tracing.maybe_start_trace(sample=True)
+            token = tracing.activate(ctx)
+            try:
+                with tracing.span("caller") as caller:
+                    out = await eng.predict_async(x)
+            finally:
+                tracing.deactivate(token)
+        finally:
+            eng.close()
+        assert float(out.sum()) == pytest.approx(2.0 * 40 * 40, rel=1e-3)
+        tree = tracing.build_trace_tree(ctx.trace_id)
+        (root,) = tree["tree"]
+        assert root["span_id"] == caller["span_id"]
+        assert [c["name"] for c in root["children"]] == [
+            "engine.queue", "engine.request",
+        ]
+        (predict,) = root["children"][1]["children"]
+        assert predict["name"] == "engine.predict"
+        own = {}
+        for child in predict["children"]:
+            assert child["trace_id"] == ctx.trace_id
+            own[child["name"]] = own.get(child["name"], 0.0) + child["duration_s"]
+        assert set(own) == {
+            "engine.cut", "engine.put", "engine.dispatch",
+            "engine.device_wait", "engine.d2h", "engine.stitch",
+        }
+        stage_seconds = predict["attrs"]["stage_seconds"]
+        for name, seconds in own.items():
+            assert stage_seconds[name.removeprefix("engine.")] == pytest.approx(
+                seconds, abs=1e-5
+            )
+        assert stage_seconds["readback"] == pytest.approx(
+            stage_seconds["device_wait"] + stage_seconds["d2h"], abs=2e-6
+        )
+        assert stage_seconds["compute"] > 0
+        assert predict["attrs"]["chip_seconds"] == pytest.approx(
+            predict["duration_s"], abs=1e-5
+        )
+        # the same intervals stand on the timeline, sampled or not
+        (on_timeline,) = tracing.get_stages(
+            int(predict["started_at"] * 1e9) - 1000, name="engine.predict"
+        )
+        assert on_timeline["duration_s"] == pytest.approx(
+            predict["duration_s"], abs=1e-6
+        )
+
+    async def test_get_traces_returns_stages(self):
+        from types import SimpleNamespace
+
+        from bioengine_tpu.utils.permissions import create_context
+        from bioengine_tpu.worker.worker import BioEngineWorker
+
+        worker = SimpleNamespace(admin_users=["admin"])
+        admin = create_context("admin", workspace="bioengine")
+        since = time.time()
+        with tracing.stage("verb.stage", bytes=7):
+            pass
+        with tracing.stage("verb.other"):
+            pass
+        with pytest.raises(PermissionError):
+            BioEngineWorker.get_traces(
+                worker, stages=True,
+                context=create_context("anon", workspace="public"),
+            )
+        got = BioEngineWorker.get_traces(
+            worker, stages=True, since=since, context=admin
+        )
+        assert [s["name"] for s in got][-2:] == ["verb.stage", "verb.other"]
+        (stage,) = BioEngineWorker.get_traces(
+            worker, stages=True, name="verb.stage", since=since, context=admin
+        )
+        # shaped like a span, with the thread and the request beside it
+        assert {
+            "name", "started_at", "duration_s", "attrs", "thread", "request_seq",
+        } <= set(stage)
+        assert stage["attrs"] == {"bytes": 7} and stage["started_at"] >= since
+        assert len(
+            BioEngineWorker.get_traces(worker, stages=True, limit=1, context=admin)
+        ) == 1
+
+    @pytest.mark.parametrize(
+        "given, want", [({}, (1, 0)), ({"host_tracer_level": 2,
+                                        "python_tracer_level": 1}, (2, 1))],
+    )
+    async def test_profiling_passes_profile_options(
+        self, given, want, tmp_path, monkeypatch
+    ):
+        """An operator's trace holds the stage annotations (host level
+        1), not a million Python events (Python tracer off)."""
+        import jax
+
+        from bioengine_tpu.utils import profiling
+
+        seen = {}
+        monkeypatch.setattr(
+            jax.profiler, "start_trace",
+            lambda d, profiler_options=None: seen.update(
+                dir=d, options=profiler_options
+            ),
+        )
+        trace_dir = profiling.start_trace(tmp_path, None, None, **given)
+        assert seen["dir"] == trace_dir and Path(trace_dir).is_dir()
+        options = seen["options"]
+        assert (options.host_tracer_level, options.python_tracer_level) == want
+
+    async def test_batcher_counts_its_waits_as_a_sum(self):
+        from bioengine_tpu.serving import ContinuousBatcher
+
+        def total():
+            (series,) = metrics.collect()["batcher_queue_wait_seconds_total"][
+                "series"
+            ]
+            return series["value"]
+
+        async def double(signature, payloads):
+            return [p * 2 for p in payloads]
+
+        batcher = ContinuousBatcher(double, max_batch=4, max_wait_ms=20.0)
+        before, since = total(), time.time_ns()
+        assert await asyncio.gather(
+            batcher.submit("k", 1), batcher.submit("k", 2)
+        ) == [2, 4]
+        await batcher.close()
+        waits = [
+            s["duration_s"]
+            for s in tracing.get_stages(since, name="runtime.batch_wait")
+        ]
+        assert len(waits) == 2 and min(waits) >= 0.015
+        assert batcher.stats["queue_wait_seconds"] == pytest.approx(sum(waits))
+        assert total() - before == pytest.approx(sum(waits), abs=2e-6)
+
+    async def test_model_runner_stages_cover_the_host_work(self, tmp_path):
+        """runtime.assemble / runtime.split on the loop, runtime.preprocess
+        / runtime.postprocess on the dispatch thread inside
+        engine.request, their seconds in the engine's PipelineStats."""
+        import importlib.util
+
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        import yaml
+
+        from bioengine_tpu.models.unet import UNet2D
+        from bioengine_tpu.runtime.convert import save_params_npz
+
+        package = tmp_path / "stage-unet"
+        package.mkdir()
+        x = np.random.default_rng(0).normal(size=(1, 64, 64, 1)).astype(np.float32)
+        model = UNet2D(features=(4, 8), out_channels=1)
+        save_params_npz(
+            str(package / "weights.npz"),
+            model.init(jax.random.key(0), jnp.asarray(x))["params"],
+        )
+        (package / "rdf.yaml").write_text(yaml.safe_dump({
+            "type": "model", "name": "Stage UNet", "description": "stages",
+            "inputs": [{"name": "input0", "axes": "byxc"}],
+            "outputs": [{"name": "output0", "axes": "byxc"}],
+            "weights": {"jax_params": {
+                "source": "weights.npz",
+                "architecture": {
+                    "name": "unet2d",
+                    "kwargs": {"features": [4, 8], "out_channels": 1},
+                },
+            }},
+        }))
+        spec = importlib.util.spec_from_file_location(
+            "stage_mr_rt",
+            Path(__file__).resolve().parent.parent
+            / "apps" / "model-runner" / "runtime_deployment.py",
+        )
+        rt = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(rt)
+        dep = rt.RuntimeDeployment(batch_max=8, batch_wait_ms=5.0)
+        await dep.async_init()
+        try:
+            await dep.predict(str(package), x)  # load and compile
+            since = time.time_ns()
+            reply = await dep.predict(str(package), x)
+            (stats,) = dep.pipeline_stats().values()
+        finally:
+            await dep.close()
+        assert reply["output0"].shape == x.shape
+        stages = {s["name"]: s for s in tracing.get_stages(since)}
+        assert {
+            "runtime.batch_wait", "runtime.assemble", "engine.queue",
+            "engine.request", "runtime.preprocess", "engine.predict",
+            "runtime.postprocess", "runtime.split",
+        } <= set(stages)
+        request = stages["engine.request"]
+        for name in ("runtime.preprocess", "engine.predict", "runtime.postprocess"):
+            assert stages[name]["thread"] == request["thread"]
+            assert request["start_ns"] <= stages[name]["start_ns"]
+            assert stages[name]["end_ns"] <= request["end_ns"]
+        assert request["thread"].startswith("dispatch-")
+        loop_thread = stages["runtime.assemble"]["thread"]
+        assert loop_thread == stages["runtime.split"]["thread"] != request["thread"]
+        # in the order a request passes them
+        order = [
+            "runtime.batch_wait", "runtime.assemble", "engine.queue",
+            "engine.request", "runtime.split",
+        ]
+        ends = [stages[n]["end_ns"] for n in order]
+        assert ends == sorted(ends)
+        assert stats["requests"] == 2 and stats["preprocess_seconds"] >= 0
+        assert stats["rows_executed"] == stats["rows_useful"] == 2

@@ -1,4 +1,5 @@
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ from bioengine_tpu.runtime.rdf import (
     load_model_rdf,
     to_nhwc,
 )
+from bioengine_tpu.utils import tracing
 
 pytestmark = pytest.mark.unit
 
@@ -748,3 +750,148 @@ class TestCheckpointService:
             restored = ckpt.restore(7, sharded_template)
         leaf = jax.tree.leaves(restored.params)[0]
         assert len(leaf.sharding.device_set) == 4
+
+
+class TestEngineStages:
+    """The stage timeline (utils/tracing.py): one time_ns() pair per
+    stage feeds the process-wide timeline and the PipelineStats sums."""
+
+    ON_DISPATCH = (
+        "engine.queue", "engine.request", "engine.predict", "engine.put",
+        "engine.dispatch", "engine.device_wait", "engine.d2h",
+    )
+    SUMMED = {
+        "engine.cut": "cut_seconds", "engine.put": "put_seconds",
+        "engine.dispatch": "dispatch_seconds",
+        "engine.device_wait": "device_wait_seconds",
+        "engine.d2h": "d2h_seconds", "engine.stitch": "stitch_seconds",
+        "engine.queue": "queue_seconds",
+    }
+
+    def _engine(self, **cfg_overrides):
+        cfg_kw = dict(max_tile=64, tile=48, tile_overlap=16, tile_batch=16)
+        cfg_kw.update(cfg_overrides)
+        return InferenceEngine(
+            "staged",
+            lambda p, x: x * p["scale"] + 0.25,
+            {"scale": jnp.asarray(1.7)},
+            config=EngineConfig(**cfg_kw),
+            cache=CompiledProgramCache(),
+        )
+
+    @staticmethod
+    def _sums(eng):
+        stats = eng.pipeline_stats
+        return {f: getattr(stats, f) for f in stats._FIELDS}
+
+    @staticmethod
+    def _stages_of(eng, since_ns):
+        return [
+            s for s in tracing.get_stages(since_ns)
+            if s["thread"].startswith(("dispatch-staged", "pipeline-"))
+        ]
+
+    @pytest.mark.parametrize("path", ["tiled", "direct", "serial"])
+    def test_a_prediction_leaves_its_stages(self, path):
+        eng = self._engine(pipeline_depth=0 if path == "serial" else 2)
+        size = 40 if path == "direct" else 112
+        x = np.random.rand(1, size, size, 1).astype(np.float32)
+        try:
+            eng.submit(eng.predict, x).result()  # compile outside the count
+            before, since = self._sums(eng), time.time_ns()
+            out = eng.submit(eng.predict, x).result()
+            after = self._sums(eng)
+        finally:
+            eng.close()
+        np.testing.assert_allclose(out, x * 1.7 + 0.25, rtol=1e-4, atol=1e-5)
+        stages = self._stages_of(eng, since)
+        by_name = {}
+        for s in stages:
+            by_name.setdefault(s["name"], []).append(s)
+        assert set(by_name) == set(self.ON_DISPATCH) | {
+            "engine.cut", "engine.stitch"
+        }
+        (request,) = by_name["engine.request"]
+        (queue,) = by_name["engine.queue"]
+        assert request["request_seq"] > 0
+        for s in stages:
+            assert s["end_ns"] >= s["start_ns"]
+            assert s["request_seq"] == request["request_seq"]
+            if s is not queue:  # the wait ends where the request begins
+                assert request["start_ns"] <= s["start_ns"]
+                assert s["end_ns"] <= request["end_ns"]
+        assert queue["end_ns"] <= request["start_ns"]
+        for name in self.ON_DISPATCH:
+            assert {s["thread"] for s in by_name[name]} == {"dispatch-staged_0"}
+        helpers = path == "tiled"
+        assert {s["thread"] for s in by_name["engine.cut"]} == {
+            "pipeline-cut" if helpers else "dispatch-staged_0"
+        }
+        assert {s["thread"] for s in by_name["engine.stitch"]} == {
+            "pipeline-stitch" if helpers else "dispatch-staged_0"
+        }
+        # one measurement: the timeline's durations ARE the sums' deltas
+        for name, field in self.SUMMED.items():
+            assert sum(s["duration_s"] for s in by_name[name]) == pytest.approx(
+                after[field] - before[field], abs=1e-9
+            ), name
+        assert after["readback_seconds"] - before["readback_seconds"] == (
+            pytest.approx(
+                after["device_wait_seconds"] - before["device_wait_seconds"]
+                + after["d2h_seconds"] - before["d2h_seconds"], abs=1e-9,
+            )
+        )
+        assert after["requests"] - before["requests"] == 1
+        assert after["items"] - before["items"] == 1
+        assert after["chunks"] - before["chunks"] == len(by_name["engine.put"])
+        assert after["runs"] - before["runs"] == (1 if path == "tiled" else 0)
+        (put,) = by_name["engine.put"]
+        assert put["attrs"]["bytes"] == after["h2d_bytes"] - before["h2d_bytes"]
+
+    def test_nine_tiles_in_a_chunk_of_sixteen(self):
+        eng = self._engine()
+        # stride 32 over 112 px: tile starts 0, 32, 64 on each axis
+        eng.predict(np.random.rand(1, 112, 112, 1).astype(np.float32))
+        stats = eng.pipeline_stats
+        assert (stats.rows_useful, stats.rows_executed) == (9, 16)
+        assert stats.d2h_bytes == stats.h2d_bytes == 16 * 64 * 64 * 4
+
+    def test_engine_queue_counts_two_tasks_submitted_at_once(self):
+        eng = self._engine()
+        since = time.time_ns()
+        try:
+            slow = eng.submit(time.sleep, 0.05)
+            fast = eng.submit(lambda: None)
+            slow.result(), fast.result()
+        finally:
+            eng.close()
+        queues = [
+            s for s in self._stages_of(eng, since) if s["name"] == "engine.queue"
+        ]
+        assert len(queues) == 2
+        assert queues[0]["request_seq"] != queues[1]["request_seq"]
+        # the second task waited for the dispatch thread while the first ran
+        assert queues[1]["duration_s"] >= 0.04
+        stats = eng.pipeline_stats
+        assert stats.requests == 2
+        assert stats.queue_seconds == pytest.approx(
+            sum(s["duration_s"] for s in queues), abs=1e-9
+        )
+
+    def test_timeline_is_bounded(self):
+        now = time.time_ns()
+        for i in range(tracing.MAX_STAGES + 50):
+            tracing.record_stage("bound.test", now + i, now + i + 1)
+        stages = tracing.get_stages()
+        assert len(stages) == tracing.MAX_STAGES
+        # the newest survive, the oldest roll off
+        assert stages[-1]["start_ns"] == now + tracing.MAX_STAGES + 49
+        assert tracing.get_stages(name="bound.test", max_stages=3) == stages[-3:]
+        tracing.clear_stages()
+
+    def test_programs_are_named_after_model_and_shape(self):
+        eng = self._engine()
+        program = eng._program((2, 64, 64, 1), np.float32)
+        assert program.__name__ == "engine_staged_2x64x64x1"
+        lowered = program.lower(eng.params, jnp.zeros((2, 64, 64, 1)))
+        assert "jit_engine_staged_2x64x64x1" in lowered.as_text()[:400]
