@@ -20,10 +20,18 @@ random init. Parameter path names below are chosen to line up 1:1 with
 the torch state_dict keys — change them only together with the name
 map.
 
-TPU notes: attention/matmuls run bf16 on the MXU; the decomposed
-rel-pos bias is two small einsums fused by XLA; window partition is a
-reshape (no data movement beyond layout). Shapes are static per
-(H, W) bucket as everywhere else in the framework.
+TPU notes: matmuls run bf16 on the MXU. Attention never writes its
+(B*heads, N, N) scores to HBM: the decomposed rel-pos bias
+``bias_h[n, k_h] + bias_w[n, k_w]`` is folded into the QK^T contraction
+(``SAMAttention``: q gets the two bias rows appended, k the one-hot of
+its own row and column, so ``q' k'^T`` is the biased score; for cpsam
+64 + 32 + 32 = 128, the lane width) and ``softmax(q' k'^T) v`` runs
+through ``ops.attention`` — the fused Pallas kernel on a TPU, its plain
+XLA reference elsewhere. Adding the bias at score size instead (a 5-D
+broadcast, a copy and an f32 softmax over 512 MB a block) was 60-65 % of
+the served step's device time (PERF.md section 6, PR 27). Window
+partition is a reshape (no data movement beyond layout). Shapes are
+static per (H, W) bucket as everywhere else in the framework.
 """
 
 from __future__ import annotations
@@ -33,6 +41,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+
+from bioengine_tpu.ops.attention import attention
 
 
 def _resize_rel_pos(rel_pos: jnp.ndarray, needed: int) -> jnp.ndarray:
@@ -69,7 +79,16 @@ class SAMAttention(nn.Module):
     for global ones): the parameters are declared at that checkpoint
     shape — so converted weights always load — and resized at use when
     the runtime grid differs (flax validates provided param shapes
-    against the declared shape at apply time)."""
+    against the declared shape at apply time).
+
+    The bias of query n = (h, w) against key (k_h, k_w) is
+    ``q[n]·Rh[h, k_h] + q[n]·Rw[w, k_w]`` (SAM's
+    ``add_decomposed_rel_pos``, unscaled q). It is not added to a score
+    tensor: with ``q' = [q·hd^-½, bias_h[n, :], bias_w[n, :]]`` and
+    ``k' = [k, onehot(k_h, H), onehot(k_w, W)]`` the contraction
+    ``q'·k'`` is the biased score term for term, at depth hd + H + W and
+    scale 1. The one-hots are exact in any dtype and the contraction
+    accumulates in f32."""
 
     dim: int
     num_heads: int
@@ -79,15 +98,13 @@ class SAMAttention(nn.Module):
     @nn.compact
     def __call__(self, x):
         B, H, W, _ = x.shape
-        hd = self.dim // self.num_heads
+        nh, hd = self.num_heads, self.dim // self.num_heads
         qkv = nn.Dense(3 * self.dim, dtype=self.dtype, name="qkv")(x)
-        qkv = qkv.reshape(B, H * W, 3, self.num_heads, hd)
+        qkv = qkv.reshape(B, H * W, 3, nh, hd)
         q, k, v = jnp.moveaxis(qkv, 2, 0)  # (B, N, nh, hd)
-        q = jnp.moveaxis(q, 2, 1).reshape(B * self.num_heads, H * W, hd)
-        k = jnp.moveaxis(k, 2, 1).reshape(B * self.num_heads, H * W, hd)
-        v = jnp.moveaxis(v, 2, 1).reshape(B * self.num_heads, H * W, hd)
-
-        attn = (q * (hd**-0.5)) @ jnp.swapaxes(k, -2, -1)  # (B*nh, N, N)
+        q = jnp.moveaxis(q, 2, 1)  # (B, nh, N, hd)
+        k = jnp.moveaxis(k, 2, 1)
+        v = jnp.moveaxis(v, 2, 1)
 
         rel_h = self.param(
             "rel_pos_h",
@@ -103,17 +120,29 @@ class SAMAttention(nn.Module):
         )
         Rh = _rel_pos_gather(H, H, rel_h).astype(self.dtype)  # (H, H, hd)
         Rw = _rel_pos_gather(W, W, rel_w).astype(self.dtype)  # (W, W, hd)
-        q_r = q.reshape(B * self.num_heads, H, W, hd)
-        bias_h = jnp.einsum("bhwc,hkc->bhwk", q_r, Rh)
-        bias_w = jnp.einsum("bhwc,wkc->bhwk", q_r, Rw)
-        attn = attn.reshape(B * self.num_heads, H, W, H, W)
-        attn = attn + bias_h[:, :, :, :, None] + bias_w[:, :, :, None, :]
-        attn = attn.reshape(B * self.num_heads, H * W, H * W)
-
-        attn = jax.nn.softmax(attn.astype(jnp.float32), axis=-1).astype(
-            self.dtype
+        q_r = q.reshape(B, nh, H, W, hd)
+        bias_h = jnp.einsum("bnhwc,hkc->bnhwk", q_r, Rh)
+        bias_w = jnp.einsum("bnhwc,wkc->bnhwk", q_r, Rw)
+        q_fold = jnp.concatenate(
+            [
+                q * (hd**-0.5),
+                bias_h.reshape(B, nh, H * W, H),
+                bias_w.reshape(B, nh, H * W, W),
+            ],
+            axis=-1,
         )
-        out = (attn @ v).reshape(B, self.num_heads, H * W, hd)
+        key_pos = jnp.concatenate(
+            [
+                jnp.repeat(jnp.eye(H, dtype=self.dtype), W, axis=0),
+                jnp.tile(jnp.eye(W, dtype=self.dtype), (H, 1)),
+            ],
+            axis=-1,
+        )  # (N, H + W): key n = (n // W, n % W), one-hot twice
+        k_fold = jnp.concatenate(
+            [k, jnp.broadcast_to(key_pos, (B, nh, H * W, H + W))], axis=-1
+        )
+
+        out = attention(q_fold, k_fold, v, scale=1.0)  # (B, nh, N, hd)
         out = jnp.moveaxis(out, 1, 2).reshape(B, H, W, self.dim)
         return nn.Dense(self.dim, dtype=self.dtype, name="proj")(out)
 

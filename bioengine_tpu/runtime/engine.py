@@ -429,6 +429,11 @@ class InferenceEngine:
                 # a warm replica's program list reads hit/hit/hit, a
                 # cold one's carries the real 20-40 s entries
                 "cache_hits": {k: v["cache_hit"] for k, v in mine.items()},
+                # attention calls traced into each program, by path and
+                # N: a served cpsam program reads {"fused:1024": 24}
+                "attention_paths": {
+                    k: v["attention_paths"] for k, v in mine.items()
+                },
                 "persistent_hits": sum(
                     1 for v in mine.values() if v["cache_hit"]
                 ),

@@ -18,6 +18,7 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Hashable, Optional
 
+from bioengine_tpu.ops.attention import traced_paths
 from bioengine_tpu.utils import flight, metrics
 from bioengine_tpu.utils import compile_cache as _compile_cache
 
@@ -54,6 +55,11 @@ class CacheStats:
     compile_seconds: dict = field(default_factory=dict)
     # per-key cache_hit verdict, same lifecycle as compile_seconds
     cache_hit: dict = field(default_factory=dict)
+    # per-key attention calls traced into the program while it was
+    # built, by path (``{"fused:1024": 24}``: attention_traced_total's
+    # rise over build()), same lifecycle. A served cpsam program that
+    # reads ``xla`` on a TPU is running without its kernel.
+    attention_paths: dict = field(default_factory=dict)
     # lifetime total, survives evictions
     cumulative_compile_seconds: float = 0.0
 
@@ -158,9 +164,14 @@ class CompiledProgramCache:
                 if cache_dir
                 else None
             )
+            traced_before = traced_paths()
             t0 = time.perf_counter()
             program = build()
             dt = time.perf_counter() - t0
+            # another thread tracing a model meanwhile would be counted
+            # here too; builds are rare enough for that to be an anomaly
+            # worth seeing, not one worth a lock around tracing
+            attention = traced_paths(since=traced_before)
             # Tag disk/tier hits apart from real compiles. Primary
             # signal: a REAL compile persists a new cache entry while a
             # hit writes nothing (wall time alone can't separate them —
@@ -182,6 +193,7 @@ class CompiledProgramCache:
                     self.stats.persistent_hits += 1
                 self.stats.compile_seconds[str(key)] = dt
                 self.stats.cache_hit[str(key)] = cache_hit
+                self.stats.attention_paths[str(key)] = attention
                 self.stats.cumulative_compile_seconds += dt
                 self._programs[key] = program
                 self._programs.move_to_end(key)
@@ -189,6 +201,7 @@ class CompiledProgramCache:
                     victim, _ = self._programs.popitem(last=False)
                     self.stats.compile_seconds.pop(str(victim), None)
                     self.stats.cache_hit.pop(str(victim), None)
+                    self.stats.attention_paths.pop(str(victim), None)
                     self.stats.evictions += 1
                     evicted.append(victim)
             flight.record(
@@ -196,6 +209,7 @@ class CompiledProgramCache:
                 key=str(key),
                 seconds=round(dt, 3),
                 cache_hit=cache_hit,
+                attention_paths=attention,
             )
             for victim in evicted:
                 flight.record("program.evict", key=str(victim))
@@ -212,14 +226,18 @@ class CompiledProgramCache:
             return dict(self.stats.compile_seconds)
 
     def compile_info_snapshot(self) -> dict:
-        """Per-key ``{"seconds": s, "cache_hit": bool}`` under the
-        cache lock — the describe() view that tells a tier/disk hit
-        apart from a real compile."""
+        """Per-key ``{"seconds": s, "cache_hit": bool, "attention_paths":
+        {...}}`` under the cache lock — the describe() view that tells a
+        tier/disk hit apart from a real compile, and a program with the
+        fused attention kernel from one without."""
         with self._lock:
             return {
                 k: {
                     "seconds": v,
                     "cache_hit": bool(self.stats.cache_hit.get(k, False)),
+                    "attention_paths": dict(
+                        self.stats.attention_paths.get(k, {})
+                    ),
                 }
                 for k, v in self.stats.compile_seconds.items()
             }
@@ -246,6 +264,7 @@ class CompiledProgramCache:
                 del self._programs[k]
                 self.stats.compile_seconds.pop(str(k), None)
                 self.stats.cache_hit.pop(str(k), None)
+                self.stats.attention_paths.pop(str(k), None)
             self.stats.evictions += len(victims)
         for k in victims:
             flight.record("program.evict", key=str(k))

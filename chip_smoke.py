@@ -19,8 +19,9 @@ seconds and the ``device_kind`` it ran on:
            image through the tiled pipeline, one call over the HTTP bridge
   trace    start_profiling -> 4 requests -> stop_profiling; the xplane
            must hold a TPU device plane with events
-  kernel   the Pallas flash-attention kernel compiled by Mosaic at the
-           ViT-B/14@448 and cpsam shapes, forward and gradient
+  kernel   the Pallas attention kernel compiled by Mosaic at the
+           ViT-B/14@448 and cpsam shapes (plain depth, and the served
+           program's folded 128/64 depth), forward and gradient
   stop     worker.stop(); no thread may outlive it
 
 Nothing is caught and continued: the first failed check raises, the
@@ -63,6 +64,17 @@ MODEL_ID = "smoke-unet2d"
 # same bf16 network with different fusions, not a loose "looks similar"
 BF16_TOL = 2.0**-6
 
+# (B, H, N, q/k depth, v depth, scale; None = depth**-0.5): ViT-B/14 heads
+# at 448x448 — the first shape the embedder turns the kernel on for —
+# one cpsam tile's heads at plain depth, and what the served cpsam
+# program runs since PR 27: 16 tiles' heads with the relative-position
+# bias folded into the contraction (64 + 32 + 32 lanes, scale 1)
+KERNEL_SHAPES = (
+    (2, 12, 1025, 64, 64, None),
+    (1, 16, 1024, 64, 64, None),
+    (16, 16, 1024, 128, 64, 1.0),
+)
+
 
 @dataclasses.dataclass(frozen=True)
 class SmokeConfig:
@@ -77,6 +89,7 @@ class SmokeConfig:
     chips: int = 1
     out_dir: Path = DEFAULT_OUT
     parity_with: Optional[Path] = None
+    kernel_shapes: tuple = KERNEL_SHAPES
 
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -613,58 +626,58 @@ async def trace(cfg: SmokeConfig, report: Report, conn, worker_sid, app_sid) -> 
 
 # ---- kernel -----------------------------------------------------------------
 
-# (B, H, N, d): ViT-B/14 heads at 448x448 — the first shape the embedder
-# turns the kernel on for — and cpsam's global layers (models/sam.py)
-KERNEL_SHAPES = ((2, 12, 1025, 64), (1, 16, 1024, 64))
-
-
-def kernel(report: Report) -> None:
+def kernel(cfg: SmokeConfig, report: Report) -> None:
     import jax
     import jax.numpy as jnp
 
-    from bioengine_tpu.ops.pallas.attention import (
-        _reference_attention,
-        flash_attention,
-    )
+    from bioengine_tpu.ops.attention import reference_attention
+    from bioengine_tpu.ops.pallas.attention import flash_attention
 
-    def flash(q, k, v, causal):
-        return flash_attention(q, k, v, causal=causal, interpret=False)
+    on_chip = cfg.platform == "tpu"  # the rehearsal interprets the kernel
+
+    def flash(q, k, v, causal, scale):
+        return flash_attention(
+            q, k, v, causal=causal, scale=scale, interpret=not on_chip
+        )
 
     # everything jitted: op-by-op, the reference and its gradient would
     # compile one program per primitive
-    reference = jax.jit(_reference_attention, static_argnums=3)
+    reference = jax.jit(reference_attention, static_argnums=(3, 4))
 
-    def grads(fn, causal):
+    def grads(fn, causal, scale):
         def total(q, k, v):
-            return fn(q, k, v, causal).astype(jnp.float32).sum()
+            return fn(q, k, v, causal, scale).astype(jnp.float32).sum()
 
         return jax.jit(jax.grad(total, argnums=(0, 1, 2)))
 
     with report.phase("kernel") as info:
         errors = {}
-        for shape in KERNEL_SHAPES:
+        for B, H, N, d_qk, d_v, scale in cfg.kernel_shapes:
             q, k, v = jax.jit(
                 lambda: tuple(
-                    jax.random.normal(key, shape, jnp.bfloat16)
-                    for key in jax.random.split(jax.random.key(0), 3)
+                    jax.random.normal(key, (B, H, N, d), jnp.bfloat16)
+                    for key, d in zip(
+                        jax.random.split(jax.random.key(0), 3),
+                        (d_qk, d_qk, d_v),
+                    )
                 )
             )()
             for causal in (False, True):
-                tag = f"{'x'.join(map(str, shape))}{'-causal' if causal else ''}"
+                tag = f"{B}x{H}x{N}x{d_qk}/{d_v}{'-causal' if causal else ''}"
                 lowered = flash_attention.lower(
-                    q, k, v, causal=causal, interpret=False
+                    q, k, v, causal=causal, scale=scale, interpret=not on_chip
                 )
                 check(
-                    "tpu_custom_call" in lowered.as_text(),
+                    not on_chip or "tpu_custom_call" in lowered.as_text(),
                     f"kernel {tag}: lowered module holds no Mosaic custom call",
                 )
                 fwd = assert_close(
-                    flash(q, k, v, causal),
-                    reference(q, k, v, causal),
+                    flash(q, k, v, causal, scale),
+                    reference(q, k, v, causal, scale),
                     f"kernel {tag} forward",
                 )
-                got = grads(flash, causal)(q, k, v)
-                want = grads(_reference_attention, causal)(q, k, v)
+                got = grads(flash, causal, scale)(q, k, v)
+                want = grads(reference_attention, causal, scale)(q, k, v)
                 grad = max(
                     assert_close(g, w, f"kernel {tag} d{name}")
                     for g, w, name in zip(got, want, "qkv")
@@ -714,7 +727,7 @@ async def run(cfg: SmokeConfig, report: Report) -> None:
             cfg, report, conn, worker_sid, app_id, app_sid, model, params
         )
         await trace(cfg, report, conn, worker_sid, app_sid)
-        kernel(report)
+        kernel(cfg, report)
 
 
 def main(argv: Optional[list[str]] = None) -> int:

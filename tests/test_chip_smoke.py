@@ -4,7 +4,8 @@ The script itself has no CPU mode (run with JAX_PLATFORMS=cpu it fails
 at its device gate). Its phase functions take the platform and the
 model width as arguments, so the serving half — start → package →
 deploy → serve → stop — runs here at toy width before chip time is
-spent on it. The trace and kernel phases need the chip.
+spent on it, and the kernel phase with the kernel interpreted at toy
+shapes. The trace phase, and Mosaic, need the chip.
 """
 
 from __future__ import annotations
@@ -52,3 +53,28 @@ async def test_serving_phases_at_toy_width(tmp_path, monkeypatch, capsys):
     ]
     assert phases == ["gate", "start", "package", "deploy", "serve", "stop"]
     assert (tmp_path / "outputs-chips1.npz").is_file()
+
+
+def test_kernel_phase_at_toy_size(tmp_path, capsys):
+    """The kernel phase with the kernel interpreted: a plain shape whose
+    N is no multiple of the block, and the folded form the served cpsam
+    program runs (q/k depth != v depth, scale 1), forward and gradient,
+    both also causal."""
+    cfg = chip_smoke.SmokeConfig(
+        platform="cpu",
+        out_dir=tmp_path,
+        kernel_shapes=((1, 2, 100, 32, 32, None), (2, 2, 64, 48, 16, 1.0)),
+    )
+    report = chip_smoke.Report()
+    try:
+        chip_smoke.kernel(cfg, report)
+    finally:
+        report.close()
+    (line,) = [
+        line
+        for line in capsys.readouterr().out.splitlines()
+        if line.startswith("[chip_smoke] kernel")
+    ]
+    assert " ok " in line
+    for tag in ("1x2x100x32/32", "2x2x64x48/16-causal"):
+        assert tag in line

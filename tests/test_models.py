@@ -380,6 +380,143 @@ class TestGoldenCpSAM:
         )
 
 
+def _sam_attention_5d(params, x, num_heads):
+    """SAM's ``add_decomposed_rel_pos`` as segment-anything states it:
+    the scores reshaped to (H, W, H, W), the two biases broadcast onto
+    them, a softmax over the stored scores. What ``SAMAttention`` ran
+    before it folded the bias into the contraction (PR 27); kept here as
+    the plain statement the fold is held to."""
+    from bioengine_tpu.models.sam import _rel_pos_gather
+
+    B, H, W, dim = x.shape
+    hd = dim // num_heads
+    qkv = x @ params["qkv"]["kernel"] + params["qkv"]["bias"]
+    qkv = qkv.reshape(B, H * W, 3, num_heads, hd)
+    q, k, v = (
+        jnp.moveaxis(t, 2, 1).reshape(B * num_heads, H * W, hd)
+        for t in jnp.moveaxis(qkv, 2, 0)
+    )
+    attn = (q * hd**-0.5) @ jnp.swapaxes(k, -2, -1)
+    Rh = _rel_pos_gather(H, H, params["rel_pos_h"])
+    Rw = _rel_pos_gather(W, W, params["rel_pos_w"])
+    q_r = q.reshape(B * num_heads, H, W, hd)
+    bias_h = jnp.einsum("bhwc,hkc->bhwk", q_r, Rh)
+    bias_w = jnp.einsum("bhwc,wkc->bhwk", q_r, Rw)
+    attn = attn.reshape(B * num_heads, H, W, H, W)
+    attn = attn + bias_h[:, :, :, :, None] + bias_w[:, :, :, None, :]
+    attn = jax.nn.softmax(attn.reshape(B * num_heads, H * W, H * W), axis=-1)
+    out = (attn @ v).reshape(B, num_heads, H * W, hd)
+    out = jnp.moveaxis(out, 1, 2).reshape(B, H, W, dim)
+    return out @ params["proj"]["kernel"] + params["proj"]["bias"]
+
+
+class TestFoldedRelPos:
+    """``SAMAttention`` folds the decomposed relative-position bias into
+    the QK^T contraction; in f32 it has to equal the 5-D formulation."""
+
+    DIM, HEADS = 32, 2
+
+    # grid (H, W), stored table extent, and whether the input is a set
+    # of 14-token windows cut from a grid that 14 does not divide
+    CASES = {
+        "global-square": ((8, 8), 8, False),
+        "non-square": ((6, 10), 10, False),
+        "window-14-padded": ((20, 17), 14, True),
+        "table-resized-at-use": ((8, 8), 5, False),
+    }
+
+    def _setup(self, case):
+        from bioengine_tpu.models.sam import SAMAttention, _window_partition
+
+        (H, W), table, windowed = self.CASES[case]
+        rng = np.random.default_rng(3)
+        x = jnp.asarray(rng.normal(size=(2, H, W, self.DIM)), jnp.float32)
+        if windowed:
+            x, _ = _window_partition(x, 14)  # zeros at the bottom and right
+        module = SAMAttention(self.DIM, self.HEADS, table, jnp.float32)
+        params = module.init(jax.random.key(0), x)["params"]
+        # the tables initialise to zeros: give them something to get wrong
+        params = {
+            **params,
+            "rel_pos_h": jnp.asarray(
+                rng.normal(size=params["rel_pos_h"].shape), jnp.float32
+            ),
+            "rel_pos_w": jnp.asarray(
+                rng.normal(size=params["rel_pos_w"].shape), jnp.float32
+            ),
+        }
+        return module, params, x
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_forward_equals_the_5d_formulation(self, case):
+        module, params, x = self._setup(case)
+        got = module.apply({"params": params}, x)
+        want = _sam_attention_5d(params, x, self.HEADS)
+        assert float(jnp.abs(want).max()) > 0.1
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+    def test_a_wrong_fold_is_seen(self):
+        """The comparison has teeth: with the row and column tables
+        swapped the 5-D formulation moves far beyond the tolerance."""
+        module, params, x = self._setup("non-square")
+        swapped = {
+            **params,
+            "rel_pos_h": params["rel_pos_w"],
+            "rel_pos_w": params["rel_pos_h"],
+        }
+        got = module.apply({"params": params}, x)
+        wrong = _sam_attention_5d(swapped, x, self.HEADS)
+        assert float(jnp.abs(got - wrong).max()) > 1e-2
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_gradients_equal_the_5d_formulation(self, case):
+        module, params, x = self._setup(case)
+        weights = jnp.asarray(
+            np.random.default_rng(4).normal(size=x.shape), jnp.float32
+        )
+
+        def folded(p):
+            return jnp.sum(module.apply({"params": p}, x) * weights)
+
+        def plain(p):
+            return jnp.sum(_sam_attention_5d(p, x, self.HEADS) * weights)
+
+        got, want = jax.grad(folded)(params), jax.grad(plain)(params)
+        for name in ("rel_pos_h", "rel_pos_w"):
+            assert float(jnp.abs(want[name]).max()) > 1e-3
+            np.testing.assert_allclose(
+                got[name], want[name], atol=2e-5, rtol=1e-4, err_msg=name
+            )
+        for leaf in ("kernel", "bias"):
+            np.testing.assert_allclose(
+                got["qkv"][leaf], want["qkv"][leaf], atol=2e-5, rtol=1e-4,
+                err_msg=f"qkv.{leaf}",
+            )
+
+    def test_parameter_tree_is_the_checkpoint_s(self):
+        """Names and shapes unchanged by the fold: converted cpsam
+        checkpoints load as before."""
+        module, params, _ = self._setup("table-resized-at-use")
+        shapes = jax.tree.map(lambda a: a.shape, params)
+        hd = self.DIM // self.HEADS
+        assert shapes == {
+            "qkv": {"kernel": (self.DIM, 3 * self.DIM), "bias": (3 * self.DIM,)},
+            "proj": {"kernel": (self.DIM, self.DIM), "bias": (self.DIM,)},
+            "rel_pos_h": (2 * 5 - 1, hd),
+            "rel_pos_w": (2 * 5 - 1, hd),
+        }
+
+    def test_counter_reads_the_path_the_backend_dictates(self):
+        """On the CPU the suite names, every traced ``SAMAttention``
+        counts one ``xla`` under its N and nothing under ``fused``."""
+        from bioengine_tpu.ops.attention import traced_paths
+
+        module, params, x = self._setup("non-square")
+        before = traced_paths()
+        jax.jit(module.apply)({"params": params}, x)
+        assert traced_paths(since=before) == {"xla:60": 1}
+
+
 class TestGoldenFlows:
     """ops/flows.py pinned against an INDEPENDENT implementation
     (tests/generate_golden_flows.py: exact sparse-solve diffusion +

@@ -10,7 +10,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bioengine_tpu.ops.pallas.attention import flash_attention, make_attn_fn
+from bioengine_tpu.ops.attention import (
+    attention,
+    reference_attention,
+    traced_paths,
+)
+from bioengine_tpu.ops.pallas import attention as kernel_module
+from bioengine_tpu.ops.pallas.attention import (
+    _block_sizes,
+    flash_attention,
+    make_attn_fn,
+)
 
 
 def ref_attention(q, k, v, causal=False):
@@ -123,3 +133,286 @@ class TestFlashAttention:
 
         g = jax.grad(loss)(q)
         assert np.isfinite(np.asarray(g)).all()
+
+
+class TestFoldedShapes:
+    """What cpsam's folded attention asks of the kernel: a q/k depth
+    that is not v's, an explicit scale, N off the block grid."""
+
+    @staticmethod
+    def _qkv(n, d_qk, d_v, dtype=jnp.float32, seed=7):
+        rng = np.random.default_rng(seed)
+        return tuple(
+            jnp.asarray(rng.normal(size=(2, 2, n, d)), dtype)
+            for d in (d_qk, d_qk, d_v)
+        )
+
+    # (N, blocks): one kv step with padding, one without, the online
+    # path over two kv steps, and over four with two of them all padding
+    @pytest.mark.parametrize(
+        "n,blocks",
+        [(200, {}), (256, {}), (300, dict(block_q=128, block_k=256)),
+         (100, dict(block_q=128, block_k=96))],
+    )
+    def test_depths_differ_and_scale_is_explicit(self, n, blocks):
+        q, k, v = self._qkv(n, 92, 64)
+        out = flash_attention(q, k * 0.3, v, scale=1.0, **blocks)
+        ref = reference_attention(q, k * 0.3, v, scale=1.0)
+        assert out.shape == (2, 2, n, 64)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+    def test_default_scale_is_the_qk_depth_s(self):
+        q, k, v = self._qkv(130, 48, 16)
+        np.testing.assert_allclose(
+            flash_attention(q, k, v),
+            flash_attention(q, k, v, scale=48**-0.5),
+            atol=1e-6,
+        )
+        np.testing.assert_allclose(
+            flash_attention(q, k, v), reference_attention(q, k, v),
+            atol=2e-5, rtol=2e-5,
+        )
+
+    def test_causal_with_two_depths(self):
+        q, k, v = self._qkv(200, 96, 32)
+        out = flash_attention(q, k, v, causal=True, scale=0.2)
+        ref = reference_attention(q, k, v, True, 0.2)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+    def test_bf16_operands_stay_bf16(self):
+        """bf16 in, bf16 out, within bf16 rounding of the f32 reference."""
+        q, k, v = self._qkv(196, 92, 64, jnp.bfloat16)
+        out = flash_attention(q, k, v, scale=1.0)
+        assert out.dtype == jnp.bfloat16
+        ref = reference_attention(q, k, v, scale=1.0)
+        np.testing.assert_allclose(
+            out.astype(np.float32), ref.astype(np.float32), atol=3e-2
+        )
+
+    def test_gradients_flow_to_the_wider_depth(self):
+        q, k, v = self._qkv(136, 80, 16)
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(fn(q, k, v, scale=1.0) ** 2)
+
+        got = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape
+            np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4)
+
+    @pytest.mark.parametrize(
+        "n,expected",
+        [
+            (196, (256, 256)),      # a SAM window
+            (1024, (512, 1024)),    # cpsam's tile: one kv step
+            (1025, (384, 1152)),    # ViT-B/14 at 448 px with its CLS token
+            (2304, (384, 1152)),    # 18 lane widths: both caps bite
+            (4096, (512, 2048)),    # two kv steps
+            (896, (128, 896)),      # 7 lane widths: no divisor under the cap
+        ],
+    )
+    def test_block_sizes_come_from_n(self, n, expected):
+        block_q, block_k = _block_sizes(n)
+        assert (block_q, block_k) == expected
+        padded = -(-n // 128) * 128
+        assert padded % block_q == 0 and padded % block_k == 0
+
+
+class TestAttentionDispatch:
+    """``ops.attention.attention``: the reference off the TPU, the kernel
+    on it, and a counter that says which."""
+
+    def test_cpu_takes_the_reference_and_counts_xla(self):
+        q, k, v = TestFoldedShapes._qkv(72, 40, 8)
+        before = traced_paths()
+        out = attention(q, k, v, scale=1.0)
+        assert traced_paths(since=before) == {"xla:72": 1}
+        np.testing.assert_array_equal(
+            out, reference_attention(q, k, v, scale=1.0)
+        )
+
+    def test_tpu_backend_takes_the_kernel_and_counts_fused(self, monkeypatch):
+        """The backend pretended, the kernel interpreted: the choice is
+        ``jax.default_backend()``'s and nothing else's."""
+        calls = []
+
+        def interpreted(q, k, v, **kwargs):
+            calls.append(kwargs)
+            return flash_attention(q, k, v, interpret=True, **kwargs)
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(kernel_module, "flash_attention", interpreted)
+        q, k, v = TestFoldedShapes._qkv(72, 40, 8)
+        before = traced_paths()
+        out = attention(q, k, v, scale=1.0)
+        assert traced_paths(since=before) == {"fused:72": 1}
+        assert calls == [{"scale": 1.0}]
+        np.testing.assert_allclose(
+            out, reference_attention(q, k, v, scale=1.0), atol=2e-5, rtol=2e-5
+        )
+
+    def test_program_cache_keeps_the_rise_of_a_build(self):
+        from bioengine_tpu.runtime.program_cache import CompiledProgramCache
+
+        q, k, v = TestFoldedShapes._qkv(40, 24, 8)
+        cache = CompiledProgramCache()
+
+        def build():
+            fn = jax.jit(
+                lambda q, k, v: attention(attention(q, k, q), k, v)
+            )
+            fn(q, k, v)
+            return fn
+
+        cache.get_or_compile(("two-attentions", 40), build)
+        cache.get_or_compile(("none", 0), lambda: None)
+        info = cache.compile_info_snapshot()
+        assert info[str(("two-attentions", 40))]["attention_paths"] == {
+            "xla:40": 2
+        }
+        assert info[str(("none", 0))]["attention_paths"] == {}
+        cache.evict(lambda key: True)
+        assert cache.stats.attention_paths == {}
+
+
+class TestPartitionedUnderGspmd:
+    """A Mosaic call cannot be partitioned automatically: lowering one
+    inside a multi-device jit raises. Where its operands belong to a
+    mesh the kernel wraps itself in a ``shard_map`` over the batch, so
+    the engine's dp-sharded batch and the dp fine-tune step keep
+    working, each device on its own shard."""
+
+    @staticmethod
+    def _operands(devices, spec, axes):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        mesh = Mesh(np.array(devices).reshape(*axes.values()), tuple(axes))
+        q, k, v = TestFoldedShapes._qkv(100, 48, 16, seed=11)
+        q, k, v = (jnp.concatenate([a] * 4) for a in (q, k, v))  # batch 8
+        sharded = tuple(
+            jax.device_put(a, NamedSharding(mesh, P(*spec))) for a in (q, k, v)
+        )
+        return (q, k, v), sharded
+
+    def test_batch_sharded_runs_per_shard_with_no_collective(self, devices):
+        plain, sharded = self._operands(devices[:4], ("dp",), {"dp": 4})
+        fn = jax.jit(lambda q, k, v: flash_attention(q, k, v, scale=1.0))
+        out = fn(*sharded)
+        assert out.sharding.spec[0] == "dp"
+        np.testing.assert_allclose(
+            out, reference_attention(*plain, scale=1.0), atol=2e-5, rtol=2e-5
+        )
+        hlo = fn.lower(*sharded).compile().as_text()
+        assert "all-gather" not in hlo and "all-reduce" not in hlo
+
+    def test_batch_that_does_not_divide_takes_the_reference(
+        self, devices, monkeypatch
+    ):
+        """Six items over four devices: the kernel refuses, and
+        ``attention`` does not ask it."""
+        _, sharded = self._operands(devices[:4], (), {"dp": 4})
+        six = tuple(a[:6] for a in sharded)
+        with pytest.raises(ValueError, match="does not divide"):
+            jax.jit(lambda q, k, v: flash_attention(q, k, v, scale=1.0))(*six)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        before = traced_paths()
+        jax.jit(lambda q, k, v: attention(q, k, v, scale=1.0))(*six)
+        assert traced_paths(since=before) == {"xla:100": 1}
+
+    def test_sequence_sharded_operands_are_gathered_not_miscomputed(
+        self, devices
+    ):
+        plain, sharded = self._operands(
+            devices[:4], ("dp", None, "sp"), {"dp": 2, "sp": 2}
+        )
+        out = jax.jit(lambda q, k, v: flash_attention(q, k, v, scale=1.0))(
+            *sharded
+        )
+        np.testing.assert_allclose(
+            out, reference_attention(*plain, scale=1.0), atol=2e-5, rtol=2e-5
+        )
+
+    def test_gradients_under_a_dp_mesh(self, devices):
+        plain, sharded = self._operands(devices[:4], ("dp",), {"dp": 4})
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(fn(q, k, v, scale=1.0) ** 2)
+
+        got = jax.jit(jax.grad(loss(flash_attention), argnums=(0, 1, 2)))(
+            *sharded
+        )
+        want = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(*plain)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One described (not attached) v5e chip: the TPU compiler is
+    installed here and refuses what the chip's would refuse. Described
+    inside a fixture, never at import (only one process may load the
+    TPU library; a worker that cannot skips these tests)."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without a chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+class TestMosaicAcceptsTheServedShapes:
+    """The kernel compiled by Mosaic for a v5e at the widths the repo
+    serves. Nothing runs and nothing is measured; what is refused here
+    would have cost a chip call."""
+
+    @pytest.mark.parametrize(
+        "shape_qk,d_v,kwargs",
+        [
+            # cpsam-vitl's served program: 16 tiles x 16 heads, folded
+            ((16, 16, 1024, 128), 64, dict(scale=1.0)),
+            # CpSAM's default windows under fine-tuning: 14 x 14 tokens
+            ((48, 16, 196, 92), 64, dict(scale=1.0)),
+            # the embedder, ViT-B/14 at 448 px
+            ((2, 12, 1025, 64), 64, dict()),
+            ((2, 12, 1025, 64), 64, dict(causal=True)),
+            # beyond MAX_BLOCK_K: the online path over two kv steps
+            ((1, 16, 4096, 128), 64, dict(scale=1.0)),
+        ],
+    )
+    def test_compiles_for_v5e(self, one_chip, no_compile_cache, shape_qk, d_v, kwargs):
+        def spec(shape):
+            return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+        compiled = (
+            jax.jit(
+                lambda q, k, v: flash_attention(
+                    q, k, v, interpret=False, **kwargs
+                )
+            )
+            .trace(spec(shape_qk), spec(shape_qk), spec(shape_qk[:3] + (d_v,)))
+            .lower(lowering_platforms=("tpu",))
+            .compile()
+        )
+        assert "tpu_custom_call" in compiled.as_text()
