@@ -29,21 +29,22 @@ def main(argv=None) -> int:
     parser.add_argument("--precisions", nargs="+", default=["fp8", "bf16"])
     args = parser.parse_args(argv)
 
+    import importlib
+
     from benchmarks import harness
-    from benchmarks.generators import closed_loop
 
     cell = harness.load_cell(args.workload)
+    generator = importlib.import_module(
+        f"benchmarks.generators.{cell.traffic['generator']}"
+    )
     device = harness.device_gate("tpu", cell.chips)
     limits = cell.config["limits"]
     for seed in args.seeds:
-        plan = closed_loop.plan(cell.traffic, int(cell.config["in_channels"]), seed)
-        sample = [
-            {"kind": kind, "image": 0, "output": None}
-            for kind in closed_loop.kinds(cell.traffic)
-        ]
+        plan = generator.plan(cell.traffic, cell.config, seed)
+        sample = cell.path.control_sample(cell, plan)
         for precision in args.precisions:
             t0 = time.perf_counter()
-            readings = harness.compare(cell, seed, sample, plan.pool, precision)
+            readings = cell.path.compare(cell, seed, sample, plan.pool, precision)
             checks = harness.judge(readings, limits, len(sample))
             print(json.dumps({
                 "workload": cell.name, "seed": seed, "precision": precision,

@@ -54,7 +54,8 @@ def kinds(traffic: dict) -> list[tuple[int, int]]:
     return sorted({(e["items"], e["size"]) for e in traffic["deck"]})
 
 
-def plan(traffic: dict, channels: int, seed: int) -> Plan:
+def plan(traffic: dict, config: dict, seed: int) -> Plan:
+    channels = int(config["in_channels"])
     rng = np.random.default_rng(abs(int(seed)))
     pool = {
         kind: [

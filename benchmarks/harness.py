@@ -2,20 +2,27 @@
 
 Everything is one process that holds the chip. The worker is the object
 ``python -m bioengine_tpu.worker --mode single-machine`` builds, the app
-is ``apps/model-runner`` as shipped, and every request is
-``infer(model_id, inputs, default_blocksize_parameter, sample_id)`` over
-a real client connection (the start/package/deploy/counter code began
-as copies of ``chip_smoke.py``'s phases). From the program the harness
-takes the system under test and its counters (the profiler it starts
-itself: ``start_trace``); the traffic, the weights, the plain reference, the work counts, the peaks
-and the trace reduction are the benchmark's own.
+is the one the configuration names, deployed as shipped, and every
+request goes over a real client connection (the start/deploy/counter
+code began as copies of ``chip_smoke.py``'s phases). From the program
+the harness takes the system under test and its counters (the profiler
+it starts itself: ``start_trace``); the traffic, the weights, the plain
+reference, the work counts, the peaks and the trace reduction are the
+benchmark's own.
+
+What one served path does lives in ``benchmarks/paths/<name>.py``, the
+module the configuration names under ``deployment.path``: the package
+it serves, how the app is deployed, the programs a mix can form and the
+lone request that runs each, one request (unary or streamed), what a
+request's work is in the path's own unit, and the comparison with the
+plain reference. What every run does is here.
 
 Order of a run (``run_cell``):
 
   gate      the platform asked for, with enough chips, or raise
-  weights   made on the device from the seed in one jitted call, written
-            as a ``jax_params`` package
-  start     worker (port 0), one client connection per closed-loop client
+  package   the path's: weights made on the device from the seed in one
+            jitted call, written as the app wants them
+  start     worker (port 0), one client connection per client of the plan
   deploy    ``deploy_app(local_path=...)``, waited to HEALTHY
   warm      one request per program the mix can form, then the clients
             loop for the mix's lead-in; ``setup_s`` ends where the
@@ -45,21 +52,19 @@ from typing import Any, Optional
 
 import numpy as np
 
+from benchmarks import window_metrics
+
 BENCH = Path(__file__).resolve().parent
 REPO = BENCH.parent
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-BATCH_LADDER = (1, 2, 4, 8, 16, 32, 64)
 # a --trace 1 run records this much of the middle of the window, and
 # starts the profiler this long before (starting it stalls the process
 # for a second or two, and the clients have to find their stride again)
 TRACED_SECONDS = 8.0
 TRACE_LEAD_SECONDS = 3.0
-# docs/OPERATIONS.md "Sizing /dev/shm for the object store": the one
-# environment variable a configuration file may set
-OPERATOR_ENV = ("BIOENGINE_RPC_STORE_MB",)
 # a reply of the wrong shape or with a non-finite value (JSON has no inf)
 NOT_COMPARABLE = 1e30
 
@@ -79,10 +84,24 @@ class Cell:
     traffic: dict
     end_to_end: list[str]
     per_layer: list[str]
+    units: dict[str, str]
+    path: Any                     # the module of the served path
 
 
 def load_manifest(root: Path = REPO) -> dict:
     return json.loads((root / "BENCHMARK.json").read_text())
+
+
+def path_module(config: dict):
+    """The served path a configuration names. There is no default: a
+    configuration that names none is refused."""
+    name = (config.get("deployment") or {}).get("path")
+    if not name:
+        raise ValueError(
+            f"configuration {config.get('name')!r} names no served path: set "
+            "deployment.path to a module of benchmarks/paths/"
+        )
+    return importlib.import_module(f"benchmarks.paths.{name}")
 
 
 def load_cell(name: str, root: Path = REPO) -> Cell:
@@ -96,8 +115,9 @@ def load_cell(name: str, root: Path = REPO) -> Cell:
     )
     config = json.loads((root / config_entry["file"]).read_text())
     traffic = json.loads(
-        (BENCH / "traffic" / f"{workload['traffic']}.json").read_text()
+        (root / BENCH.name / "traffic" / f"{workload['traffic']}.json").read_text()
     )
+    metrics = manifest["end_to_end"] + manifest["per_layer"]
 
     def reported(metric: dict) -> bool:
         return name in metric.get("workloads", [name])
@@ -109,11 +129,27 @@ def load_cell(name: str, root: Path = REPO) -> Cell:
         traffic=traffic,
         end_to_end=[m["name"] for m in manifest["end_to_end"] if reported(m)],
         per_layer=[m["name"] for m in manifest["per_layer"] if reported(m)],
+        units={m["name"]: m["unit"] for m in metrics},
+        path=path_module(config),
     )
 
 
-def model_kwargs(config: dict) -> dict:
-    return {k: config[k] for k in config["model_kwargs"] if k != "in_channels"}
+def operator_env(cell: Cell) -> dict[str, str]:
+    """The operator variables the configuration sets, each of which its
+    path has to list (and ``docs/OPERATIONS.md`` to name)."""
+    env = cell.config["deployment"].get("env") or {}
+    for key in env:
+        if key not in cell.path.OPERATOR_ENV:
+            raise ValueError(
+                f"configuration {cell.config['name']!r} sets {key}; its path "
+                f"{cell.path.__name__.rsplit('.', 1)[-1]!r} lets a "
+                f"configuration set {list(cell.path.OPERATOR_ENV)} and nothing else"
+            )
+    return {key: str(value) for key, value in env.items()}
+
+
+def work_module(config: dict):
+    return importlib.import_module(f"benchmarks.work.{config['work']}")
 
 
 def peaks_for(kind: str) -> dict:
@@ -121,10 +157,6 @@ def peaks_for(kind: str) -> dict:
     if kind not in table:
         raise KeyError(f"device_kind {kind!r} is not in benchmarks/peaks.json")
     return table[kind]
-
-
-def batch_bucket(n: int) -> int:
-    return next(b for b in BATCH_LADDER if b >= n)
 
 
 # ---- compile counter ---------------------------------------------------------
@@ -198,69 +230,6 @@ def memory_peaks() -> tuple[int, int]:
     )
 
 
-# ---- package -----------------------------------------------------------------
-
-
-def reference_module(config: dict):
-    return importlib.import_module(f"benchmarks.references.{config['reference']}")
-
-
-def work_module(config: dict):
-    return importlib.import_module(f"benchmarks.work.{config['work']}")
-
-
-def make_package(config: dict, seed: int, collection: Path) -> str:
-    """Writes ``collection/<model_id>/`` and returns the model id."""
-    import yaml
-
-    from benchmarks.references._common import make_weights
-
-    model_id = f"bench-{config['name']}"
-    package = collection / model_id
-    package.mkdir(parents=True)
-    shapes = reference_module(config).param_shapes(
-        model_kwargs(config), int(config["in_channels"])
-    )
-    weights = make_weights(shapes, seed)
-    flat = {k: np.asarray(v) for k, v in weights.items()}
-    del weights
-    with open(package / "weights.npz", "wb") as f:
-        np.savez(f, **flat)
-        # 1.2 GB of dirty pages are written back during set-up, not half
-        # a minute later in the middle of the window
-        f.flush()
-        os.fsync(f.fileno())
-    # the manifest beside the npz selects the streamed-weights path
-    (package / "weights.npz.manifest.json").write_text(
-        json.dumps(
-            {k: {"shape": list(v.shape), "dtype": str(v.dtype)} for k, v in flat.items()},
-            sort_keys=True,
-        )
-    )
-    kwargs = {k: config[k] for k in config["model_kwargs"]}
-    (package / "rdf.yaml").write_text(
-        yaml.safe_dump(
-            {
-                "type": "model",
-                "name": model_id,
-                "description": f"benchmark package of {config['name']}, random weights",
-                "inputs": [{"name": "input0", "axes": config["axes"]}],
-                "outputs": [{"name": "output0", "axes": config["axes"]}],
-                "weights": {
-                    "jax_params": {
-                        "source": "weights.npz",
-                        "architecture": {
-                            "name": config["architecture"],
-                            "kwargs": kwargs,
-                        },
-                    }
-                },
-            }
-        )
-    )
-    return model_id
-
-
 # ---- worker ------------------------------------------------------------------
 
 
@@ -308,15 +277,14 @@ def remove_shm_store() -> None:
     Path("/dev/shm", shm_store_name()).unlink(missing_ok=True)
 
 
-async def deploy(admin, worker_sid: str, app_dir: Path, cache_dir: Path):
-    """Returns (app_id, app service id). The entry deployment's package
-    cache IS the collection: the package counts as fetched, as it is on
-    every request but a model's first."""
+async def deploy(admin, worker_sid: str, app_dir: Path, deployment_kwargs: dict):
+    """Returns (app_id, app service id). The app is deployed as shipped;
+    ``deployment_kwargs`` are the path's (where its package lies)."""
     result = await admin.call(
         worker_sid,
         "deploy_app",
         local_path=str(app_dir),
-        deployment_kwargs={"entry_deployment": {"cache_dir": str(cache_dir)}},
+        deployment_kwargs=deployment_kwargs,
     )
     app_id = result["app_id"]
     deadline = time.monotonic() + 300
@@ -333,13 +301,19 @@ async def deploy(admin, worker_sid: str, app_dir: Path, cache_dir: Path):
         await asyncio.sleep(0.1)
 
 
-async def read_counters(admin, worker_sid: str, app_id: str) -> dict:
-    """The registry's families and the engines' pipeline stats, summed
-    over the runtime replicas."""
-    families = await admin.call(worker_sid, "get_metrics")
+async def engine_replicas(admin, worker_sid: str, app_id: str, engines: str) -> list:
+    """The replicas of the deployment that holds the engines, as the
+    path names it."""
     status = await admin.call(worker_sid, "get_app_status", app_id)
+    return status["deployments"][engines]["replicas"]
+
+
+async def read_counters(admin, worker_sid: str, app_id: str, engines: str) -> dict:
+    """The registry's families and the engines' pipeline stats, summed
+    over the replicas that hold the engines."""
+    families = await admin.call(worker_sid, "get_metrics")
     pipeline: dict[str, float] = {}
-    for replica in status["deployments"]["runtime_deployment"]["replicas"]:
+    for replica in await engine_replicas(admin, worker_sid, app_id, engines):
         for stats in (replica.get("pipeline_stats") or {}).values():
             for key, value in stats.items():
                 if isinstance(value, (int, float)):
@@ -347,17 +321,15 @@ async def read_counters(admin, worker_sid: str, app_id: str) -> dict:
     return {"families": families, "pipeline": pipeline, "at": time.perf_counter()}
 
 
-async def program_facts(admin, worker_sid: str, app_id: str) -> dict:
+async def program_facts(admin, worker_sid: str, app_id: str, engines: str) -> dict:
     """Seconds each engine program took to obtain, and whether the
     persistent cache had it (information for ``setup_s``)."""
-    status = await admin.call(worker_sid, "get_app_status", app_id)
     facts = {}
-    for replica in status["deployments"]["runtime_deployment"]["replicas"]:
+    for replica in await engine_replicas(admin, worker_sid, app_id, engines):
         for engine in (replica.get("mesh") or {}).get("engines", {}).values():
-            programs = engine["programs"]
-            for key, seconds in programs["compile_seconds"].items():
-                shape = key.split(", ", 1)[1].split(", 'float")[0]
-                facts[shape] = [seconds, bool(programs["cache_hits"][key])]
+            programs = engine.get("programs") or {}
+            for key, seconds in (programs.get("compile_seconds") or {}).items():
+                facts[key] = [seconds, bool(programs["cache_hits"][key])]
     return facts
 
 
@@ -398,53 +370,6 @@ def stop_trace() -> None:
     jax.profiler.stop_trace()
 
 
-# ---- warm-up: every program the mix can form ---------------------------------
-
-
-def tiling_of(cell: Cell) -> tuple[int, int, int]:
-    """(tile, max_tile, overlap) a request of this cell is served with."""
-    engine = cell.config["engine"]
-    block = cell.traffic.get("blocksize")
-    if block:
-        return int(block), int(block), int(engine["tile_overlap"])
-    return int(engine["tile"]), int(engine["max_tile"]), int(engine["tile_overlap"])
-
-
-def program_shapes(cell: Cell) -> dict[tuple[int, ...], tuple[int, int]]:
-    """Program input shape -> the (items, size) of a lone request that
-    runs it. Tiled requests run chunks of ``tile_batch`` tiles, each
-    padded up the batch ladder; the others are co-batched by the runtime
-    (at most ``max_ongoing_requests`` of them), the sum padded likewise."""
-    from benchmarks.generators.closed_loop import kinds
-    from benchmarks.references._common import n_tiles
-
-    tile, max_tile, overlap = tiling_of(cell)
-    chunk = int(cell.config["engine"]["tile_batch"])
-    channels = int(cell.config["in_channels"])
-    slots = int(
-        cell.config["deployment"]["shipped"]["runtime_deployment"][
-            "max_ongoing_requests"
-        ]
-    )
-    out: dict[tuple[int, ...], tuple[int, int]] = {}
-    direct_items: set[int] = set()
-    for items, size in kinds(cell.traffic):
-        if size > max_tile:
-            n = n_tiles(size, size, tile, overlap)
-            for left in {min(chunk, n - i) for i in range(0, n, chunk)}:
-                out.setdefault(
-                    (batch_bucket(left), tile, tile, channels), (items, size)
-                )
-        else:
-            direct_items.add(items)
-    if direct_items:
-        (size,) = {s for _, s in kinds(cell.traffic) if s <= max_tile}
-        for bucket in BATCH_LADDER:
-            if bucket <= batch_bucket(slots * max(direct_items)):
-                out[(bucket, size, size, channels)] = (bucket, size)
-    return out
-
-
 # ---- the run -----------------------------------------------------------------
 
 
@@ -482,42 +407,6 @@ class RunData:
         return None if a is None or b is None else b - a
 
 
-def percentile(values: list[float], q: float) -> float:
-    return float(np.percentile(np.asarray(values, np.float64), q))
-
-
-def window_latencies(run: RunData) -> list[float]:
-    """Client latency of every request of the window; a failed request
-    counts as the worst a request of this run can be."""
-    return [
-        r["latency_ms"] if r["ok"] else run.seconds * 1000.0 for r in run.in_window
-    ]
-
-
-def pixels_served(run: RunData, t0: float, t1: float) -> float:
-    """Input pixels of the requests answered OK, each counted by the
-    share of its time, from send to reply, that lay inside [t0, t1]: a
-    request in flight at an edge has part of its work done on either
-    side. (Counting whole requests only, a window of a hundred requests
-    reads in steps of one percent, and the same loop read 1.1822 or
-    1.1938 Mpx/s for 99 or 100 completed: chip runs of PR 25.)"""
-    pixels = 0.0
-    for r in run.requests:
-        if r["ok"] and r["end"] > r["start"]:
-            inside = max(0.0, min(r["end"], t1) - max(r["start"], t0))
-            pixels += r["pixels"] * inside / (r["end"] - r["start"])
-    return pixels
-
-
-def end_to_end(run: RunData, setup_s: float) -> dict[str, float]:
-    return {
-        "throughput_mpx_s": pixels_served(run, *run.window) / 1e6 / run.seconds,
-        "latency_p50_ms": percentile(window_latencies(run), 50),
-        "latency_p95_ms": percentile(window_latencies(run), 95),
-        "setup_s": setup_s,
-    }
-
-
 def per_layer(run: RunData) -> dict[str, float]:
     out = {}
     for name in run.cell.per_layer:
@@ -526,13 +415,6 @@ def per_layer(run: RunData) -> dict[str, float]:
         if value is not None:
             out[name] = float(value)
     return out
-
-
-def units(root: Path = REPO) -> dict[str, str]:
-    manifest = load_manifest(root)
-    return {
-        m["name"]: m["unit"] for m in manifest["end_to_end"] + manifest["per_layer"]
-    }
 
 
 # ---- correctness -------------------------------------------------------------
@@ -551,56 +433,10 @@ def draw_sample(kept: dict, per_size: int, seed: int) -> list[dict]:
     return sample
 
 
-def compare(cell: Cell, seed: int, sample: list[dict], pool: dict,
-            precision: str = "f32") -> dict[str, float]:
-    """The numbers ``correct`` rests on, worst over the sample: the
-    relative l2 distance between reply and reference, and the largest
-    absolute gap over the reference's largest value. ``precision`` below
-    f32 exists for the control, which compares the reference in a lower
-    precision in the program's place."""
-    import functools
-
-    import jax
-
-    from benchmarks.references import _common
-
-    ref = reference_module(cell.config)
-    kwargs = model_kwargs(cell.config)
-    weights = _common.make_weights(
-        ref.param_shapes(kwargs, int(cell.config["in_channels"])), seed
-    )
-    tile, max_tile, overlap = tiling_of(cell)
-
-    def forward_in(prec: str):
-        fn = jax.jit(functools.partial(ref.forward, kwargs=kwargs, precision=prec))
-        return lambda tiles: fn(weights, tiles)
-
-    exact = forward_in("f32")
-    lower = forward_in(precision) if precision != "f32" else None
-    worst = {"rel_l2": 0.0, "max_err": 0.0}
-    cache: dict[tuple, np.ndarray] = {}
-    for entry in sample:
-        key = (entry["kind"], entry["image"])
-        image = pool[entry["kind"]][entry["image"]]
-        if key not in cache:
-            cache[key] = _common.predict(exact, image, tile, max_tile, overlap)
-        want = cache[key]
-        if lower is not None:
-            got = _common.predict(lower, image, tile, max_tile, overlap)
-        else:
-            got = np.asarray(entry["output"], np.float32)
-        if got.shape != want.shape or not np.isfinite(got).all():
-            return {"rel_l2": NOT_COMPARABLE, "max_err": NOT_COMPARABLE}
-        diff = got.astype(np.float64) - want
-        worst["rel_l2"] = max(
-            worst["rel_l2"],
-            float(np.linalg.norm(diff) / max(np.linalg.norm(want), 1e-30)),
-        )
-        worst["max_err"] = max(
-            worst["max_err"],
-            float(np.max(np.abs(diff)) / max(np.max(np.abs(want)), 1e-30)),
-        )
-    return worst
+def not_comparable(limits: dict[str, float]) -> dict[str, float]:
+    """What a check reads where nothing can be compared: a reply of the
+    wrong shape, a value that is not finite, no reply at all."""
+    return dict.fromkeys(limits, NOT_COMPARABLE)
 
 
 def judge(readings: dict[str, float], limits: dict[str, float], n: int) -> dict:
@@ -675,63 +511,54 @@ async def serve_window(
 ) -> tuple[RunData, float, dict, dict, tuple[int, int]]:
     """Set-up, warm-up and the measured window. Returns (run data,
     setup_s, the kept replies, the input pool, memory peaks)."""
+    path = cell.path
     generator = importlib.import_module(
         f"benchmarks.generators.{cell.traffic['generator']}"
     )
-    for key, value in (cell.config["deployment"].get("env") or {}).items():
-        if key not in OPERATOR_ENV:
-            raise ValueError(f"a configuration may not set {key}")
-        os.environ[key] = str(value)
+    os.environ.update(operator_env(cell))
     # the RPC plane's shared-memory segment outlives its process and the
     # next one attaches to it as it was left: a name of this checkout's
     # own, removed before the run and after it
     os.environ["BIOENGINE_RPC_STORE_NAME"] = shm_store_name()
     remove_shm_store()
-    collection = out_dir / "collection"
-    model_id = make_package(cell.config, seed, collection)
-    # the default source is https://hypha.aicell.io, unreachable here
-    os.environ["BIOENGINE_LOCAL_MODEL_PATH"] = str(collection)
-    plan = generator.plan(cell.traffic, int(cell.config["in_channels"]), seed)
+    package = path.make_package(cell.config, seed, out_dir / "package")
+    plan = generator.plan(cell.traffic, cell.config, seed)
     log(f"package written, plan drawn at {time.perf_counter() - t_process_start:.1f}s")
 
     worker, admin, clients, worker_sid = await start_worker(
         platform, out_dir / "workspace", len(plan.clients)
     )
     requests: list[dict] = []
-    kept: dict[tuple[int, tuple[int, int]], dict] = {}
+    kept: dict[tuple[int, Any], dict] = {}
     try:
         app_id, app_sid = await deploy(
-            admin, worker_sid, REPO / cell.config["deployment"]["app"], collection
+            admin, worker_sid, REPO / cell.config["deployment"]["app"],
+            path.deployment_kwargs(package),
         )
         log(f"deployed at {time.perf_counter() - t_process_start:.1f}s")
 
-        async def infer(conn, array: np.ndarray, sample_id: str) -> dict:
-            kwargs = dict(model_id=model_id, inputs=array, sample_id=sample_id)
-            if plan.blocksize:
-                kwargs["default_blocksize_parameter"] = int(plan.blocksize)
-            return await conn.call(app_sid, "infer", **kwargs)
+        def perform(conn, payload, sample_id: str):
+            return path.perform(conn, app_sid, package, plan, payload, sample_id)
 
         async def send(c: int, n: int, request) -> dict:
-            array = plan.pool[request.kind][request.image]
+            payload = path.payload(plan, request)
             start = time.perf_counter()
+            # the path's own keys first: its unit of work among them
             record = {
-                "client": c, "kind": request.kind, "image": request.image,
-                "pixels": plan.pixels(request), "start": start, "ok": False,
+                **path.describe(plan, request),
+                "client": c, "kind": request.kind, "start": start, "ok": False,
             }
             try:
                 # an answer that comes late is late, not lost: wait a
                 # minute past the close for it, then count it as failed
                 reply = await asyncio.wait_for(
-                    infer(clients[c], array, f"c{c}-{n}"), seconds + 60.0
+                    perform(clients[c], payload, f"c{c}-{n}"), seconds + 60.0
                 )
-                record["end"] = time.perf_counter()
-                record["server_ms"] = float(reply["_meta"]["duration_ms"])
-                record["ok"] = reply["_meta"]["backend"] == "xla"
+                output = reply.pop("output")
+                # end, ok, server_ms, and what a streamed path stamped
+                record.update(reply)
                 kept[(c, request.kind)] = {
-                    "kind": request.kind, "image": request.image,
-                    # a copy: the decoded array is a view that pins its
-                    # object in the RPC plane's shared-memory store
-                    "output": np.array(reply["output0"], np.float32),
+                    "kind": request.kind, "request": request, "output": output,
                 }
             except Exception as exc:  # noqa: BLE001 — counted, never hidden
                 record["end"] = time.perf_counter()
@@ -743,19 +570,17 @@ async def serve_window(
 
         # warm-up: one lone request per program the mix can form; the
         # clients' lead-in, before the window opens, is the rest of it
-        shapes = program_shapes(cell)
+        programs = path.programs(cell)
         rng = np.random.default_rng(abs(int(seed)) + 2)
-        warm_inputs = {}
-        for shape, (items, size) in shapes.items():
-            warm_inputs[shape] = rng.standard_normal(
-                (items, size, size, shape[-1]), np.float32
-            )
-            reply = await infer(admin, warm_inputs[shape], f"warm-{shape[0]}")
-            if reply["_meta"]["backend"] != "xla":
-                raise RuntimeError(f"backend is {reply['_meta']['backend']!r}")
-        log(f"programs warm {sorted(shapes)} at "
+        lone = {}
+        for key, request in programs.items():
+            lone[key] = path.lone_payload(cell, key, request, rng)
+            reply = await perform(admin, lone[key], f"warm-{key[0]}")
+            if not reply["ok"]:
+                raise RuntimeError(f"the warm-up request of program {key} failed")
+        log(f"programs warm {sorted(programs)} at "
             f"{time.perf_counter() - t_process_start:.1f}s: "
-            + json.dumps(await program_facts(admin, worker_sid, app_id)))
+            + json.dumps(await program_facts(admin, worker_sid, app_id, path.ENGINES)))
         traced: Optional[dict] = None
 
         async def profile_part() -> None:
@@ -804,7 +629,9 @@ async def serve_window(
             # rehearsal still reads the counters
             if trace and platform == "tpu":
                 opened["profiler"] = asyncio.create_task(profile_part())
-            opened["counters"] = await read_counters(admin, worker_sid, app_id)
+            opened["counters"] = await read_counters(
+                admin, worker_sid, app_id, path.ENGINES
+            )
 
         window = await generator.drive(plan, send, seconds, open_window)
         opened["beat"].cancel()
@@ -823,7 +650,7 @@ async def serve_window(
         for at, what, held, stack in stalls:
             log(f"window: {what} stood still {held:.2f}s from "
                 f"{at - window[0]:.1f}s {stack}")
-        end_counters = await read_counters(admin, worker_sid, app_id)
+        end_counters = await read_counters(admin, worker_sid, app_id, path.ENGINES)
         compiles = compile_counter.count - opened["compiles"]
         peak = memory_peaks()
 
@@ -832,12 +659,12 @@ async def serve_window(
             # in a trace of its own, names read off its "XLA Modules"
             from benchmarks import trace_reduce
 
-            names: dict[str, tuple[int, ...]] = {}
-            for shape in shapes:
-                label_dir = out_dir / "trace" / ("label-" + "x".join(map(str, shape)))
+            names: dict[str, tuple] = {}
+            for key in programs:
+                label_dir = out_dir / "trace" / ("label-" + "x".join(map(str, key)))
                 start_trace(label_dir, host_level=1)
                 sent = time.time_ns()
-                await infer(admin, warm_inputs[shape], "label")
+                await perform(admin, lone[key], "label")
                 replied = time.time_ns()
                 stop_trace()
                 reduced = trace_reduce.reduce(trace_reduce.find_xplane(label_dir))
@@ -848,12 +675,12 @@ async def serve_window(
                     f"{reduced.hi / 1e6:.1f} ms, replied at "
                     f"{reduced.at(replied) / 1e6:.1f} ms")
                 for name in reduced.module_seconds():
-                    # a name two shapes share tells nothing
-                    names[name] = () if name in names else shape
+                    # a name two programs share tells nothing
+                    names[name] = () if name in names else key
                 # the largest program's lone request names the host's work
-                if shape == max(shapes):
+                if key == max(programs):
                     traced["lone_request"] = reduced
-            traced["programs"] = {n: s for n, s in names.items() if s}
+            traced["programs"] = {n: k for n, k in names.items() if k}
     finally:
         for conn in [admin, *clients]:
             await conn.disconnect()
@@ -890,7 +717,7 @@ def reduce_window_trace(run: RunData, device: dict) -> dict:
     # (nested events each count their own time)
     lone = run.trace["lone_request"]
     breakdown = {
-        "device_ops": reduced.top_ops(10),
+        "device_ops": reduced.top_ops(10, span),
         "idle_gaps": (
             [["window: " + name, s] for name, s in reduced.idle_gaps(1, span)]
             + [["lone request, host: " + name, s] for name, s in lone.host_seconds(9)]
@@ -936,7 +763,6 @@ def run_cell(
         "attempted": len(done),
         "failed": sum(not r["ok"] for r in done),
     }
-    names = units()
     breakdown = None
     if run.trace is not None:
         breakdown = reduce_window_trace(run, device)
@@ -944,24 +770,27 @@ def run_cell(
         metrics = per_layer(run)
     else:
         metrics = {
-            k: v for k, v in end_to_end(run, setup_s).items() if k in cell.end_to_end
+            k: v for k, v in window_metrics.end_to_end(run, setup_s).items()
+            if k in cell.end_to_end
         }
     result["metrics"] = {
-        name: {"value": value, "unit": names.get(name, "")}
+        name: {"value": value, "unit": cell.units.get(name, "")}
         for name, value in metrics.items()
     }
     result["device"] = device
     if breakdown is not None:
         result["breakdown"] = breakdown
-    shutil.rmtree(out_dir / "collection", ignore_errors=True)
+    shutil.rmtree(out_dir / "package", ignore_errors=True)
 
     # the program's state is freed (worker stopped): now the reference
     t_check = time.perf_counter()
     sample = draw_sample(kept, int(cell.traffic["check_per_size"]), seed)
-    readings = compare(cell, seed, sample, pool) if sample else {
-        "rel_l2": NOT_COMPARABLE, "max_err": NOT_COMPARABLE
-    }
-    checks = judge(readings, cell.config["limits"], len(sample))
+    limits = cell.config["limits"]
+    readings = (
+        cell.path.compare(cell, seed, sample, pool) if sample
+        else not_comparable(limits)
+    )
+    checks = judge(readings, limits, len(sample))
     log(f"reference over {len(sample)} replies took "
         f"{time.perf_counter() - t_check:.1f}s")
     ordered = {"correct": is_correct(checks), **result, "checks": checks}

@@ -76,6 +76,12 @@ def layer_norm(x, scale, bias, eps: float = 1e-6):
     return (x - mean) * jax.lax.rsqrt(var + eps) * scale + bias
 
 
+def model_kwargs(config: dict) -> dict:
+    """The keyword arguments a configuration hands its architecture,
+    less the input channels (the references take those apart)."""
+    return {k: config[k] for k in config["model_kwargs"] if k != "in_channels"}
+
+
 # ---- weights ----------------------------------------------------------------
 
 

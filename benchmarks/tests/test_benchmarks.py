@@ -16,9 +16,11 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from benchmarks import harness, trace_reduce
+from benchmarks import harness, trace_reduce, window_metrics
 from benchmarks.generators import closed_loop
+from benchmarks.paths import infer
 from benchmarks.references import _common, cpsam
+from benchmarks.tests import toy_overlay
 from benchmarks.work import cpsam as cpsam_work
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -57,6 +59,19 @@ def toy_cell() -> harness.Cell:
 # ---- BENCHMARK.json ------------------------------------------------------------
 
 
+def expected_of(cell: str) -> dict:
+    """What the tests expect of a cell: a file of the cell's own, so that
+    a PR that adds a cell adds this file and edits no test."""
+    path = BENCH / "fixtures" / "cells" / f"{cell}.json"
+    assert path.is_file(), (
+        f"cell {cell!r} has no benchmarks/fixtures/cells/{cell}.json: add it, with "
+        '"programs" (the program keys its mix can form), "limits" (the keys of '
+        'its configuration\'s limits) and, for the image path, "tiles" (side -> '
+        "count of pieces)"
+    )
+    return json.loads(path.read_text())
+
+
 def test_manifest_names_files_and_moves():
     m = harness.load_manifest()
     assert set(m) == {
@@ -74,6 +89,7 @@ def test_manifest_names_files_and_moves():
         for module in ("references", "work"):
             key = "reference" if module == "references" else "work"
             assert (BENCH / module / f"{body[key]}.py").is_file()
+        assert (BENCH / "paths" / f"{body['deployment']['path']}.py").is_file()
     cells = {w["name"] for w in m["workloads"]}
     for w in m["workloads"]:
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
@@ -107,13 +123,25 @@ def test_manifest_names_files_and_moves():
         assert any(cell in p["workloads"] for p in m["per_layer"])
 
 
+def cells() -> list[str]:
+    return [w["name"] for w in harness.load_manifest()["workloads"]]
+
+
 def test_every_cell_loads_and_its_programs_enumerate():
-    want = {"cpsam-vitl.fov": {(16, 256, 256, 3)}}
-    assert {w["name"] for w in harness.load_manifest()["workloads"]} == set(want)
-    for name, shapes in want.items():
+    assert cells()
+    for name in cells():
+        want = expected_of(name)
         cell = harness.load_cell(name)
-        assert set(harness.program_shapes(cell)) == shapes
-        assert set(cell.config["limits"]) == {"rel_l2", "max_err"}
+        assert sorted(map(list, cell.path.programs(cell))) == sorted(want["programs"])
+        assert sorted(cell.config["limits"]) == sorted(want["limits"])
+        assert set(cell.end_to_end) >= {"latency_p50_ms", "setup_s"}
+        if cell.path.THROUGHPUT:
+            assert cell.path.THROUGHPUT[0] in cell.end_to_end
+    with pytest.raises(AssertionError, match="fixtures/cells/no-such.cell.json: add it"):
+        expected_of("no-such.cell")
+
+
+def test_the_image_path_enumerates_the_co_batched_buckets():
     # requests the client has cut itself are co-batched by the runtime:
     # every bucket up to slots x the largest request
     cell = harness.load_cell("cpsam-vitl.fov")
@@ -121,13 +149,21 @@ def test_every_cell_loads_and_its_programs_enumerate():
         "blocksize": None,
         "deck": [{"items": n, "size": 256, "count": 1} for n in (1, 2, 4)],
     }
-    assert set(harness.program_shapes(cell)) == {
+    assert set(infer.programs(cell)) == {
         (b, 256, 256, 3) for b in (1, 2, 4, 8, 16)
     }
 
 
 def test_tile_counts_of_the_mixes():
-    assert [_common.n_tiles(s, s, 256, 64) for s in (512, 768, 1024)] == [9, 16, 25]
+    counted = 0
+    for name in cells():
+        tiles = expected_of(name).get("tiles")
+        if tiles:  # a cell of the image path whose requests the server cuts
+            tile, _, overlap = infer.tiling_of(harness.load_cell(name))
+            for side, count in tiles.items():
+                assert _common.n_tiles(int(side), int(side), tile, overlap) == count
+                counted += 1
+    assert counted
     assert [_common.n_tiles(s, s, 512, 64) for s in (1200, 1536, 2048)] == [9, 16, 25]
     assert _common.tile_starts(1024, 256, 64) == [0, 192, 384, 576, 768]
 
@@ -144,9 +180,10 @@ def test_unknown_device_kind_is_an_error():
 def test_images_follow_the_seed_and_the_order_of_sizes_does_not():
     traffic = json.loads((BENCH / "traffic" / "fov.json").read_text())
     traffic["deck"] = [{**e, "size": e["size"] // 8} for e in traffic["deck"]]
-    a = closed_loop.plan(traffic, 3, SEED)
-    b = closed_loop.plan(traffic, 3, SEED)
-    c = closed_loop.plan(traffic, 3, SEED + 1)
+    config = {"in_channels": 3}
+    a = closed_loop.plan(traffic, config, SEED)
+    b = closed_loop.plan(traffic, config, SEED)
+    c = closed_loop.plan(traffic, config, SEED + 1)
     assert a.clients == b.clients
     for kind in a.pool:
         for x, y in zip(a.pool[kind], b.pool[kind]):
@@ -159,7 +196,7 @@ def test_images_follow_the_seed_and_the_order_of_sizes_does_not():
     assert len({tuple(cl) for cl in sizes(a)}) == 8  # an order of each client's own
     assert len(a.clients) == 8 and len(a.clients[0]) == 10
     assert sorted(sizes(a)[0]) == [(1, 64)] * 4 + [(1, 96)] * 3 + [(1, 128)] * 3
-    other = closed_loop.plan({**traffic, "order": traffic["order"] + 1}, 3, SEED)
+    other = closed_loop.plan({**traffic, "order": traffic["order"] + 1}, config, SEED)
     assert sizes(other) != sizes(a)
 
 
@@ -172,7 +209,7 @@ def test_the_window_opens_on_clients_in_their_stride():
 
     traffic = json.loads((BENCH / "traffic" / "fov.json").read_text())
     traffic.update(lead_in_s=0.2, deck=[{"items": 1, "size": 8, "count": 2}])
-    plan = closed_loop.plan(traffic, 3, SEED)
+    plan = closed_loop.plan(traffic, {"in_channels": 3}, SEED)
     sent, opened = [], []
 
     async def send(c, n, request):
@@ -250,7 +287,7 @@ def test_the_reference_has_no_windows():
     config = harness.load_cell("cpsam-vitl.fov").config
     assert config["window_size"] == 0
     assert config["global_attn_indexes"] == list(range(config["depth"]))
-    shapes = cpsam.param_shapes(harness.model_kwargs(config), 3)
+    shapes = cpsam.param_shapes(_common.model_kwargs(config), 3)
     assert {shapes[f"encoder/block{i}/attn/rel_pos_h"] for i in range(24)} == {(63, 64)}
 
 
@@ -274,7 +311,7 @@ def test_work_count_at_the_real_shape():
     Ours counts matrix products only, so it may lie up to 5 % under
     (norms, softmax, GELU) and never over. (ISSUE 25's 25.8 TFLOP for 32
     tiles was the count of SAM's windowed pattern, which is not cpsam.)"""
-    vit = harness.model_kwargs(harness.load_cell("cpsam-vitl.fov").config)
+    vit = _common.model_kwargs(harness.load_cell("cpsam-vitl.fov").config)
     assert 0.95 * 11.878e12 < cpsam_work.flops((16, 256, 256, 3), vit) <= 11.878e12
     assert cpsam_work.param_count(vit, 3) == PARAMS
     per_tile = cpsam_work.flops((1, 256, 256, 3), vit)
@@ -322,6 +359,7 @@ def test_interval_arithmetic_on_a_known_case():
     assert reduced.busy_s((0, 40)) == pytest.approx(30e-9)
     assert reduced.module_seconds() == {"jit_f(1)": [45e-9, 10e-9]}
     assert reduced.top_ops(2) == [["fusion.1", 20e-9], ["fusion.2", 15e-9]]
+    assert reduced.top_ops(3, (8, 35)) == [["fusion.2", 12e-9], ["copy", 5e-9], ["fusion.1", 2e-9]]
     assert trace_reduce.op_kind(
         "%fusion.7 = (bf16[8,4]{1,0:T(8,128)}, f32[4]{0}) fusion(bf16[8,4]{1,0} %p), kind=kLoop"
     ) == "fusion (bf16[8,4], f32[4])"
@@ -348,11 +386,19 @@ def test_reduction_of_the_recorded_chip_trace(tmp_path):
     assert reduced.span_s() == pytest.approx(expected["span_s"], rel=1e-9)
     assert reduced.busy_s() <= reduced.span_s()
     assert reduced.top_ops(3)[0][0] == expected["top_op"]
+    assert sum(s for _, s in reduced.top_ops(10**6)) == pytest.approx(expected["busy_s"], rel=1e-9)
     assert reduced.host_seconds(1)[0][0] == "np.asarray(jax.Array)"
     # clipped to its first half, the one execution is busy throughout
     half = (reduced.lo, (reduced.lo + reduced.hi) // 2)
     assert reduced.busy_s(half) == pytest.approx((half[1] - half[0]) / 1e9, rel=1e-3)
     assert reduced.module_seconds((reduced.hi, reduced.hi + 1)) == {}
+    # the operations are clipped to the same span as the busy seconds, so
+    # that the two can be divided
+    top = reduced.top_ops(10**6, half)
+    assert top[0] == [expected["top_op"], pytest.approx(expected["top_op_first_half_s"], rel=1e-9)]
+    assert sum(s for _, s in top) == pytest.approx(expected["busy_first_half_s"], rel=1e-9)
+    assert reduced.busy_s(half) == pytest.approx(expected["busy_first_half_s"], rel=1e-9)
+    assert reduced.top_ops(3, (reduced.hi, reduced.hi + 1)) == []
     # the trace's start on the wall clock: a time.time_ns() of the traced
     # process lands on the trace's own clock
     assert reduced.started_wall_ns == expected["started_wall_ns"]
@@ -397,11 +443,7 @@ def test_counter_readers_read_and_trace_readers_stay_silent_off_the_chip(toy_run
     _, traced = toy_runs
     got = traced["metrics"]
     # no device plane on a CPU: nothing under a device metric's name
-    assert set(got) == {
-        "above_runtime_ms", "batch_occupancy", "engine_host_share_pct",
-        "compiles_in_window",
-    }
-    assert 0 < got["engine_host_share_pct"]["value"] < 100
+    assert set(got) == {"batch_occupancy", "compiles_in_window"}
     assert got["compiles_in_window"]["value"] == 0
     assert got["batch_occupancy"]["value"] >= 1
     assert "busy_s" not in traced["device"] and "breakdown" not in traced
@@ -448,14 +490,312 @@ def test_the_precision_control_fails_where_the_stated_precision_passes():
     step below the precision the configuration states (bf16 -> fp8). Kept
     here at toy size; read on the chip at the cells' own sizes (PERF.md)."""
     cell = toy_cell()
-    plan = closed_loop.plan(cell.traffic, int(cell.config["in_channels"]), SEED)
-    sample = [
-        {"kind": kind, "image": 0, "output": None}
-        for kind in closed_loop.kinds(cell.traffic)
-    ]
-    stated = harness.compare(cell, SEED, sample, plan.pool, precision="bf16")
-    control = harness.compare(cell, SEED, sample, plan.pool, precision="fp8")
+    plan = closed_loop.plan(cell.traffic, cell.config, SEED)
+    sample = infer.control_sample(cell, plan)
+    assert [e["kind"] for e in sample] == closed_loop.kinds(cell.traffic)
+    stated = infer.compare(cell, SEED, sample, plan.pool, precision="bf16")
+    control = infer.compare(cell, SEED, sample, plan.pool, precision="fp8")
     limits = cell.config["limits"]
     assert harness.is_correct(harness.judge(stated, limits, len(sample)))
     assert not harness.is_correct(harness.judge(control, limits, len(sample)))
     assert control["rel_l2"] > 3 * stated["rel_l2"]
+
+
+# ---- the seam: what a configuration may name and set --------------------------------
+
+
+def test_a_configuration_names_its_path_and_sets_only_what_the_path_lists():
+    cell = harness.load_cell("cpsam-vitl.fov")
+    assert cell.path is infer and cell.config["deployment"]["path"] == "infer"
+    assert harness.operator_env(cell) == {"BIOENGINE_RPC_STORE_MB": "1024"}
+    # no default path: a configuration without the key is refused
+    nameless = {**cell.config, "deployment": {"app": "apps/model-runner"}}
+    with pytest.raises(ValueError, match="names no served path: set deployment.path"):
+        harness.path_module(nameless)
+    # a variable the path does not list is refused, by name
+    cell.config["deployment"]["env"] = {"BIOENGINE_DECODE_KV_BLOCKS": "64"}
+    with pytest.raises(ValueError, match="sets BIOENGINE_DECODE_KV_BLOCKS; its path 'infer'"):
+        harness.operator_env(cell)
+    token_cell = toy_overlay.toy_cell()
+    assert harness.operator_env(token_cell) == {"BIOENGINE_DECODE_KV_BLOCKS": "512"}
+    token_cell.config["deployment"]["env"] = {"BIOENGINE_RPC_STORE_MB": "64"}
+    with pytest.raises(ValueError, match="its path 'generate'"):
+        harness.operator_env(token_cell)
+
+
+def test_every_operator_variable_of_a_path_is_documented():
+    """``docs/OPERATIONS.md`` names every variable a path lets a
+    configuration set (the real paths and the test double alike)."""
+    toy_overlay.mount()
+    operations = (REPO / "docs" / "OPERATIONS.md").read_text()
+    listed = 0
+    for directory in (BENCH / "paths", toy_overlay.OVERLAY / "benchmarks" / "paths"):
+        for file in sorted(directory.glob("[a-z]*.py")):
+            path = harness.path_module({"deployment": {"path": file.stem}})
+            for variable in path.OPERATOR_ENV:
+                assert f"`{variable}`" in operations, f"{file.name} lists {variable}"
+                listed += 1
+    assert listed >= 2
+
+
+def test_the_harness_names_nothing_of_one_path():
+    """What one served path does has left ``harness.py`` (ISSUE 28's own
+    grep, comments and all)."""
+    words = re.compile(
+        r"infer|model-runner|runtime_deployment|entry_deployment|pixels|blocksize|tile"
+    )
+    found = [
+        line for line in (BENCH / "harness.py").read_text().splitlines()
+        if words.search(line)
+    ]
+    assert found == []
+
+
+# ---- work in the path's unit: the old readers against the new ------------------------
+
+
+def test_old_and_new_readers_agree_to_the_last_digit(tmp_path):
+    """``step_mfu`` and ``program_roofline`` on one recorded run (the
+    chip trace of the fixture, with requests laid around it), computed
+    as the readers did before the seam (pixels, ``flops_per_pixel``, the
+    program's shape and the model's kwargs) and as they do now (work,
+    ``flops_per_unit``, the path's program key and the configuration)."""
+    import importlib
+    import lzma
+
+    fixture = tmp_path / "fixture.xplane.pb"
+    fixture.write_bytes(
+        lzma.decompress((BENCH / "fixtures" / "fov-16x256.label.xplane.pb.xz").read_bytes())
+    )
+    reduced = trace_reduce.reduce(fixture)
+    cell = harness.load_cell("cpsam-vitl.fov")
+    (program,) = reduced.module_seconds()
+    shape = (16, 256, 256, 3)
+    t0, t1 = 100.0, 100.0 + reduced.span_s()
+    span = (reduced.lo - 1, reduced.hi + 10**6)  # the program's event ends after its last operation
+    requests = [
+        {"start": t0 - 0.11, "end": t0 + 0.07, "ok": True, "pixels": 512 * 512},
+        {"start": t0 + 0.013, "end": t0 + 0.29, "ok": True, "pixels": 768 * 768},
+        {"start": t0 + 0.2, "end": t1 + 0.4, "ok": True, "pixels": 1024 * 1024},
+        {"start": t0 + 0.1, "end": t0 + 0.2, "ok": False, "pixels": 1024 * 1024},
+    ]
+    for r in requests:
+        r.update(work=r["pixels"], latency_ms=(r["end"] - r["start"]) * 1000.0)
+    peaks = harness.peaks_for("TPU v5 lite")
+    run = harness.RunData(
+        cell=cell, seconds=t1 - t0, window=(t0, t1), requests=requests,
+        counters={}, compiles_in_window=0,
+        trace={
+            "reduced": reduced, "span": span,
+            "host_window": (t0, t1), "peaks": peaks, "programs": {program: shape},
+        },
+    )
+    kwargs = _common.model_kwargs(cell.config)
+    # as PR 25 to 27 computed them
+    pixels = 0.0
+    for r in requests:
+        if r["ok"] and r["end"] > r["start"]:
+            inside = max(0.0, min(r["end"], t1) - max(r["start"], t0))
+            pixels += r["pixels"] * inside / (r["end"] - r["start"])
+    per_pixel = cpsam_work.flops_per_pixel(kwargs, 3, 256)
+    old_mfu = 100.0 * pixels * per_pixel / ((t1 - t0) * (peaks["bf16_flops_per_s"] * 1))
+    durations = reduced.module_seconds(span)[program]
+    least = max(
+        cpsam_work.flops(shape, kwargs) / peaks["bf16_flops_per_s"],
+        cpsam_work.min_bytes(shape, kwargs) / peaks["hbm_bytes_per_s"],
+    )
+    old_roofline = 100.0 * (0.0 + least * len(durations)) / (0.0 + sum(durations))
+    new_mfu = importlib.import_module("benchmarks.layer_metrics.step_mfu").read(run)
+    new_roofline = importlib.import_module(
+        "benchmarks.layer_metrics.program_roofline"
+    ).read(run)
+    assert repr(new_mfu) == repr(old_mfu) and 0 < new_mfu < 100
+    assert repr(new_roofline) == repr(old_roofline) and 0 < new_roofline < 100
+    assert window_metrics.work_served(run, t0, t1) == pixels
+    # the throughput is the same work over the window, under the path's name
+    assert window_metrics.end_to_end(run, 1.0)["throughput_mpx_s"] == (
+        pixels / 1e6 / (t1 - t0)
+    )
+
+
+# ---- a second served path, so that the seam is not shaped by one user -----------------
+
+
+@pytest.fixture(scope="module")
+def token_runs(tmp_path_factory):
+    """The toy token path (``toy_path/``: ``apps/generate`` as shipped,
+    through a streamed call), rehearsed untraced and traced; the traced
+    rehearsal's ``RunData`` is kept as the readers saw it."""
+    out = tmp_path_factory.mktemp("tokens")
+    seen = []
+    read = harness.per_layer
+
+    def watched(run):
+        seen.append(run)
+        return read(run)
+
+    plain = harness.run_cell(
+        toy_overlay.toy_cell(), SEED + 2, 1.5, False, platform="cpu", out_dir=out / "a"
+    )
+    harness.per_layer = watched
+    try:
+        traced = harness.run_cell(
+            toy_overlay.toy_cell(), SEED + 3, 1.5, True, platform="cpu", out_dir=out / "b"
+        )
+    finally:
+        harness.per_layer = read
+    return plain, traced, seen[0]
+
+
+def test_the_token_path_prints_its_own_line(token_runs):
+    plain, traced, run = token_runs
+    for line in (plain, traced):
+        assert list(line)[:5] == ["correct", "attempted", "failed", "metrics", "device"]
+        assert list(line)[-1] == "checks"
+        assert line["correct"] is True and line["failed"] == 0 < line["attempted"]
+        assert set(line["checks"]) == {"logit_gap", "compared"}
+        value, limit = line["checks"]["logit_gap"]
+        assert 0 <= value <= limit and line["checks"]["compared"] == 4
+        assert json.loads(json.dumps(line)) == line
+    # latencies and set-up as every path; no throughput in the image path's unit
+    assert set(plain["metrics"]) == {"latency_p50_ms", "latency_p95_ms", "setup_s"}
+    assert all(m["value"] > 0 for m in plain["metrics"].values())
+    # none of the image path's counter metrics, no device metric off the chip
+    assert set(traced["metrics"]) == {"tokens_per_s", "first_item_ms", "compiles_in_window"}
+    assert traced["metrics"]["tokens_per_s"] == {
+        "value": traced["metrics"]["tokens_per_s"]["value"], "unit": "tokens/s",
+    }
+    assert traced["metrics"]["tokens_per_s"]["value"] > 0
+
+
+def test_the_token_path_s_records_carry_the_stream_s_stamps(token_runs):
+    _, _, run = token_runs
+    assert run.requests and run.cell.path.THROUGHPUT is None
+    for r in run.requests:
+        assert r["ok"] and r["work"] in (8, 12) and r["kind"] == (16, r["work"])
+        assert "pixels" not in r and "server_ms" not in r
+        assert r["start"] < r["first_item"] <= r["items"][-1] == r["end"]
+        assert r["latency_ms"] == (r["end"] - r["start"]) * 1000.0
+    assert window_metrics.work_served(run, *run.window) > 0
+
+
+def test_a_streamed_reply_is_stamped_item_by_item():
+    """The app's service carries no stream to an outside client (it
+    publishes ``generate`` alone), so the per-item stamps are proven on a
+    service that publishes a generator, as ``tests/test_streaming.py``
+    builds one: the path's ``perform``, unchanged, stamps every token."""
+    import asyncio
+    import importlib
+    import time
+
+    from bioengine_tpu.rpc.client import connect_to_server
+    from bioengine_tpu.rpc.server import RpcServer
+
+    toy_overlay.mount()
+    from benchmarks.paths import generate
+
+    async def generate_stream(prompt: str, max_new_tokens: int = 4, context=None):
+        for index in range(max_new_tokens):
+            await asyncio.sleep(0.002)
+            yield {"token": (ord(prompt[0]) + index) % 256, "index": index}
+
+    async def served() -> tuple[float, dict]:
+        server = RpcServer(admin_users=["admin"])
+        await server.start()
+        try:
+            conn = await connect_to_server({
+                "server_url": f"http://127.0.0.1:{server.port}",
+                "token": server.issue_token("admin"),
+            })
+            await conn.register_service(
+                {"id": "toy-stream", "generate_stream": generate_stream}
+            )
+            before = time.perf_counter()
+            reply = await generate.perform(
+                conn, "toy-stream", {"method": "generate_stream"}, None,
+                {"prompt": "abc", "max_new_tokens": 6}, "s-0",
+            )
+            await conn.disconnect()
+            return before, reply
+        finally:
+            await server.stop()
+
+    before, reply = asyncio.run(served())
+    assert reply["ok"] and reply["output"] == [97, 98, 99, 100, 101, 102]
+    assert len(reply["items"]) == 6 and reply["items"] == sorted(reply["items"])
+    assert before < reply["first_item"] == reply["items"][0]
+    assert reply["end"] == reply["items"][-1]
+    # the tokens came 2 ms apart and were stamped as they came, not at the end
+    assert reply["items"][-1] - reply["items"][0] >= 5 * 0.0015
+    run = harness.RunData(
+        cell=None, seconds=1.0, window=(before, reply["end"]),
+        requests=[{**reply, "start": before}], counters={}, compiles_in_window=0,
+    )
+    gap = importlib.import_module("benchmarks.layer_metrics.item_gap_ms")
+    assert gap.read(run) >= 1.5
+
+
+def test_a_broken_token_path_comes_out_not_correct(monkeypatch, tmp_path):
+    """A token altered where it is produced: the decode engine's step
+    hands back the next character instead of the one it chose."""
+    from bioengine_tpu.runtime.decode_engine import DecodeEngine
+
+    sound = DecodeEngine.step
+
+    def altered(self, seq_ids, tokens):
+        return [(t + 1) % 256 for t in sound(self, seq_ids, tokens)]
+
+    monkeypatch.setattr(DecodeEngine, "step", altered)
+    line = harness.run_cell(
+        toy_overlay.toy_cell(), SEED + 9, 1.0, False, platform="cpu", out_dir=tmp_path
+    )
+    assert line["correct"] is False and line["attempted"] > 0
+    value, limit = line["checks"]["logit_gap"]
+    assert value > limit
+
+
+def test_nothing_to_compare_reads_not_comparable_under_the_limits_own_keys():
+    assert harness.not_comparable({"logit_gap": 0.5}) == {"logit_gap": harness.NOT_COMPARABLE}
+    checks = harness.judge(harness.not_comparable({"a": 1.0, "b": 2.0}), {"a": 1.0, "b": 2.0}, 0)
+    assert not harness.is_correct(checks)
+
+
+# ---- a new path and its cell arrive as new files and new entries only ----------------
+
+
+def test_a_new_path_and_its_cell_are_added_without_an_edit(tmp_path):
+    """The benchmark copied to a temporary root, the toy path's files
+    added beside it (none may be there already) and its entries appended
+    to ``BENCHMARK.json``; the copy's own manifest and loading tests pass
+    there, in a process that sees the copy alone."""
+    import os
+    import subprocess
+    import sys
+
+    added = toy_overlay.merged_tree(tmp_path)
+    assert {a.split("/")[1] for a in added} == {
+        "paths", "generators", "traffic", "configs", "references", "work",
+        "layer_metrics", "fixtures",
+    }
+    for file in sorted((REPO / "benchmarks").rglob("*")):
+        if file.is_file() and "__pycache__" not in file.parts:
+            copy = tmp_path / file.relative_to(REPO)
+            assert copy.read_bytes() == file.read_bytes(), f"{file} was edited"
+    real, merged = harness.load_manifest(), harness.load_manifest(tmp_path)
+    assert merged["configs"][:-1] == real["configs"]
+    assert merged["workloads"][:-1] == real["workloads"]
+    assert merged["end_to_end"] == real["end_to_end"]
+    assert [w["name"] for w in merged["workloads"]][-1] == toy_overlay.CELL
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(tmp_path))
+    done = subprocess.run(
+        [
+            sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+            "-p", "no:xdist", "-p", "no:randomly",
+            "benchmarks/tests/test_benchmarks.py", "-k",
+            "manifest_names_files_and_moves or every_cell_loads or tile_counts",
+        ],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert done.returncode == 0, done.stdout[-3000:] + done.stderr[-2000:]
+    assert "3 passed" in done.stdout

@@ -10,7 +10,7 @@ that a ``time.time_ns()`` of the traced process can be laid onto it.
 
   busy      union of the intervals in which an operation ran on a device
   modules   every program execution: name, start, duration
-  top ops   operations summed by name
+  top ops   operations summed by kind, clipped to a window like busy
   gaps      the idle intervals between operations, longest first, each
             labelled by the host event that overlapped it most
 
@@ -128,13 +128,18 @@ class Reduced:
                     out.setdefault(name, []).append(dur / 1e9)
         return out
 
-    def top_ops(self, n: int = 10) -> list[list]:
-        """Device seconds summed by kind of operation (``op_kind``)."""
+    def top_ops(self, n: int = 10, window: Optional[Interval] = None) -> list[list]:
+        """Device seconds summed by kind of operation (``op_kind``), of
+        the part of each operation that lies inside ``window``: the same
+        clip as ``busy_s``, so that the two can be divided."""
+        lo, hi = window or (self.lo, self.hi)
         total: dict[str, int] = {}
         for dev in self.devices:
-            for name, _, dur in dev.ops:
-                kind = op_kind(name)
-                total[kind] = total.get(kind, 0) + dur
+            for name, start, dur in dev.ops:
+                inside = min(start + dur, hi) - max(start, lo)
+                if inside > 0:
+                    kind = op_kind(name)
+                    total[kind] = total.get(kind, 0) + inside
         ranked = sorted(total.items(), key=lambda kv: -kv[1])[:n]
         return [[name, dur / 1e9] for name, dur in ranked]
 

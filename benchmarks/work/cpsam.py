@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import math
 
+from benchmarks.references._common import model_kwargs
 from benchmarks.references.cpsam import DEFAULTS, param_shapes
 
 
@@ -49,3 +50,22 @@ def min_bytes(shape: tuple[int, ...], kwargs: dict) -> float:
 def flops_per_pixel(kwargs: dict, in_channels: int, tile: int) -> float:
     """Per useful input pixel: one native tile's work over its pixels."""
     return flops((1, tile, tile, in_channels), kwargs) / (tile * tile)
+
+
+# ---- what the readers call: a configuration, and the path's program key ----------
+
+
+def flops_per_unit(config: dict) -> float:
+    """Operations per unit of work as the path counts it: an input pixel."""
+    return flops_per_pixel(
+        model_kwargs(config), int(config["in_channels"]), int(config["native_tile"])
+    )
+
+
+def program_flops(key: tuple[int, ...], config: dict) -> float:
+    """The image path's program key is the program's input shape."""
+    return flops(key, model_kwargs(config))
+
+
+def program_min_bytes(key: tuple[int, ...], config: dict) -> float:
+    return min_bytes(key, model_kwargs(config))
