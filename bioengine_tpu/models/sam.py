@@ -23,15 +23,21 @@ map.
 TPU notes: matmuls run bf16 on the MXU. Attention never writes its
 (B*heads, N, N) scores to HBM: the decomposed rel-pos bias
 ``bias_h[n, k_h] + bias_w[n, k_w]`` is folded into the QK^T contraction
-(``SAMAttention``: q gets the two bias rows appended, k the one-hot of
-its own row and column, so ``q' k'^T`` is the biased score; for cpsam
-64 + 32 + 32 = 128, the lane width) and ``softmax(q' k'^T) v`` runs
-through ``ops.attention`` — the fused Pallas kernel on a TPU, its plain
-XLA reference elsewhere. Adding the bias at score size instead (a 5-D
-broadcast, a copy and an f32 softmax over 512 MB a block) was 60-65 % of
-the served step's device time (PERF.md section 6, PR 27). Window
-partition is a reshape (no data movement beyond layout). Shapes are
-static per (H, W) bucket as everywhere else in the framework.
+(q gets the two bias rows appended, k the one-hot of its own row and
+column, so ``q' k'^T`` is the biased score; for cpsam 64 + 32 + 32 =
+128, the lane width). ``SAMAttention`` hands ``ops.attention
+.packed_attention`` the qkv projection's output as it is and the two
+tables; on a TPU a global block's 64-wide heads on a 32 x 32 grid go
+through the packed Pallas kernel, which reads head pairs out of that
+array, forms the bias rows, q' and k' in VMEM and writes what ``proj``
+reads: no attention operand is relaid in HBM (those relayouts were 42 %
+of the served step's device time, the score-size bias before them
+60-65 %; PERF.md section 6, PRs 31 and 27). Windows, other grids and
+other backends unpack to ``(B, heads, N, .)`` operands inside
+``ops.attention``: the fused kernel on a TPU, its plain XLA reference
+elsewhere. Window partition is a reshape (no data movement beyond
+layout). Shapes are static per (H, W) bucket as everywhere else in the
+framework.
 """
 
 from __future__ import annotations
@@ -42,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from bioengine_tpu.ops.attention import attention
+from bioengine_tpu.ops.attention import packed_attention
 
 
 def _resize_rel_pos(rel_pos: jnp.ndarray, needed: int) -> jnp.ndarray:
@@ -57,19 +63,6 @@ def _resize_rel_pos(rel_pos: jnp.ndarray, needed: int) -> jnp.ndarray:
     ).astype(rel_pos.dtype)
 
 
-def _rel_pos_gather(q_size: int, k_size: int, rel_pos: jnp.ndarray):
-    """Decomposed relative-position table lookup (SAM's get_rel_pos):
-    returns (q_size, k_size, head_dim)."""
-    max_dist = 2 * max(q_size, k_size) - 1
-    table = _resize_rel_pos(rel_pos, max_dist)
-    coords = (
-        jnp.arange(q_size)[:, None] * max(k_size / q_size, 1.0)
-        - jnp.arange(k_size)[None, :] * max(q_size / k_size, 1.0)
-        + (k_size - 1) * max(q_size / k_size, 1.0)
-    )
-    return table[coords.astype(jnp.int32)]
-
-
 class SAMAttention(nn.Module):
     """Multi-head attention over a (B, H, W, dim) token grid with SAM's
     decomposed relative position bias.
@@ -82,13 +75,11 @@ class SAMAttention(nn.Module):
     against the declared shape at apply time).
 
     The bias of query n = (h, w) against key (k_h, k_w) is
-    ``q[n]·Rh[h, k_h] + q[n]·Rw[w, k_w]`` (SAM's
+    ``q[n]·rel_h[h - k_h + H - 1] + q[n]·rel_w[w - k_w + W - 1]`` (SAM's
     ``add_decomposed_rel_pos``, unscaled q). It is not added to a score
-    tensor: with ``q' = [q·hd^-½, bias_h[n, :], bias_w[n, :]]`` and
-    ``k' = [k, onehot(k_h, H), onehot(k_w, W)]`` the contraction
-    ``q'·k'`` is the biased score term for term, at depth hd + H + W and
-    scale 1. The one-hots are exact in any dtype and the contraction
-    accumulates in f32."""
+    tensor: ``ops.attention.packed_attention`` folds it into the QK^T
+    contraction, and which program runs that is read off the shapes
+    there, never set here."""
 
     dim: int
     num_heads: int
@@ -100,11 +91,6 @@ class SAMAttention(nn.Module):
         B, H, W, _ = x.shape
         nh, hd = self.num_heads, self.dim // self.num_heads
         qkv = nn.Dense(3 * self.dim, dtype=self.dtype, name="qkv")(x)
-        qkv = qkv.reshape(B, H * W, 3, nh, hd)
-        q, k, v = jnp.moveaxis(qkv, 2, 0)  # (B, N, nh, hd)
-        q = jnp.moveaxis(q, 2, 1)  # (B, nh, N, hd)
-        k = jnp.moveaxis(k, 2, 1)
-        v = jnp.moveaxis(v, 2, 1)
 
         rel_h = self.param(
             "rel_pos_h",
@@ -118,32 +104,14 @@ class SAMAttention(nn.Module):
             (2 * self.table_size - 1, hd),
             jnp.float32,
         )
-        Rh = _rel_pos_gather(H, H, rel_h).astype(self.dtype)  # (H, H, hd)
-        Rw = _rel_pos_gather(W, W, rel_w).astype(self.dtype)  # (W, W, hd)
-        q_r = q.reshape(B, nh, H, W, hd)
-        bias_h = jnp.einsum("bnhwc,hkc->bnhwk", q_r, Rh)
-        bias_w = jnp.einsum("bnhwc,wkc->bnhwk", q_r, Rw)
-        q_fold = jnp.concatenate(
-            [
-                q * (hd**-0.5),
-                bias_h.reshape(B, nh, H * W, H),
-                bias_w.reshape(B, nh, H * W, W),
-            ],
-            axis=-1,
-        )
-        key_pos = jnp.concatenate(
-            [
-                jnp.repeat(jnp.eye(H, dtype=self.dtype), W, axis=0),
-                jnp.tile(jnp.eye(W, dtype=self.dtype), (H, 1)),
-            ],
-            axis=-1,
-        )  # (N, H + W): key n = (n // W, n % W), one-hot twice
-        k_fold = jnp.concatenate(
-            [k, jnp.broadcast_to(key_pos, (B, nh, H * W, H + W))], axis=-1
-        )
-
-        out = attention(q_fold, k_fold, v, scale=1.0)  # (B, nh, N, hd)
-        out = jnp.moveaxis(out, 1, 2).reshape(B, H, W, self.dim)
+        out = packed_attention(
+            qkv.reshape(B, H * W, 3 * self.dim),
+            _resize_rel_pos(rel_h, 2 * H - 1).astype(self.dtype),
+            _resize_rel_pos(rel_w, 2 * W - 1).astype(self.dtype),
+            grid=(H, W),
+            heads=nh,
+        )  # (B, N, dim)
+        out = out.reshape(B, H, W, self.dim)
         return nn.Dense(self.dim, dtype=self.dtype, name="proj")(out)
 
 

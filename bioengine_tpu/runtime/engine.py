@@ -430,7 +430,7 @@ class InferenceEngine:
                 # cold one's carries the real 20-40 s entries
                 "cache_hits": {k: v["cache_hit"] for k, v in mine.items()},
                 # attention calls traced into each program, by path and
-                # N: a served cpsam program reads {"fused:1024": 24}
+                # N: a served cpsam program reads {"packed:1024": 24}
                 "attention_paths": {
                     k: v["attention_paths"] for k, v in mine.items()
                 },

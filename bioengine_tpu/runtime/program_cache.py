@@ -56,7 +56,7 @@ class CacheStats:
     # per-key cache_hit verdict, same lifecycle as compile_seconds
     cache_hit: dict = field(default_factory=dict)
     # per-key attention calls traced into the program while it was
-    # built, by path (``{"fused:1024": 24}``: attention_traced_total's
+    # built, by path (``{"packed:1024": 24}``: attention_traced_total's
     # rise over build()), same lifecycle. A served cpsam program that
     # reads ``xla`` on a TPU is running without its kernel.
     attention_paths: dict = field(default_factory=dict)

@@ -20,8 +20,9 @@ seconds and the ``device_kind`` it ran on:
   trace    start_profiling -> 4 requests -> stop_profiling; the xplane
            must hold a TPU device plane with events
   kernel   the Pallas attention kernel compiled by Mosaic at the
-           ViT-B/14@448 and cpsam shapes (plain depth, and the served
-           program's folded 128/64 depth), forward and gradient
+           ViT-B/14@448 and cpsam shapes (plain depth, the folded
+           128/64 depth, and the packed call of the served program
+           over the qkv projection's layout), forward and gradient
   stop     worker.stop(); no thread may outlive it
 
 Nothing is caught and continued: the first failed check raises, the
@@ -47,6 +48,7 @@ import argparse
 import asyncio
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 import shutil
@@ -74,6 +76,9 @@ KERNEL_SHAPES = (
     (1, 16, 1024, 64, 64, None),
     (16, 16, 1024, 128, 64, 1.0),
 )
+# (B, heads, (H, W)) at 64-wide heads: what the served cpsam program runs
+# since PR 31, the packed call over the qkv projection's own layout
+PACKED_SHAPES = ((16, 16, (32, 32)),)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,6 +95,7 @@ class SmokeConfig:
     out_dir: Path = DEFAULT_OUT
     parity_with: Optional[Path] = None
     kernel_shapes: tuple = KERNEL_SHAPES
+    packed_shapes: tuple = PACKED_SHAPES
 
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -630,8 +636,14 @@ def kernel(cfg: SmokeConfig, report: Report) -> None:
     import jax
     import jax.numpy as jnp
 
-    from bioengine_tpu.ops.attention import reference_attention
-    from bioengine_tpu.ops.pallas.attention import flash_attention
+    from bioengine_tpu.ops.attention import (
+        reference_attention,
+        unpacked_attention,
+    )
+    from bioengine_tpu.ops.pallas.attention import (
+        flash_attention,
+        packed_flash_attention,
+    )
 
     on_chip = cfg.platform == "tpu"  # the rehearsal interprets the kernel
 
@@ -649,6 +661,13 @@ def kernel(cfg: SmokeConfig, report: Report) -> None:
             return fn(q, k, v, causal, scale).astype(jnp.float32).sum()
 
         return jax.jit(jax.grad(total, argnums=(0, 1, 2)))
+
+    def packed_grads(fn):
+        return jax.jit(
+            jax.grad(
+                lambda *a: fn(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+            )
+        )
 
     with report.phase("kernel") as info:
         errors = {}
@@ -683,6 +702,46 @@ def kernel(cfg: SmokeConfig, report: Report) -> None:
                     for g, w, name in zip(got, want, "qkv")
                 )
                 errors[tag] = [round(fwd, 5), round(grad, 5)]
+        for B, heads, (H, W) in cfg.packed_shapes:
+            tag = f"packed-{B}x{heads}x{H}x{W}"
+            operands = jax.jit(
+                lambda: tuple(
+                    scale * jax.random.normal(key, shape, jnp.bfloat16)
+                    for key, scale, shape in zip(
+                        jax.random.split(jax.random.key(1), 3),
+                        (1.0, 0.3, 0.3),
+                        (
+                            (B, H * W, 3 * heads * 64),
+                            (2 * H - 1, 64),
+                            (2 * W - 1, 64),
+                        ),
+                    )
+                )
+            )()
+            how = dict(grid=(H, W), heads=heads, interpret=not on_chip)
+            packed = functools.partial(packed_flash_attention, **how)
+            plain = functools.partial(
+                unpacked_attention, reference_attention,
+                grid=(H, W), heads=heads,
+            )
+            lowered = packed_flash_attention.lower(*operands, **how)
+            check(
+                not on_chip or "tpu_custom_call" in lowered.as_text(),
+                f"kernel {tag}: lowered module holds no Mosaic custom call",
+            )
+            fwd = assert_close(
+                packed(*operands), jax.jit(plain)(*operands),
+                f"kernel {tag} forward",
+            )
+            grad = max(
+                assert_close(g, w, f"kernel {tag} d{name}")
+                for g, w, name in zip(
+                    packed_grads(packed)(*operands),
+                    packed_grads(plain)(*operands),
+                    ("qkv", "rel_h", "rel_w"),
+                )
+            )
+            errors[tag] = [round(fwd, 5), round(grad, 5)]
         info.update(max_abs_err_fwd_grad=errors)
 
 

@@ -58,12 +58,14 @@ async def test_serving_phases_at_toy_width(tmp_path, monkeypatch, capsys):
 def test_kernel_phase_at_toy_size(tmp_path, capsys):
     """The kernel phase with the kernel interpreted: a plain shape whose
     N is no multiple of the block, and the folded form the served cpsam
-    program runs (q/k depth != v depth, scale 1), forward and gradient,
-    both also causal."""
+    program ran (q/k depth != v depth, scale 1), forward and gradient,
+    both also causal; and the packed call it runs now, on a grid of
+    unequal extents."""
     cfg = chip_smoke.SmokeConfig(
         platform="cpu",
         out_dir=tmp_path,
         kernel_shapes=((1, 2, 100, 32, 32, None), (2, 2, 64, 48, 16, 1.0)),
+        packed_shapes=((1, 2, (16, 48)),),
     )
     report = chip_smoke.Report()
     try:
@@ -76,5 +78,5 @@ def test_kernel_phase_at_toy_size(tmp_path, capsys):
         if line.startswith("[chip_smoke] kernel")
     ]
     assert " ok " in line
-    for tag in ("1x2x100x32/32", "2x2x64x48/16-causal"):
+    for tag in ("1x2x100x32/32", "2x2x64x48/16-causal", "packed-1x2x16x48"):
         assert tag in line

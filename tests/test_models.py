@@ -1,3 +1,5 @@
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -380,14 +382,29 @@ class TestGoldenCpSAM:
         )
 
 
+def _rel_pos_gather(q_size: int, k_size: int, rel_pos):
+    """segment-anything's ``get_rel_pos``: the table interpolated to the
+    largest relative distance, then looked up -> (q_size, k_size, hd)."""
+    max_dist = 2 * max(q_size, k_size) - 1
+    table = rel_pos
+    if table.shape[0] != max_dist:
+        table = jax.image.resize(
+            table, (max_dist, table.shape[1]), method="linear"
+        )
+    coords = (
+        jnp.arange(q_size)[:, None] * max(k_size / q_size, 1.0)
+        - jnp.arange(k_size)[None, :] * max(q_size / k_size, 1.0)
+        + (k_size - 1) * max(q_size / k_size, 1.0)
+    )
+    return table[coords.astype(jnp.int32)]
+
+
 def _sam_attention_5d(params, x, num_heads):
     """SAM's ``add_decomposed_rel_pos`` as segment-anything states it:
     the scores reshaped to (H, W, H, W), the two biases broadcast onto
     them, a softmax over the stored scores. What ``SAMAttention`` ran
     before it folded the bias into the contraction (PR 27); kept here as
     the plain statement the fold is held to."""
-    from bioengine_tpu.models.sam import _rel_pos_gather
-
     B, H, W, dim = x.shape
     hd = dim // num_heads
     qkv = x @ params["qkv"]["kernel"] + params["qkv"]["bias"]
@@ -423,17 +440,41 @@ class TestFoldedRelPos:
         "non-square": ((6, 10), 10, False),
         "window-14-padded": ((20, 17), 14, True),
         "table-resized-at-use": ((8, 8), 5, False),
+        # cpsam's global block at a toy width: two 64-wide heads on a
+        # 32 x 32 grid, which the packed kernel takes on a TPU
+        "packs-on-a-tpu": ((32, 32), 20, False),
     }
+    WIDER = {"packs-on-a-tpu": 128}
+    # (forward, gradient) tolerances: sums over 1024 keys and 128
+    # channels round further apart than over 64 and 32
+    ATOL = {"default": (1e-5, 2e-5), "packs-on-a-tpu": (5e-5, 2e-4)}
+
+    @pytest.fixture(params=["cpu", "tpu-pretended"])
+    def backend(self, request, monkeypatch):
+        """The CPU the suite names, or ``jax.default_backend()`` saying
+        "tpu" with both kernels interpreted: the model code and the
+        dispatcher run exactly what they run on the chip."""
+        if request.param == "cpu":
+            return
+        from bioengine_tpu.ops.pallas import attention as kernels
+
+        for name in ("flash_attention", "packed_flash_attention"):
+            monkeypatch.setattr(
+                kernels, name,
+                functools.partial(getattr(kernels, name), interpret=True),
+            )
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def _setup(self, case):
         from bioengine_tpu.models.sam import SAMAttention, _window_partition
 
         (H, W), table, windowed = self.CASES[case]
+        dim = self.WIDER.get(case, self.DIM)
         rng = np.random.default_rng(3)
-        x = jnp.asarray(rng.normal(size=(2, H, W, self.DIM)), jnp.float32)
+        x = jnp.asarray(rng.normal(size=(2, H, W, dim)), jnp.float32)
         if windowed:
             x, _ = _window_partition(x, 14)  # zeros at the bottom and right
-        module = SAMAttention(self.DIM, self.HEADS, table, jnp.float32)
+        module = SAMAttention(dim, self.HEADS, table, jnp.float32)
         params = module.init(jax.random.key(0), x)["params"]
         # the tables initialise to zeros: give them something to get wrong
         params = {
@@ -448,12 +489,13 @@ class TestFoldedRelPos:
         return module, params, x
 
     @pytest.mark.parametrize("case", list(CASES))
-    def test_forward_equals_the_5d_formulation(self, case):
+    def test_forward_equals_the_5d_formulation(self, case, backend):
         module, params, x = self._setup(case)
         got = module.apply({"params": params}, x)
         want = _sam_attention_5d(params, x, self.HEADS)
         assert float(jnp.abs(want).max()) > 0.1
-        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+        atol, _ = self.ATOL.get(case, self.ATOL["default"])
+        np.testing.assert_allclose(got, want, atol=atol, rtol=1e-5)
 
     def test_a_wrong_fold_is_seen(self):
         """The comparison has teeth: with the row and column tables
@@ -469,7 +511,7 @@ class TestFoldedRelPos:
         assert float(jnp.abs(got - wrong).max()) > 1e-2
 
     @pytest.mark.parametrize("case", list(CASES))
-    def test_gradients_equal_the_5d_formulation(self, case):
+    def test_gradients_equal_the_5d_formulation(self, case, backend):
         module, params, x = self._setup(case)
         weights = jnp.asarray(
             np.random.default_rng(4).normal(size=x.shape), jnp.float32
@@ -482,14 +524,15 @@ class TestFoldedRelPos:
             return jnp.sum(_sam_attention_5d(p, x, self.HEADS) * weights)
 
         got, want = jax.grad(folded)(params), jax.grad(plain)(params)
+        _, atol = self.ATOL.get(case, self.ATOL["default"])
         for name in ("rel_pos_h", "rel_pos_w"):
             assert float(jnp.abs(want[name]).max()) > 1e-3
             np.testing.assert_allclose(
-                got[name], want[name], atol=2e-5, rtol=1e-4, err_msg=name
+                got[name], want[name], atol=atol, rtol=1e-4, err_msg=name
             )
         for leaf in ("kernel", "bias"):
             np.testing.assert_allclose(
-                got["qkv"][leaf], want["qkv"][leaf], atol=2e-5, rtol=1e-4,
+                got["qkv"][leaf], want["qkv"][leaf], atol=atol, rtol=1e-4,
                 err_msg=f"qkv.{leaf}",
             )
 
@@ -515,6 +558,25 @@ class TestFoldedRelPos:
         before = traced_paths()
         jax.jit(module.apply)({"params": params}, x)
         assert traced_paths(since=before) == {"xla:60": 1}
+
+    @pytest.mark.parametrize(
+        "case,path",
+        [
+            ("packs-on-a-tpu", "packed:1024"),
+            ("global-square", "fused:64"),
+            ("window-14-padded", "fused:196"),
+        ],
+    )
+    def test_on_a_tpu_the_shape_decides_the_path(self, case, path, monkeypatch):
+        """Nothing of the module says which kernel: a 32 x 32 grid of
+        64-wide heads packs, a window and a toy width do not."""
+        from bioengine_tpu.ops.attention import traced_paths
+
+        module, params, x = self._setup(case)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        before = traced_paths()
+        jax.eval_shape(module.apply, {"params": params}, x)
+        assert traced_paths(since=before) == {path: 1}
 
 
 class TestGoldenFlows:

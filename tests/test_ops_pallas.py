@@ -12,14 +12,18 @@ import pytest
 
 from bioengine_tpu.ops.attention import (
     attention,
+    packed_attention,
     reference_attention,
     traced_paths,
+    unpacked_attention,
 )
 from bioengine_tpu.ops.pallas import attention as kernel_module
 from bioengine_tpu.ops.pallas.attention import (
     _block_sizes,
     flash_attention,
     make_attn_fn,
+    packed_flash_attention,
+    packs,
 )
 
 
@@ -347,6 +351,255 @@ class TestPartitionedUnderGspmd:
             np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4)
 
 
+def _packed_operands(grid, heads, hd=64, batch=2, dtype=jnp.float32, seed=13):
+    """What ``SAMAttention`` hands ``packed_attention``: the projection's
+    output and the two relative-position tables at the grid's extent."""
+    H, W = grid
+    rng = np.random.default_rng(seed)
+    return (
+        jnp.asarray(rng.normal(size=(batch, H * W, 3 * heads * hd)), dtype),
+        jnp.asarray(0.3 * rng.normal(size=(2 * H - 1, hd)), dtype),
+        jnp.asarray(0.3 * rng.normal(size=(2 * W - 1, hd)), dtype),
+    )
+
+
+def _unpacked_reference(qkv, rel_h, rel_w, grid, heads):
+    return unpacked_attention(
+        reference_attention, qkv, rel_h, rel_w, grid, heads
+    )
+
+
+class TestPackedAttention:
+    """The packed entry, interpreted, at a toy width with hd 64: 128-lane
+    head pairs cut out of the projection's output, the bias rows, q' and
+    k' formed in the kernel. The reference is the plain one over the
+    unpacked ``(B, heads, N, .)`` operands."""
+
+    @pytest.mark.parametrize(
+        "grid,dtype,atol",
+        [
+            ((32, 32), jnp.float32, 5e-5),
+            ((32, 32), jnp.bfloat16, 3e-2),
+            # rows and columns of unequal extent: the two tables' lanes
+            # and both rotations differ
+            ((16, 48), jnp.float32, 5e-5),
+            ((48, 16), jnp.bfloat16, 3e-2),
+        ],
+    )
+    def test_matches_the_reference_over_unpacked_operands(
+        self, grid, dtype, atol
+    ):
+        operands = _packed_operands(grid, heads=4, dtype=dtype)
+        out = packed_flash_attention(*operands, grid=grid, heads=4)
+        ref = _unpacked_reference(*operands, grid, 4)
+        assert out.shape == ref.shape == (2, grid[0] * grid[1], 256)
+        assert out.dtype == dtype
+        assert float(jnp.abs(ref.astype(jnp.float32)).max()) > 1.0
+        np.testing.assert_allclose(
+            out.astype(np.float32), ref.astype(np.float32), atol=atol
+        )
+
+    def test_the_bias_is_seen(self):
+        """The comparison has teeth: with the two tables swapped the
+        result moves far beyond the tolerance."""
+        qkv, rel_h, rel_w = _packed_operands((32, 32), heads=2)
+        out = packed_flash_attention(qkv, rel_h, rel_w, grid=(32, 32), heads=2)
+        wrong = _unpacked_reference(qkv, rel_w, rel_h, (32, 32), 2)
+        assert float(jnp.abs(out - wrong).max()) > 1e-2
+
+    def test_gradients_are_the_reference_s(self):
+        grid, heads = (32, 32), 2
+        operands = _packed_operands(grid, heads, batch=1)
+        weights = jnp.asarray(
+            np.random.default_rng(5).normal(size=(1, 1024, 128)), jnp.float32
+        )
+
+        def packed(*operands):
+            out = packed_flash_attention(*operands, grid=grid, heads=heads)
+            return jnp.sum(out * weights)
+
+        def plain(*operands):
+            return jnp.sum(_unpacked_reference(*operands, grid, heads) * weights)
+
+        got = jax.grad(packed, argnums=(0, 1, 2))(*operands)
+        want = jax.grad(plain, argnums=(0, 1, 2))(*operands)
+        for g, w, name in zip(got, want, ("qkv", "rel_h", "rel_w")):
+            assert g.shape == w.shape
+            assert float(jnp.abs(w).max()) > 1e-3, name
+            np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4, err_msg=name)
+
+    def test_refuses_what_does_not_pack(self):
+        operands = _packed_operands((14, 14), heads=2)
+        with pytest.raises(ValueError, match="does not pack"):
+            packed_flash_attention(*operands, grid=(14, 14), heads=2)
+
+    # (grid, heads, hd) -> whether two heads and their bias rows fill the
+    # lanes, the sequence is one unpadded kv step, and a q block holds
+    # whole rows of the grid
+    @pytest.mark.parametrize(
+        "grid,heads,hd,expected",
+        [
+            ((32, 32), 16, 64, True),    # cpsam's global block
+            ((16, 48), 4, 64, True),
+            ((14, 14), 16, 64, False),   # SAM's window: 64 + 28 lanes
+            ((64, 64), 16, 64, False),   # 64 + 128 lanes, two kv steps
+            ((16, 16), 4, 32, False),    # four heads to the lane width
+            ((32, 32), 3, 64, False),    # a head without its pair
+            ((30, 34), 2, 64, False),    # 1020 tokens: padding
+            ((60, 4), 2, 64, False),     # rows of half a sublane tile
+        ],
+    )
+    def test_which_shapes_pack(self, grid, heads, hd, expected):
+        n = grid[0] * grid[1]
+        assert packs(n, heads * hd, grid, heads) is expected
+
+
+class TestPackedDispatch:
+    """``ops.attention.packed_attention``: the packed kernel where the
+    backend is a TPU and the lanes line up, everything else unpacked to
+    ``attention``; the counter says which. Tracing alone counts, so the
+    shapes are traced (``eval_shape``), not run."""
+
+    @staticmethod
+    def _trace(grid, heads, hd=64, batch=2):
+        H, W = grid
+        shapes = (
+            jax.ShapeDtypeStruct((batch, H * W, 3 * heads * hd), jnp.bfloat16),
+            jax.ShapeDtypeStruct((2 * H - 1, hd), jnp.bfloat16),
+            jax.ShapeDtypeStruct((2 * W - 1, hd), jnp.bfloat16),
+        )
+        before = traced_paths()
+        out = jax.eval_shape(
+            lambda *a: packed_attention(*a, grid=grid, heads=heads), *shapes
+        )
+        assert out.shape == (batch, H * W, heads * hd)
+        return traced_paths(since=before)
+
+    def test_cpu_unpacks_to_the_reference(self):
+        assert self._trace((32, 32), 4) == {"xla:1024": 1}
+
+    @pytest.mark.parametrize(
+        "grid,heads,hd,expected",
+        [
+            ((32, 32), 16, 64, {"packed:1024": 1}),
+            ((16, 48), 4, 64, {"packed:768": 1}),
+            ((14, 14), 16, 64, {"fused:196": 1}),
+            ((64, 64), 16, 64, {"fused:4096": 1}),
+            ((16, 16), 4, 32, {"fused:256": 1}),
+            ((32, 32), 3, 64, {"fused:1024": 1}),
+        ],
+    )
+    def test_tpu_backend_packs_where_the_lanes_line_up(
+        self, monkeypatch, grid, heads, hd, expected
+    ):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert self._trace(grid, heads, hd) == expected
+
+    def test_tpu_backend_runs_the_packed_kernel(self, monkeypatch):
+        """The backend pretended, the kernel interpreted: same result
+        as the CPU's unpacked reference."""
+        calls = []
+
+        def interpreted(*operands, **kwargs):
+            calls.append(kwargs)
+            return packed_flash_attention(*operands, interpret=True, **kwargs)
+
+        operands = _packed_operands((32, 32), heads=2)
+        want = packed_attention(*operands, grid=(32, 32), heads=2)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(
+            kernel_module, "packed_flash_attention", interpreted
+        )
+        got = packed_attention(*operands, grid=(32, 32), heads=2)
+        assert calls == [{"grid": (32, 32), "heads": 2}]
+        np.testing.assert_allclose(got, want, atol=5e-5)
+
+    def test_program_cache_records_the_packed_depth(self, monkeypatch):
+        from bioengine_tpu.runtime.program_cache import CompiledProgramCache
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cache = CompiledProgramCache()
+
+        def build():
+            self._trace((32, 32), 4)
+            self._trace((32, 32), 4)
+            self._trace((14, 14), 4)
+
+        cache.get_or_compile(("three-blocks", 1024), build)
+        info = cache.compile_info_snapshot()
+        assert info[str(("three-blocks", 1024))]["attention_paths"] == {
+            "packed:1024": 2, "fused:196": 1,
+        }
+
+
+class TestPackedUnderGspmd:
+    """The packed call under a CPU ``dp`` mesh: per shard, the tables
+    whole on every device, no collective."""
+
+    @staticmethod
+    def _operands(devices):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        mesh = Mesh(np.array(devices), ("dp",))
+        qkv, rel_h, rel_w = _packed_operands((32, 32), heads=2, batch=8)
+        sharded = (
+            jax.device_put(qkv, NamedSharding(mesh, P("dp"))),
+            jax.device_put(rel_h, NamedSharding(mesh, P())),
+            jax.device_put(rel_w, NamedSharding(mesh, P())),
+        )
+        return (qkv, rel_h, rel_w), sharded
+
+    def test_batch_sharded_runs_per_shard_with_no_collective(self, devices):
+        plain, sharded = self._operands(devices[:4])
+        fn = jax.jit(
+            lambda *a: packed_flash_attention(*a, grid=(32, 32), heads=2)
+        )
+        out = fn(*sharded)
+        assert out.sharding.spec[0] == "dp"
+        np.testing.assert_allclose(
+            out, _unpacked_reference(*plain, (32, 32), 2), atol=5e-5
+        )
+        hlo = fn.lower(*sharded).compile().as_text()
+        assert "all-gather" not in hlo and "all-reduce" not in hlo
+
+    def test_batch_that_does_not_divide_takes_the_reference(
+        self, devices, monkeypatch
+    ):
+        _, (qkv, rel_h, rel_w) = self._operands(devices[:4])
+        six = (qkv[:6], rel_h, rel_w)
+        with pytest.raises(ValueError, match="does not divide"):
+            jax.jit(
+                lambda *a: packed_flash_attention(*a, grid=(32, 32), heads=2)
+            )(*six)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        before = traced_paths()
+        jax.jit(lambda *a: packed_attention(*a, grid=(32, 32), heads=2))(*six)
+        assert traced_paths(since=before) == {"xla:1024": 1}
+
+    def test_gradients_under_a_dp_mesh(self, devices):
+        plain, sharded = self._operands(devices[:4])
+
+        def loss(fn):
+            return lambda *a: jnp.sum(fn(*a) ** 2)
+
+        got = jax.jit(
+            jax.grad(
+                loss(
+                    lambda *a: packed_flash_attention(
+                        *a, grid=(32, 32), heads=2
+                    )
+                ),
+                argnums=(0, 1, 2),
+            )
+        )(*sharded)
+        want = jax.grad(
+            loss(lambda *a: _unpacked_reference(*a, (32, 32), 2)),
+            argnums=(0, 1, 2),
+        )(*plain)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, atol=1e-3, rtol=1e-4)
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     """One described (not attached) v5e chip: the TPU compiler is
@@ -416,3 +669,68 @@ class TestMosaicAcceptsTheServedShapes:
             .compile()
         )
         assert "tpu_custom_call" in compiled.as_text()
+
+
+class TestTheServedBlockHasNoRelayout:
+    """One cpsam block as the benchmark's configuration serves it,
+    compiled for a described v5e with the backend pretended a TPU: the
+    packed kernel is in it, and between the qkv projection and the
+    output projection nothing relays an attention operand. These passes
+    were 42 % of the served step (PERF.md section 6, PR 31); an edit that
+    brings one back fails here before it costs a chip run."""
+
+    RELAYOUTS = (
+        "copy bf16[16,1024,3,16,64]",
+        "reshape bf16[16,1024,3,16,64]",
+        "copy bf16[16,16,1024,64]",
+        "copy bf16[16,16,1024,128]",
+        "pad_maximum_fusion bf16[256,1024,128]",
+        "broadcast bf16[16,16,1024,64]",
+        "copy bf16[16,16,32,32,32]",
+        "copy bf16[16,1024,16,64]",
+    )
+
+    def test_compiles_for_v5e_without_them(
+        self, one_chip, no_compile_cache, monkeypatch
+    ):
+        import re
+
+        from bioengine_tpu.models.sam import SAMBlock
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        block = SAMBlock(1024, 16, 4.0, 0, 32)
+        params = jax.eval_shape(
+            block.init, jax.random.key(0),
+            jax.ShapeDtypeStruct((1, 32, 32, 1024), jnp.bfloat16),
+        )
+        on_chip = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            (params, jax.ShapeDtypeStruct((16, 32, 32, 1024), jnp.bfloat16)),
+        )
+        before = traced_paths()
+        text = (
+            jax.jit(block.apply)
+            .trace(*on_chip)
+            .lower(lowering_platforms=("tpu",))
+            .compile()
+            .as_text()
+        )
+        assert traced_paths(since=before) == {"packed:1024": 1}
+        assert "tpu_custom_call" in text and "packed_attention" in text
+        entry = text[text.index("ENTRY"):]
+        # "%name = type[shape]{layout} opcode(" -> "opcode-or-fusion-kind type[shape]"
+        produced = {
+            f"{re.sub(r'[.][0-9]+$', '', name) if op == 'fusion' else op} {shape}"
+            for name, shape, op in re.findall(
+                r"%([\w.\-]+) = (\w+\[[\d,]*\])\S* ([\w\-]+)\(", entry
+            )
+        }
+        # the pattern reads this dump: the qkv projection is in it
+        assert any(p.endswith("bf16[16,32,32,3072]") for p in produced)
+        assert not produced & set(self.RELAYOUTS)
+        # nothing at all the size of an attention operand but the
+        # projection's output, the kernel's and the block's own
+        assert not {
+            p for p in produced
+            if re.search(r"bf16\[16,(16,1024|1024,16|1024,3,16),", p)
+        }
