@@ -37,8 +37,6 @@ class ViTEmbedder:
     def __init__(
         self,
         weights_path: Optional[str] = None,
-        # 128 measured fastest on v5e (1912 -> 2062 img/s vs bucket 64
-        # with bf16 softmax); larger buckets regress (bench.py sweep)
         batch_bucket: int = 128,
         use_flash_attention: Optional[bool] = None,
     ) -> None:

@@ -37,8 +37,8 @@ call-graph depth in the message):
   guard the call.
 
 ``analyze --hot-path-report FILE`` emits a JSON artifact ranking every
-reachable function by finding count × call-graph depth — the starting
-map for the ``request_overhead`` bench (docs/performance.md).
+reachable function by finding count × call-graph depth
+(docs/performance.md).
 """
 
 from __future__ import annotations
@@ -267,9 +267,8 @@ REPORT_SCHEMA = "bioengine.hot-path-report/v1"
 
 def build_hot_path_report(ctx: ProjectContext) -> dict:
     """The overhead map: every function reachable from a request-path
-    root, ranked by unsuppressed finding count × call-graph depth.
-    Consumed by docs/performance.md as the starting point for the
-    ROADMAP item 3 ``request_overhead`` bench."""
+    root, ranked by unsuppressed finding count × call-graph depth
+    (docs/performance.md, "Small-request hot path")."""
     roots = collect_roots(ctx)
     reach = reachable_set(ctx, roots)
     functions = []

@@ -76,7 +76,6 @@ class ServerConnection:
         protocols: Optional[list[str]] = None,
         auto_reconnect: bool = False,
         reconnect_max_backoff_s: float = 5.0,
-        compat_pre_fast1: bool = False,
     ):
         self.url = url
         # same-host deployments skip the TCP stack entirely:
@@ -89,7 +88,8 @@ class ServerConnection:
         self.token = token
         self.timeout = timeout
         # capabilities declared at handshake; [] forces pure-legacy
-        # framing in BOTH directions (bench baseline, interop tests)
+        # framing in BOTH directions (wire interop with a peer that
+        # predates the capabilities; tests/test_rpc_fast_frames.py)
         self.protocols = (
             [
                 protocol.PROTO_OOB1,
@@ -136,12 +136,6 @@ class ServerConnection:
         # 64 random bits per request shows up on the microsecond path
         self._call_prefix = f"{tracing.new_id()[:8]}-"
         self._call_seq = 0
-        # measurement compat: reproduce the pre-fast1 per-request
-        # bookkeeping (a fresh uuid call id + an asyncio.wait_for
-        # timeout chain per call) so the request_overhead bench's
-        # baseline leg measures the pre-optimization stack in the SAME
-        # interpreter as the fast leg. Never set on production paths.
-        self._compat_request = compat_pre_fast1
         self._local_services: dict[str, dict[str, Callable]] = {}
         self._service_definitions: dict[str, dict[str, Any]] = {}
         self._reader_task: Optional[asyncio.Task] = None
@@ -533,17 +527,6 @@ class ServerConnection:
             await self._ws.close()
 
     async def _request(self, msg: dict) -> Any:
-        if self._compat_request:
-            # pre-fast1 request path, kept verbatim for the bench's
-            # baseline leg (see compat_pre_fast1 in __init__)
-            msg["call_id"] = call_id = tracing.new_id()
-            fut: asyncio.Future = asyncio.get_running_loop().create_future()
-            self._pending[call_id] = fut
-            try:
-                await self._send_msg(msg)
-                return await asyncio.wait_for(fut, self.timeout)
-            finally:
-                self._pending.pop(call_id, None)
         self._call_seq = seq = self._call_seq + 1
         msg["call_id"] = call_id = f"{self._call_prefix}{seq:x}"
         loop = asyncio.get_running_loop()
@@ -690,7 +673,7 @@ class ServerConnection:
         codec = self.codec
         ctx = tracing.current_trace()
         traced = codec.trace and ctx is not None and ctx.sampled
-        if codec.fast and not traced and not self._compat_request:
+        if codec.fast and not traced:
             # small-request hot path: encode straight from the call
             # site — the envelope dict is only built if the fast
             # encode bails (oversize / non-scalar payload)
@@ -888,6 +871,5 @@ async def connect_to_server(config: dict[str, Any]) -> ServerConnection:
         transport_config=config.get("transport_config"),
         protocols=config.get("protocols"),
         auto_reconnect=bool(config.get("reconnect", False)),
-        compat_pre_fast1=bool(config.get("compat_pre_fast1", False)),
     )
     return await conn.connect()

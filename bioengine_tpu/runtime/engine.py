@@ -646,7 +646,7 @@ class InferenceEngine:
         """The strictly serial pre-pipeline path: one chunk cut, put,
         computed, read back, and stitched at a time, one batch item
         after another. Kept as the numeric parity baseline for the
-        pipelined path and as the bench's serial leg."""
+        pipelined path."""
         images = self._validate(images)
         specs = self._axis_specs(images.ndim)
         if self._needs_tiling(images, specs):

@@ -56,8 +56,7 @@ from bioengine_tpu.utils import flight, metrics, tracing
 
 # cross-host data-plane accounting: how many activation bytes hop
 # between shards and what the hops cost — the number that says whether
-# a pipeline split is transfer-bound (surfaces in get_app_status and
-# the multihost_mesh bench stage)
+# a pipeline split is transfer-bound (surfaces in get_app_status)
 MESH_TRANSFER_BYTES = metrics.counter(
     "mesh_transfer_bytes_total",
     "activation bytes exchanged between mesh shards (both directions)",

@@ -280,11 +280,10 @@ class DeploymentHandle:
         # reassembles one cross-process tree per trace_id. Head
         # sampling (BIOENGINE_TRACE_SAMPLE) keeps the unsampled path
         # at one id mint + a few counter bumps; BIOENGINE_TRACING=0
-        # removes even that (the bench's baseline leg) — but metrics
-        # and slow-request logging have their OWN knobs and keep
-        # working with tracing off. If a sampled trace is ALREADY
-        # active (a composition call routed back through serve-router),
-        # nest under it instead of minting.
+        # removes even that — but metrics and slow-request logging
+        # have their OWN knobs and keep working with tracing off. If a
+        # sampled trace is ALREADY active (a composition call routed
+        # back through serve-router), nest under it instead of minting.
         parent = tracing.current_trace()
         ctx = parent if parent is not None else tracing.maybe_start_trace()
         token = (

@@ -37,8 +37,8 @@ is the acceptance proof for the gray-failure machinery: same seed, same
 injected degradation; with defenses the tail recovers and nothing
 fails, without them the ``p99_recovery`` invariant goes red.
 
-Entry points: :func:`run_scenario` (sync, used by the CLI / bench /
-CI) and :func:`run_scenario_async` (tests already inside a loop).
+Entry points: :func:`run_scenario` (sync, used by the CLI and CI)
+and :func:`run_scenario_async` (tests already inside a loop).
 ``BIOENGINE_SCENARIO_SCALE`` stretches every time constant for slow
 machines (2.0 = twice as slow, twice as patient).
 """
@@ -1228,9 +1228,9 @@ def _evaluate(
             "count": len(plane.routers),
             "killed": list(plane.killed_routers),
             "client_failovers": plane.router_failovers,
-            # raw (un-normalized) served count — the goodput numerator
-            # the router_scaling bench reads; best-effort capacity legs
-            # normalize seq to "absorbed" but goodput wants the truth
+            # raw (un-normalized) served count — the goodput
+            # numerator; best-effort capacity legs normalize seq to
+            # "absorbed" but goodput wants the truth
             "raw_ok": sum(1 for out in outcomes if out == "ok"),
             "staleness_max_s": (
                 round(max(plane.staleness_samples), 4)
@@ -1708,15 +1708,15 @@ CONTROLLER_CRASH = _register(
 )
 
 
-# The scale-out routing-tier capacity scenario (and the workload under
-# the router_scaling bench): hundreds of simulated mesh hosts in the
-# published table, a large local replica pool, and offered load far
-# over what ONE router's inflight cap can admit. Goodput is therefore
-# capacity-bound per router — adding routers adds admitted goodput
-# near-linearly until the offered load is fully served. The stream is
-# best-effort (strict=False): shed-at-the-router is the designed
-# behavior for the over-subscribed legs, so ok/shed normalize to
-# "absorbed" and the raw served count rides in result["routers"].
+# The scale-out routing-tier capacity scenario: hundreds of simulated
+# mesh hosts in the published table, a large local replica pool, and
+# offered load far over what ONE router's inflight cap can admit.
+# Goodput is therefore capacity-bound per router — adding routers adds
+# admitted goodput near-linearly until the offered load is fully
+# served. The stream is best-effort (strict=False): shed-at-the-router
+# is the designed behavior for the over-subscribed legs, so ok/shed
+# normalize to "absorbed" and the raw served count rides in
+# result["routers"].
 FLEET_SCALE = _register(
     Scenario(
         name="fleet_scale",

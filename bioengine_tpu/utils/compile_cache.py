@@ -5,9 +5,9 @@ dominant cold-start cost for serving replicas and the dominant wall
 cost of the benchmark (SURVEY.md: the reference's torch path has no
 analog; compiled-program caching is a TPU-specific concern). JAX ships
 a persistent cache keyed on (HLO, compiler version, device kind);
-enabling it makes every repeat compile — a replica restart, the second
-bench attempt, the NEXT round's bench on the same machine — a disk
-read instead of a compile.
+enabling it makes every repeat compile — a replica restart, the
+benchmark's second run on the same machine — a disk read instead of a
+compile.
 
 The cache directory is per-machine. At production churn (autoscale,
 preempted TPUs) a FRESH host has an empty directory and pays the full

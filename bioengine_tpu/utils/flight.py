@@ -41,8 +41,7 @@ Design constraints, in order:
 
 - **Never on the happy hot path.** No per-request event exists; the
   request path only records on failure/slow/rare-transition edges, so
-  the steady-state cost of the recorder is the ring's existence
-  (``observability_overhead`` bench, ``flight`` leg).
+  the steady-state cost of the recorder is the ring's existence.
 - **Lock-cheap.** One short ``threading.Lock`` around a deque append;
   event dicts are built outside the lock.
 - **Crash-evidence first.** ``dump(reason)`` snapshots the whole ring
@@ -95,9 +94,8 @@ _ENABLED: Optional[bool] = None
 
 
 def enabled() -> bool:
-    """``BIOENGINE_FLIGHT=0`` turns the recorder off (the bench's
-    comparison leg). Read once — record() sits on failure edges that
-    can fire in bursts."""
+    """``BIOENGINE_FLIGHT=0`` turns the recorder off. Read once —
+    record() sits on failure edges that can fire in bursts."""
     global _ENABLED
     if _ENABLED is None:
         _ENABLED = os.environ.get("BIOENGINE_FLIGHT", "1") != "0"

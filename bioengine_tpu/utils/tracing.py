@@ -145,8 +145,8 @@ def _cached_env(key: str, default: str) -> float:
 
 
 def tracing_enabled() -> bool:
-    """Global kill-switch (``BIOENGINE_TRACING=0``) — the bench's
-    baseline leg. Off means no context is minted at all."""
+    """Global kill-switch (``BIOENGINE_TRACING=0``). Off means no
+    context is minted at all."""
     return _cached_env("BIOENGINE_TRACING", "1") != 0.0
 
 

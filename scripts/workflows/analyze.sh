@@ -21,8 +21,8 @@
 #   - analyze.sarif        code-scanning annotations (SARIF 2.1.0) —
 #     exported BEFORE the job fails, so a red run still annotates
 #   - hot-path-report.json the BE-PERF-3xx overhead map (reachable
-#     functions ranked by finding count x call-graph depth) — the
-#     request_overhead bench's starting point (docs/performance.md)
+#     functions ranked by finding count x call-graph depth;
+#     docs/performance.md)
 #   - analyze-stats.json   machine-readable run stats (wall, cache
 #     hits, per-pass timings) — the CI perf-budget probe
 #   - a docs drift guard: BIOENGINE_* knobs and flight-event/metric

@@ -2,12 +2,10 @@
 # Cold-start gate: the shared compile-cache tier, streamed weight
 # loading, and warm-pool suites (tier entry protocol, persistent-hit
 # tagging, streamed-vs-eager bit parity, pool fill/promote/sweep, and
-# the preemption chaos test), then a cold_start bench smoke asserting
-# the warm-pool path beats the cold path ≥10x, then an in-process
-# multi-host DRYRUN proving a second replica start hits the compile
-# tier (first replica compiles for real; its entry rides
-# host→controller-tier→host and the second replica's compile is tagged
-# cache_hit).
+# the preemption chaos test), then an in-process multi-host DRYRUN
+# proving a second replica start hits the compile tier (first replica
+# compiles for real; its entry rides host→controller-tier→host and the
+# second replica's compile is tagged cache_hit).
 set -euo pipefail
 
 cd "$(dirname "$0")/../.."
@@ -17,30 +15,6 @@ export JAX_PLATFORMS=cpu
 echo "== cold-start test suite =="
 timeout -k 10 600 python -m pytest tests/test_cold_start.py -q -rA \
     -p no:cacheprovider
-
-echo "== cold_start bench smoke =="
-out="$(mktemp)"
-timeout -k 10 300 env BENCH_PLATFORM=cpu BENCH_DEADLINE=240 \
-    BENCH_CONFIGS=cold_start python bench.py | tail -n1 > "$out"
-python - "$out" <<'EOF'
-import json
-import sys
-
-with open(sys.argv[1]) as f:
-    d = json.loads(f.read())
-st = d["extra"]["cold_start"]
-assert st and st.get("ok"), st
-assert st["cold"]["real_compiles"] >= 1, st["cold"]
-assert st["warm_cache_hit_observed"], st["warm_cache"]
-assert st["warm_pool"]["promoted_from_warm_pool"], st["warm_pool"]
-assert st["speedup_warm_pool"] >= 10.0, st["speedup_warm_pool"]
-print(
-    f"cold_start OK: cold={st['cold']['ttfr_s']}s "
-    f"warm_cache={st['warm_cache']['ttfr_s']}s "
-    f"warm_pool={st['warm_pool']['ttfr_s']}s "
-    f"(speedups {st['speedup_warm_cache']}x / {st['speedup_warm_pool']}x)"
-)
-EOF
 
 echo "== compile-tier dryrun (second replica start hits the tier) =="
 timeout -k 10 300 python - <<'EOF'

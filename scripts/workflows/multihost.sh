@@ -2,7 +2,7 @@
 # CI job: topology-portable multi-host meshes — fails fast on cross-host
 # placement/execution regressions without waiting for the slow suite.
 #
-# Three checks on a forced 4-virtual-device CPU layout (the same trick
+# Two checks on a forced 4-virtual-device CPU layout (the same trick
 # as tests/conftest.py and the MULTICHIP dryruns):
 #   1. the full multichip dryrun (__graft_entry__.dryrun_multichip),
 #      which now ends with a cross-host mesh phase: the CrossHostEngine
@@ -11,9 +11,7 @@
 #   2. the mesh suite (tests/test_mesh.py): planner + config units,
 #      CrossHostEngine composition, 2-in-process-host serving with
 #      parity + the RpcStats OOB pin, mesh1 capability gating, and the
-#      kill-a-shard-host chaos leg with exact chip accounting;
-#   3. a bench smoke of the multihost_mesh stage (schema + parity +
-#      OOB pin; CPU throughput is informational).
+#      kill-a-shard-host chaos leg with exact chip accounting.
 #
 # Run locally from the repo root:  scripts/workflows/multihost.sh
 set -euo pipefail
@@ -27,31 +25,3 @@ python __graft_entry__.py 4
 
 echo "multihost: mesh planner/engine/serving/chaos suite"
 python -m pytest tests/test_mesh.py -q -p no:cacheprovider
-
-echo "multihost: multihost_mesh bench smoke (schema + parity + OOB pin)"
-BENCH_PLATFORM=cpu BENCH_CONFIGS=multihost_mesh BENCH_DEADLINE=170 \
-python - <<'EOF'
-import json
-import os
-import subprocess
-import sys
-
-proc = subprocess.run(
-    [sys.executable, "bench.py"], capture_output=True, text=True,
-    timeout=200, env=dict(os.environ),
-)
-assert proc.returncode == 0, proc.stderr[-2000:]
-lines = [l for l in proc.stdout.strip().splitlines() if l.startswith("{")]
-st = json.loads(lines[-1])["extra"]["multihost_mesh"]
-assert st["ok"], st
-assert st["parity_ok"], st
-assert st["cross_host_2host"] and not st["cross_host_1host"], st
-assert st["oob_payloads_out"] > 0 and st["legacy_msgs_out"] == 0, st
-print(
-    "multihost_mesh OK: "
-    f"1host={st['images_per_sec_1host']} img/s "
-    f"2host={st['images_per_sec_2host']} img/s "
-    f"efficiency={st['scaling_efficiency']} "
-    f"transfer={st['transfer_bytes_per_request']}B/req"
-)
-EOF

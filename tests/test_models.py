@@ -70,7 +70,7 @@ def test_cellpose_loss_components():
 
 
 def test_vit_bf16_softmax_matches_f32():
-    """The perf default (bf16 softmax, bench.py/embedder) must stay
+    """The perf default (bf16 softmax, the embedder's) must stay
     faithful to the f32 reference: cosine >= 0.999 per embedding."""
     import jax
     import jax.numpy as jnp

@@ -11,6 +11,7 @@ trace_id, and the Prometheus scrape surface.
 """
 
 import asyncio
+import gc
 import re
 import time
 from pathlib import Path
@@ -796,6 +797,10 @@ class TestStages:
             return [p * 2 for p in payloads]
 
         batcher = ContinuousBatcher(double, max_batch=4, max_wait_ms=20.0)
+        # the counter sums the batchers alive at the scrape (ROADMAP
+        # D16): an earlier test's batcher must not leave, and take its
+        # seconds with it, between the two reads
+        gc.collect()
         before, since = total(), time.time_ns()
         assert await asyncio.gather(
             batcher.submit("k", 1), batcher.submit("k", 2)

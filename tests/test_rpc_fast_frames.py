@@ -347,30 +347,6 @@ class TestEndToEnd:
         finally:
             await conn.disconnect()
 
-    async def test_compat_pre_fast1_uses_legacy_request_path(
-        self, echo_server
-    ):
-        # The bench's baseline leg: legacy protocols keep BEFS off the
-        # wire, and compat_pre_fast1 restores the pre-fast1 request
-        # bookkeeping (uuid call ids + wait_for timeout) so the leg
-        # measures the pre-optimization stack end to end.
-        conn = await connect_to_server(
-            {
-                "server_url": f"http://127.0.0.1:{echo_server.port}",
-                "protocols": [protocol.PROTO_OOB1, protocol.PROTO_TRACE1],
-                "compat_pre_fast1": True,
-                "shm_store": None,
-            }
-        )
-        try:
-            assert conn._compat_request is True
-            assert conn.codec.fast is False
-            assert await conn.call("bioengine/echo", "add", 5, 6) == 11
-            assert conn.codec.stats.small_frames_out == 0
-            assert conn.codec.stats.msgs_out >= 1
-        finally:
-            await conn.disconnect()
-
     async def test_unix_socket_transport(self, tmp_path):
         sock = str(tmp_path / "rpc.sock")
         srv = RpcServer(shm_store=None, uds_path=sock)

@@ -1502,8 +1502,6 @@ class TestMixedPrioritySoak:
         re-planned onto the survivor), both classes make progress
         throughout (no starvation), the scheduler coalesced
         cross-replica groups, and chip accounting survives the kill."""
-        import os
-
         server, controller, spawn_host, tmp_path = sched_plane
         h1 = await spawn_host("h1")
         h2 = await spawn_host("h2")
@@ -1513,7 +1511,7 @@ class TestMixedPrioritySoak:
             1: controller.get_handle("sched-app-1"),
             2: controller.get_handle("sched-app-2"),
         }
-        per_worker = int(os.environ.get("BIOENGINE_SCHED_SOAK_N", "10"))
+        per_worker = 10
         workers = 3  # parallel streams per (app, class): compatible
         #              requests must OVERLAP for coalescing to happen
         opts = {
