@@ -280,9 +280,10 @@ class Pipeline:
 
         Arrays arrive in the RDF's declared axes, are canonicalized to
         NHWC for the engine, and returned in the declared output axes.
-        The host work on either side of the engine call, with the
-        device empty, is the ``runtime.preprocess`` and
-        ``runtime.postprocess`` stages.
+        The host work on either side of the engine call is the
+        ``runtime.preprocess`` and ``runtime.postprocess`` stages: it
+        runs on this request's own thread, while the engine's stream
+        keeps the device on other requests' tiles.
         """
         spec = self.input_spec
         with tracing.stage("runtime.preprocess") as pre:
@@ -301,15 +302,15 @@ class Pipeline:
         return {out_spec.name: y}
 
     async def predict_async(self, inputs) -> dict[str, np.ndarray]:
-        """Async front door into the engine's overlapped pipeline: the
-        whole prediction (pre/post processing + tiled inference) runs
-        on the engine's single dispatch thread, so concurrent callers
-        never race for one device and the event loop never blocks —
+        """Async front door into the engine's tile stream: the whole
+        prediction (pre/post processing + the engine call) runs on one
+        of the engine's request threads, its tiles join the one stream
+        that talks to the device, and the event loop never blocks —
         without spawning a thread per request via asyncio.to_thread.
-        The torch fallback has no dispatch thread; it keeps to_thread."""
+        The torch fallback has no request threads; it keeps to_thread."""
         if self.backend == "xla":
             # submit() runs the task in a copy of this context, so the
-            # stages on the dispatch thread land in a sampled request's tree
+            # request's stages land in a sampled request's tree
             return await asyncio.wrap_future(
                 self.engine.submit(self.predict, inputs)
             )
@@ -549,7 +550,7 @@ class RuntimeDeployment:
 
     async def close(self) -> None:
         """Replica.stop's hook: flush the batcher and release every
-        cached pipeline's engine dispatch thread (LRU eviction only
+        cached pipeline's engine threads (LRU eviction only
         covers pipelines pushed out while running)."""
         if self._batcher is not None:
             await self._batcher.close()
@@ -607,7 +608,7 @@ class RuntimeDeployment:
             existing = self._pipelines.get(key)
             if existing is not None:
                 # lost a concurrent-build race: keep the first-stored
-                # pipeline (its engine already owns the dispatch thread
+                # pipeline (its engine already owns its threads
                 # and warm programs) and drop our duplicate
                 self._pipelines.move_to_end(key)
                 pipeline.close()
@@ -615,7 +616,7 @@ class RuntimeDeployment:
             self._pipelines[key] = pipeline
             while len(self._pipelines) > self.max_pipelines:
                 _, evicted = self._pipelines.popitem(last=False)
-                evicted.close()  # release the engine's dispatch thread
+                evicted.close()  # end the engine's threads
         return pipeline
 
     # ---- handle API (called by the entry deployment) --------------------

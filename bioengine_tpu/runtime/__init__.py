@@ -9,7 +9,7 @@ from bioengine_tpu.runtime.pipeline import (
     DispatchExecutor,
     PipelineStats,
     StagingPool,
-    run_pipeline,
+    TileStream,
 )
 from bioengine_tpu.runtime.program_cache import (
     CompiledProgramCache,
@@ -32,7 +32,7 @@ __all__ = [
     "DispatchExecutor",
     "PipelineStats",
     "StagingPool",
-    "run_pipeline",
+    "TileStream",
     "CompiledProgramCache",
     "default_program_cache",
     "StreamedWeightLoader",

@@ -9,15 +9,21 @@ device -> jitted forward -> crop back. Images larger than ``max_tile``
 run tiled with overlap and linear blend stitching (the reference's
 blockwise path, but vectorized: all tiles form one batch).
 
-Tiled prediction runs OVERLAPPED by default (runtime/pipeline.py):
-a staging thread cuts chunk k+1 while the device computes chunk k and
-a stitch thread blends chunk k-1, with a bounded in-flight window
-(``EngineConfig.pipeline_depth``) riding XLA's async dispatch, programs
+Every prediction joins the engine's one tile stream
+(runtime/pipeline.py ``TileStream``): a cut thread cuts the tiles of
+the requests in hand, in arrival order, into reusable staging buffers,
+the issuing thread — the only one that talks to the device — puts and
+dispatches chunk k and reads chunk k-depth+1 back (a bounded in-flight
+window, ``EngineConfig.pipeline_depth``, riding XLA's async dispatch),
+and a stitch thread blends. The stream does not end between requests:
+a chunk is filled across the requests in hand, so one request's padding
+rows carry the next one's tiles, and a request is billed its rows'
+share of each chunk it rode. Each request has a thread of its own for
+its host work (``submit``), so requests in hand overlap. Programs are
 compiled with ``donate_argnums`` so each chunk's input HBM buffer is
-recycled into its output, and host chunks assembled in reusable
-per-(bucket, dtype) staging buffers instead of fresh ``pad_to`` +
-``np.concatenate`` copies. ``predict_serial`` keeps the strictly
-serial path as the parity baseline; both produce bit-identical output.
+recycled into its output. ``predict_serial`` keeps the strictly serial
+path as the parity baseline: every reply of the stream is bit-identical
+to it, whoever shared its chunks.
 
 Multi-chip serving: an engine constructed with the replica's leased
 chip group (``devices=[...]`` or ``device_ids=[...]``) builds a named
@@ -36,12 +42,14 @@ from __future__ import annotations
 
 import contextvars
 import dataclasses
+import functools
 import itertools
 import os
 import re
 import threading
 import time
 import warnings
+import weakref
 from typing import Any, Callable, Mapping, Optional, Sequence
 
 import jax
@@ -59,9 +67,11 @@ from bioengine_tpu.runtime.buckets import (
 )
 from bioengine_tpu.runtime.pipeline import (
     DispatchExecutor,
+    InFlight,
     PipelineStats,
     StagingPool,
-    run_pipeline,
+    TileJob,
+    TileStream,
 )
 from bioengine_tpu.runtime.program_cache import (
     CompiledProgramCache,
@@ -110,6 +120,20 @@ def resolve_devices(
     return local[: len(device_ids)]
 
 
+def _weakly(method: Callable) -> Callable:
+    """``method`` of an object this reference does not keep alive."""
+    ref = weakref.WeakMethod(method)
+    return lambda *args: ref()(*args)
+
+
+# the device seconds billed to the prediction the current context is in:
+# a one-item list that ``predict`` sets and whatever runs its chunks
+# adds to (the issuing thread through its copy of the context)
+_bill: contextvars.ContextVar[Optional[list]] = contextvars.ContextVar(
+    "bioengine_engine_bill", default=None
+)
+
+
 def mesh_cache_tag(dp: int, tp: int = 1) -> str:
     """The ONE definition of mesh-shape identity in cache keys:
     compiled programs (InferenceEngine._mesh_key) and model-runner
@@ -140,14 +164,11 @@ class EngineConfig:
     tile_z: int = 32
     tile_overlap_z: int = 8
     ladder_z: tuple = (8, 16, 24, 32, 48, 64, 96, 128)
-    # ---- overlapped pipeline ------------------------------------------------
+    # ---- the tile stream ----------------------------------------------------
     # chunks dispatched to the device but not yet read back; each holds
     # one (tile_batch, *bucket) HBM buffer, so depth bounds device
-    # memory. 2 = double buffering. 0 disables overlap entirely (the
-    # serial path, one chunk at a time).
+    # memory. 2 = double buffering; at least 1.
     pipeline_depth: int = 2
-    # staged host chunks cut ahead of dispatch (bounds host RAM)
-    pipeline_prefetch: int = 2
     # compile with donate_argnums so each chunk's input buffer is
     # recycled into its output instead of allocating fresh HBM per
     # chunk. Donation never changes results; XLA falls back silently
@@ -277,9 +298,28 @@ class InferenceEngine:
             self._param_shardings = None
             self.params = jax.device_put(params, self.device)
         self._tp_rules = tp_rules
-        self.pipeline_stats = PipelineStats(depth=self.config.pipeline_depth)
+        cfg = self.config
+        self.pipeline_stats = PipelineStats(depth=max(int(cfg.pipeline_depth), 1))
         self._staging_pool = StagingPool()
+        # what the tiled predictions of one spatial size share (tile
+        # plan, ramp, blend slices, weight), the newest few
+        self._blend_plans: dict[tuple, tuple] = {}
         self._dispatcher = DispatchExecutor(f"dispatch-{model_id}")
+        self._stream = TileStream(
+            f"dispatch-{model_id}",
+            depth=cfg.pipeline_depth,
+            tile_batch=cfg.tile_batch,
+            chunk_rows=bucket_batch(
+                max(int(cfg.tile_batch), 1), multiple_of=self.dp
+            ),
+            stats=self.pipeline_stats,
+            pool=self._staging_pool,
+            dispatch=_weakly(self._dispatch_chunk),
+            force=_weakly(self._force_chunk),
+        )
+        # the stream's threads hold the stream, the stream holds the
+        # engine weakly: an engine dropped without close() still ends them
+        weakref.finalize(self, self._stream.close)
         # streamed weight loading (runtime/weight_stream.py): an engine
         # built over a manifest SKELETON compiles and warms immediately
         # while the real bytes land; prediction gates on this event so
@@ -419,6 +459,9 @@ class InferenceEngine:
             "mesh": self.mesh_shape,
             "per_chip": per_chip,
             "params_resident": self.params_resident,
+            # the stream's accounting: ``chunks_shared`` beside ``chunks``
+            # says how often requests shared a chunk
+            "pipeline": self.pipeline_stats.as_dict(),
             "programs": {
                 "live": len(mine),
                 "compile_seconds": {
@@ -444,21 +487,24 @@ class InferenceEngine:
         }
 
     def close(self) -> None:
-        """Release the async dispatch thread (idempotent)."""
+        """End the request threads and the stream's (idempotent);
+        predictions not yet answered fail with a retryable error."""
         self._dispatcher.close()
+        self._stream.close()
 
     def submit(self, fn: Callable, *args: Any, **kwargs: Any):
-        """Run ``fn`` on the engine's dispatch thread; returns a
+        """Run ``fn`` on one of the engine's request threads; returns a
         ``concurrent.futures.Future``. The building block behind
         ``predict_async`` for callers that wrap extra host work
-        (pre/post processing) around the engine — one thread serializes
-        device access instead of a fresh ``to_thread`` per request.
+        (pre/post processing) around the engine: that work runs on the
+        request's own thread, beside the device, and every ``predict``
+        inside joins the one tile stream that talks to it.
 
         The task runs in a copy of the submitter's context (a sampled
         trace and the chip-seconds accumulator cross with it). The wait
-        for the dispatch thread is the ``engine.queue`` stage, the task
+        for a request thread is the ``engine.queue`` stage, the task
         whole the ``engine.request`` stage: while one is open the engine
-        has a request in hand."""
+        has that request in hand. Requests in hand overlap."""
         submitted = time.time_ns()
         stats = self.pipeline_stats
 
@@ -571,25 +617,28 @@ class InferenceEngine:
         model output, cropped back to the original spatial size. Inputs
         larger than the per-axis ``max_tile`` run overlap-tiled with
         linear blend stitching (the reference's blockwise path, ref
-        apps/model-runner/runtime_deployment.py:277-280) through the
-        overlapped pipeline; ``pipeline_depth=0`` falls back to the
-        serial path.
+        apps/model-runner/runtime_deployment.py:277-280), their tiles in
+        the stream's chunks beside those of whatever else the engine
+        has in hand; the others run whole on the stream's issuing
+        thread, in their turn. The caller's thread builds the programs
+        its tiles may run at (a compile never holds up other requests'
+        chunks) and otherwise waits.
 
         The whole prediction is the ``engine.predict`` stage. Under a
         sampled request trace its span's attrs carry ``stage_seconds``,
-        the sums of this request's own stages (cut / h2d put / dispatch
-        / device_wait / d2h / stitch, ``readback`` = device_wait + d2h,
-        and the ``compute`` estimate) — the device-side half of the
-        request's latency breakdown — plus the prediction's
-        ``chip_seconds`` (wall seconds x mesh width). Chip-seconds ALSO
-        feed the request-scoped accounting accumulator
-        (utils/tracing.py) on every call, sampled or not: cost is
-        exact, only spans are sampled."""
+        the sums of the stages of the chunks this request rode (cut /
+        h2d put / dispatch / device_wait / d2h / stitch, ``readback`` =
+        device_wait + d2h) and ``compute``, its rows' share of those
+        chunks' device time — the device-side half of the request's
+        latency breakdown — plus the prediction's ``chip_seconds``
+        (``compute`` x mesh width). Chip-seconds ALSO feed the
+        request-scoped accounting accumulator (utils/tracing.py) on
+        every call, sampled or not: cost is exact, only spans are
+        sampled. Summed over the requests served they are the device's
+        busy time x mesh width, however the requests shared chunks."""
         width = len(self.devices)
-        stats = self.pipeline_stats
-        # the dispatch thread runs one prediction at a time, so the
-        # engine-wide estimate moves by this request's alone
-        compute_before = stats.compute_seconds
+        billed = [0.0]
+        token = _bill.set(billed)
         whole = tracing.stage(
             "engine.predict",
             model=self.model_id,
@@ -602,19 +651,21 @@ class InferenceEngine:
                 out = self._predict_impl(images)
                 if whole.span is not None:
                     whole.attrs["stage_seconds"] = self._stage_seconds(
-                        whole.span, stats.compute_seconds - compute_before
+                        whole.span, billed[0]
                     )
             return out
         finally:
-            chip_seconds = whole.seconds * width
+            _bill.reset(token)
+            chip_seconds = billed[0] * width
             if whole.span is not None:
                 whole.attrs["chip_seconds"] = round(chip_seconds, 6)
             tracing.add_chip_seconds(chip_seconds)
 
     @staticmethod
     def _stage_seconds(span: dict, compute: float) -> dict:
-        """``engine.predict``'s ``stage_seconds``: this request's own
-        ``engine.*`` stages summed by name, without the prefix."""
+        """``engine.predict``'s ``stage_seconds``: the ``engine.*``
+        stages under this request's span summed by name, without the
+        prefix."""
         own = tracing.child_stage_seconds(span)
         sums = {
             name: round(own.get(f"engine.{name}", 0.0), 6)
@@ -629,39 +680,54 @@ class InferenceEngine:
     def _predict_impl(self, images: np.ndarray) -> np.ndarray:
         images = self._validate(images)
         specs = self._axis_specs(images.ndim)
+        self.pipeline_stats.add(items=len(images))
         if self._needs_tiling(images, specs):
-            if self.config.pipeline_depth > 0:
-                return self._predict_tiled_pipelined(images, specs)
-            return self._predict_tiled_serial(images, specs)
-        self.pipeline_stats.add(items=len(images))
-        return self._predict_direct(images, specs)
+            job = _TiledJob(self, images, specs)
+            # whatever this job's chunks run at is built here, on the
+            # request's own thread: a compile would hold up every other
+            # request's chunks on the issuing thread
+            for rows in job.sizes:
+                self._program((rows, *job.row_shape), job.dtype)
+            self._stream.enrol(job).result()
+            self._bill(job.compute_seconds)
+            with tracing.stage("engine.stitch") as divide:
+                out = job.acc / job.weight
+            self.pipeline_stats.add(stitch_seconds=divide.seconds)
+            return out
+        # only the stream's issuing thread talks to the device
+        return self._stream.run(
+            functools.partial(self._predict_direct, images, specs)
+        ).result()
 
-    def _predict_tiled_serial(
-        self, images: np.ndarray, specs: list["_AxisSpec"]
-    ) -> np.ndarray:
-        self.pipeline_stats.add(items=len(images))
-        return np.stack([self._predict_tiled(item, specs) for item in images])
+    @staticmethod
+    def _bill(compute_seconds: float) -> None:
+        bill = _bill.get()
+        if bill is not None:
+            bill[0] += compute_seconds
 
     def predict_serial(self, images: np.ndarray) -> np.ndarray:
-        """The strictly serial pre-pipeline path: one chunk cut, put,
-        computed, read back, and stitched at a time, one batch item
-        after another. Kept as the numeric parity baseline for the
-        pipelined path."""
+        """The strictly serial path, on the caller's thread: one chunk
+        cut, put, computed, read back, and stitched at a time, one batch
+        item after another. Kept as the numeric parity baseline for the
+        stream."""
         images = self._validate(images)
         specs = self._axis_specs(images.ndim)
-        if self._needs_tiling(images, specs):
-            return self._predict_tiled_serial(images, specs)
         self.pipeline_stats.add(items=len(images))
+        if self._needs_tiling(images, specs):
+            return np.stack(
+                [self._predict_tiled(item, specs) for item in images]
+            )
         return self._predict_direct(images, specs)
 
     async def predict_async(self, images: np.ndarray) -> np.ndarray:
-        """Async front door: run ``predict`` on the engine's dedicated
-        dispatch thread and await the result. Replicas and the
-        continuous batcher drain into the pipeline through here without
-        wrapping whole predictions in ``asyncio.to_thread`` (no per-call
-        thread, no unbounded concurrent callers racing for one
-        device — the single dispatch thread serializes device access
-        while the pipeline's own staging/stitch threads overlap it)."""
+        """Async front door: run ``predict`` on one of the engine's
+        request threads and await the result. Replicas and the
+        continuous batcher drain into the stream through here without
+        wrapping whole predictions in ``asyncio.to_thread`` (no thread
+        spawned per call, no callers racing for one device: the
+        stream's one issuing thread serializes device access while the
+        requests' own threads and its cut and stitch threads overlap
+        it)."""
         import asyncio
 
         # submit() runs the task in a copy of this context: a sampled
@@ -671,8 +737,8 @@ class InferenceEngine:
     def _predict_direct(self, x: np.ndarray, specs: list["_AxisSpec"]) -> np.ndarray:
         """Bucket every spatial axis, pad into a reusable staging
         buffer, run the compiled program, crop back. One chunk of the
-        same six stages as the pipelined path, one after the other
-        (``cut`` is the fill, ``stitch`` the crop), into the same
+        same six stages as the stream's, one after the other (``cut``
+        is the fill, ``stitch`` the crop), into the same
         ``PipelineStats`` fields."""
         B = x.shape[0]
         C = x.shape[-1]
@@ -702,20 +768,22 @@ class InferenceEngine:
                     f"padded to bucket {buckets} — padding corrupts global "
                     f"outputs. Resize inputs to a bucket size."
                 )
+        # serial: the device has the chunk from the end of the dispatch
+        # to the end of the wait for it
+        compute = (flight.ready_ns - flight.dispatched_ns) / 1e9
         self.pipeline_stats.add(
             chunks=1,
             cut_seconds=cut.seconds,
             stitch_seconds=crop.seconds,
-            # serial: the device has the chunk from the end of the
-            # dispatch to the end of the wait for it
-            compute_seconds=(flight.ready_ns - flight.dispatched_ns) / 1e9,
+            compute_seconds=compute,
             wall_seconds=(crop.end_ns - cut.start_ns) / 1e9,
         )
+        self._bill(compute)
         return out
 
     # ---- one chunk on the device (every path's put/dispatch/wait/d2h) -------
 
-    def _dispatch_chunk(self, buf: np.ndarray, n: int) -> "_InFlight":
+    def _dispatch_chunk(self, buf: np.ndarray, n: int) -> InFlight:
         """Hand one staged chunk (``n`` useful rows) to the device
         WITHOUT blocking: ``engine.put`` is the ``device_put`` call (H2D
         enqueue and linearize), ``engine.dispatch`` the program lookup,
@@ -739,9 +807,9 @@ class InferenceEngine:
             rows_executed=buf.shape[0],
             rows_useful=n,
         )
-        return _InFlight(out, n, dispatch.end_ns)
+        return InFlight(out, n, dispatch.end_ns, stages=[put, dispatch])
 
-    def _force_chunk(self, flight: "_InFlight") -> np.ndarray:
+    def _force_chunk(self, flight: InFlight) -> np.ndarray:
         """Block until a dispatched chunk is on the host:
         ``engine.device_wait`` is the wait for the device,
         ``engine.d2h`` the copy of the ready result. ``readback_seconds``
@@ -753,6 +821,7 @@ class InferenceEngine:
         with tracing.stage("engine.d2h", bytes=out.nbytes) as d2h:
             host = np.asarray(out)
         flight.ready_ns = wait.end_ns
+        flight.stages += (wait, d2h)
         self.pipeline_stats.add(
             device_wait_seconds=wait.seconds,
             d2h_seconds=d2h.seconds,
@@ -761,7 +830,7 @@ class InferenceEngine:
         )
         return host
 
-    # ---- tiling geometry (shared by the serial and pipelined paths) ---------
+    # ---- tiling geometry (shared by the serial path and the stream) ----------
 
     def _tile_plan(
         self, spatial: tuple[int, ...], specs: list["_AxisSpec"]
@@ -833,127 +902,99 @@ class InferenceEngine:
             )
         return acc / np.maximum(weight, 1e-8)
 
-    def _predict_tiled_pipelined(
-        self, images: np.ndarray, specs: list["_AxisSpec"]
-    ) -> np.ndarray:
-        """All batch items' tiles stream through one overlapped
-        pipeline: the staging thread assembles chunk k+1 in a reusable
-        staging buffer while the device computes chunk k (async
-        dispatch, at most ``pipeline_depth`` in flight) and the stitch
-        thread ramp-blends chunk k-1 into the accumulator. Chunk
-        composition is identical to the serial path (per item, tiles in
-        coordinate order, ``tile_batch`` per chunk), so the result is
-        bit-identical to ``predict_serial``."""
-        cfg = self.config
-        B = images.shape[0]
-        C = images.shape[-1]
-        spatial = images.shape[1:-1]
-        plan = self._tile_plan(spatial, specs)
-        tsizes, overlaps, coords, buckets = (
-            plan.tsizes, plan.overlaps, plan.coords, plan.buckets,
+    def _blend_plan(self, spatial: tuple[int, ...], specs: list["_AxisSpec"]):
+        """What every tiled prediction of one spatial size shares: the
+        tile plan, the ramp, each tile's (dst, src) slices and the blend
+        weight, accumulated in tile order as the serial path does so the
+        results stay bit-identical. Kept for the newest few sizes (two
+        request threads may build the same one; either will do)."""
+        cached = self._blend_plans.get(spatial)
+        if cached is None:
+            plan = self._tile_plan(spatial, specs)
+            ramp = _ramp_nd(plan.tsizes, plan.overlaps)
+            dst_src = []
+            weight = np.zeros((*spatial, 1), np.float32)
+            for start in plan.coords:
+                dst = tuple(
+                    slice(s0, min(s0 + t, size))
+                    for s0, t, size in zip(start, plan.tsizes, spatial)
+                )
+                src = tuple(slice(0, s.stop - s.start) for s in dst)
+                dst_src.append((dst, src))
+                weight[dst] += ramp[src]
+            cached = (plan, ramp, dst_src, np.maximum(weight, 1e-8))
+            plans = dict(self._blend_plans)
+            while len(plans) >= 8:
+                del plans[next(iter(plans))]
+            plans[spatial] = cached
+            self._blend_plans = plans
+        return cached
+
+
+class _TiledJob(TileJob):
+    """One tiled prediction as the tile stream sees it: all batch items'
+    tiles, item after item in coordinate order, which is the serial
+    path's order, so whatever chunks the rows ride the result
+    (``acc / weight``) is bit-identical to ``predict_serial``."""
+
+    def __init__(
+        self, engine: InferenceEngine, images: np.ndarray,
+        specs: list["_AxisSpec"],
+    ):
+        super().__init__()
+        self.model_id = engine.model_id
+        self.images = images
+        self.spatial = spatial = images.shape[1:-1]
+        self.plan, self.ramp, self.dst_src, self.weight = engine._blend_plan(
+            spatial, specs
         )
-        chunk = max(int(cfg.tile_batch), 1)
-        ramp = _ramp_nd(tsizes, overlaps)
-
-        # dst/src slices and the blend weight are identical for every
-        # item; computing the weight once (in tile order, matching the
-        # serial accumulation order) keeps results bit-identical
-        dst_src = []
-        weight = np.zeros((*spatial, 1), np.float32)
-        for start in coords:
-            dst = tuple(
-                slice(s0, min(s0 + t, size))
-                for s0, t, size in zip(start, tsizes, spatial)
-            )
-            src = tuple(slice(0, s.stop - s.start) for s in dst)
-            dst_src.append((dst, src))
-            weight[dst] += ramp[src]
-
-        # one desc per (item, tile-chunk) — items feed the same stream,
-        # so the device never drains between batch items
-        descs = [
-            (b, i0, min(i0 + chunk, len(coords)))
-            for b in range(B)
-            for i0 in range(0, len(coords), chunk)
-        ]
-        pool = self._staging_pool
-        stats = self.pipeline_stats
-        state: dict[str, Any] = {"acc": None}
-
-        def fill(desc):
-            b, i0, i1 = desc
-            n = i1 - i0
-            item = images[b]
-            buf = pool.acquire(
-                (bucket_batch(n, multiple_of=self.dp), *buckets, C),
-                images.dtype,
-            )
-            tile_region = tuple(slice(0, t) for t in tsizes)
-            for j, start in enumerate(coords[i0:i1]):
-                sl = tuple(
-                    slice(s0, s0 + t) for s0, t in zip(start, tsizes)
-                )
-                buf[(j, *tile_region)] = item[sl]
-                # reused buffers hold stale data: zero the pad margin
-                # between the tile extent and the bucket extent (a
-                # no-op when the tile sits exactly on the ladder)
-                for ax, (t, bkt) in enumerate(zip(tsizes, buckets)):
-                    if bkt > t:
-                        idx = [j, *([slice(None)] * (len(buckets) + 1))]
-                        idx[1 + ax] = slice(t, bkt)
-                        buf[tuple(idx)] = 0
-            buf[n:] = 0  # stale rows from a previous, fuller chunk
-            return buf, n
-
-        def dispatch(desc, staged):
-            buf, n = staged
-            return self._dispatch_chunk(buf, n), buf
-
-        def force(handle):
-            flight, buf = handle
-            host = self._force_chunk(flight)
-            pool.release(buf)
-            return host[: flight.n]
-
-        def stitch(desc, host):
-            b, i0, i1 = desc
-            if host.ndim != len(spatial) + 2:
-                raise ValueError(
-                    f"tiled prediction requires dense spatial outputs, "
-                    f"model '{self.model_id}' returned {host.shape}"
-                )
-            if state["acc"] is None:
-                state["acc"] = np.zeros(
-                    (B, *spatial, host.shape[-1]), np.float32
-                )
-            acc_b = state["acc"][b]
-            for tile_out, (dst, src) in zip(host, dst_src[i0:i1]):
-                acc_b[dst] += tile_out[src] * ramp[src]
-
-        run_pipeline(
-            descs,
-            fill=fill,
-            dispatch=dispatch,
-            force=force,
-            stitch=stitch,
-            depth=cfg.pipeline_depth,
-            prefetch=cfg.pipeline_prefetch,
-            stats=stats,
+        per_item = len(self.plan.coords)
+        self.tiles = len(images) * per_item
+        self.row_shape = (*self.plan.buckets, images.shape[-1])
+        self.dtype = images.dtype
+        self.key = (self.row_shape, images.dtype.str)
+        # alone, an item's tiles run in chunks of ``tile_batch`` and one
+        # tail, each padded up the batch ladder: the row counts the
+        # stream may run this job's rows at
+        chunk = max(int(engine.config.tile_batch), 1)
+        alone = ({chunk} if per_item >= chunk else set()) | {per_item % chunk}
+        self.sizes = frozenset(
+            bucket_batch(n, multiple_of=engine.dp) for n in alone - {0}
         )
-        stats.add(items=B)
-        return state["acc"] / np.maximum(weight, 1e-8)
+        self.acc: Optional[np.ndarray] = None
 
+    def cut(self, first: int, rows: np.ndarray) -> None:
+        tsizes, buckets, coords = (
+            self.plan.tsizes, self.plan.buckets, self.plan.coords,
+        )
+        tile_region = tuple(slice(0, t) for t in tsizes)
+        for j, row in enumerate(rows):
+            b, i = divmod(first + j, len(coords))
+            sl = tuple(slice(s0, s0 + t) for s0, t in zip(coords[i], tsizes))
+            row[tile_region] = self.images[b][sl]
+            # reused buffers hold stale data: zero the pad margin
+            # between the tile extent and the bucket extent (a no-op
+            # when the tile sits exactly on the ladder)
+            for ax, (t, bkt) in enumerate(zip(tsizes, buckets)):
+                if bkt > t:
+                    idx = [slice(None)] * (len(buckets) + 1)
+                    idx[ax] = slice(t, bkt)
+                    row[tuple(idx)] = 0
 
-@dataclasses.dataclass
-class _InFlight:
-    """One chunk handed to the device: its output (a future until
-    forced), its useful rows, and when the dispatch ended and the wait
-    for it ended (``time.time_ns()``)."""
-
-    out: jax.Array
-    n: int
-    dispatched_ns: int
-    ready_ns: int = 0
+    def blend(self, first: int, rows: np.ndarray) -> None:
+        if rows.ndim != len(self.spatial) + 2:
+            raise ValueError(
+                f"tiled prediction requires dense spatial outputs, "
+                f"model '{self.model_id}' returned {rows.shape}"
+            )
+        if self.acc is None:
+            self.acc = np.zeros(
+                (len(self.images), *self.spatial, rows.shape[-1]), np.float32
+            )
+        for j, tile_out in enumerate(rows):
+            b, i = divmod(first + j, len(self.dst_src))
+            dst, src = self.dst_src[i]
+            self.acc[b][dst] += tile_out[src] * self.ramp[src]
 
 
 @dataclasses.dataclass(frozen=True)

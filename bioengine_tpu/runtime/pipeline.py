@@ -1,47 +1,62 @@
-"""Overlapped inference pipeline — bounded-depth async dispatch.
+"""The engine's tile stream — one long-lived overlapped pipeline.
 
-The engine's original tiled hot path was strictly serial: cut tiles on
-the host, block on ``jax.device_put``, compute, force a ``np.asarray``
-readback, stitch, repeat — the device idled through every host phase
-and the host idled through every device phase. XLA dispatch is
-asynchronous (a jitted call returns a future-like Array immediately),
-so the fix is structural, not a kernel change:
+A tiled prediction is many tiles through one compiled program, a chunk
+of ``tile_batch`` rows at a time. XLA dispatch is asynchronous (a jitted
+call returns a future-like Array immediately), so host and device work
+overlap when three roles run side by side:
 
-    staging thread   cut/pad chunk k+1 into a reusable staging buffer
-    caller thread    device_put + dispatch chunk k (returns instantly),
-                     force the readback of chunk k-depth+1
-    stitch thread    ramp-blend chunk k-depth into the accumulator
+    cut thread       cuts the tiles of the requests in hand, in arrival
+                     order, into reusable staging buffers, up to
+                     ``PREFETCH`` chunks ahead
+    issuing thread   device_put + dispatch of chunk k (returns at once),
+                     readback of chunk k-depth+1; the ONLY thread that
+                     issues device work, and it does nothing else
+    stitch thread    ramp-blends chunk k-depth into its requests'
+                     accumulators
 
-``run_pipeline`` orchestrates those three roles around any
-(fill, dispatch, force, stitch) stage functions, keeps at most
-``depth`` chunks in flight on the device (bounding HBM), at most
-``prefetch`` staged chunks on the host (bounding RAM), and accounts
-every stage in a ``PipelineStats``. Each stage is measured once, by
-``tracing.stage`` (utils/tracing.py): the interval the sums receive is
-the one the process-wide stage timeline, a sampled request's span tree
-and a profiler trace show.
+``TileStream`` owns those threads for as long as its engine lives, and
+every prediction joins it: **the stream does not end between requests**.
+A request has a thread of its own (``DispatchExecutor``) for its host
+work: pre-processing, the tile plan, then it enrols its tiles and waits
+for its last row to be blended, divides, post-processes and answers,
+while the device already runs the next chunk. A chunk is filled across
+the requests in hand (the head request's tiles first, then the next
+one's of the same chunk shape), so the padding rows of one request
+carry another's tiles. At most ``depth`` chunks are in flight (the HBM
+bound). An open, partly filled chunk is closed late: it keeps taking
+rows of newly enrolled requests until the issuing thread has a free
+place in its window and no full chunk to put there; then it goes,
+padded, exactly as a lone request's tail always did. No timer holds a
+place for a request that has not arrived.
+
+Every stage is measured once, by ``tracing.stage`` (utils/tracing.py):
+the interval the ``PipelineStats`` sums receive is the one the
+process-wide stage timeline, a sampled request's span tree and a
+profiler trace show.
 
 ``StagingPool`` recycles the host-side staging buffers per
-(shape, dtype) so steady-state tiled inference stops paying a fresh
-``pad_to`` + ``np.concatenate`` allocation per chunk, and
-``DispatchExecutor`` is the async front door: one long-lived dispatch
-thread per engine that coroutines await through ``asyncio.wrap_future``
-instead of spawning a thread per prediction via ``asyncio.to_thread``.
+(shape, dtype) so steady-state tiled inference pays no fresh
+``pad_to`` + ``np.concatenate`` allocation per chunk.
 """
 
 from __future__ import annotations
 
 import contextvars
-import queue
+import dataclasses
+import logging
 import threading
 import time
 from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
-from typing import Any, Callable, Iterable, Optional
+from typing import Any, Callable, Optional
 
 import numpy as np
 
 from bioengine_tpu.utils import metrics, tracing
+
+# host chunks cut ahead of the issuing thread, the open one included:
+# with the in-flight window that is every buffer a stream holds
+PREFETCH = 2
 
 
 def _collect_pipelines(instances: list) -> list:
@@ -59,7 +74,7 @@ def _collect_pipelines(instances: list) -> list:
             f"pipeline_{name}",
             round(value, 4),
             kind="counter",
-            help=f"overlapped-pipeline cumulative {name.replace('_', ' ')}",
+            help=f"engine pipeline cumulative {name.replace('_', ' ')}",
         )
         for name, value in totals.items()
     ]
@@ -84,15 +99,17 @@ class PipelineStats:
     ``preprocess_seconds`` by ``runtime.preprocess``, ...);
     ``readback_seconds`` is ``device_wait_seconds`` + ``d2h_seconds``
     (mostly the wait for the device, not host work). The direct and
-    serial paths feed the same fields as the pipelined one, one chunk
-    per program call; ``runs`` counts ``run_pipeline`` runs alone.
-    ``rows_executed`` are the batch rows of every program call, padding
-    rows included; ``rows_useful`` the tiles or items asked for.
+    serial paths feed the same fields as the stream, one chunk per
+    program call. ``wall_seconds`` is, for the stream, the time it had a
+    request in hand. ``rows_executed`` are the batch rows of every
+    program call, padding rows included; ``rows_useful`` the tiles or
+    items asked for; ``chunks_shared`` the stream's chunks that held
+    rows of more than one request.
     """
 
     _FIELDS = (
-        "runs",
         "chunks",
+        "chunks_shared",
         "items",
         "requests",
         "queue_seconds",
@@ -157,8 +174,8 @@ class StagingPool:
     ``acquire`` hands back a previously released buffer when one is
     available (its contents are STALE — the caller overwrites the rows
     it uses and zeroes the rest) and allocates otherwise. The pool
-    never holds more buffers than the pipeline had concurrently
-    outstanding, so memory stays bounded by depth + prefetch."""
+    never holds more buffers than the stream had concurrently
+    outstanding, so memory stays bounded by depth + ``PREFETCH``."""
 
     def __init__(self):
         self._free: dict[tuple, list[np.ndarray]] = {}
@@ -180,12 +197,19 @@ class StagingPool:
             self._free.setdefault(key, []).append(buf)
 
 
+# requests an engine has in hand at once (each has a thread of its own
+# while it is); what arrives beyond that waits in ``engine.queue``
+REQUEST_THREADS = 16
+
+
 class DispatchExecutor:
-    """One long-lived dispatch thread per engine — the async front
-    door. Coroutines submit whole predictions here and await the
-    future; the event loop never blocks and no per-call thread is
-    spawned (``asyncio.to_thread`` churns a pool slot per request and
-    gives every caller its own thread racing for the same device)."""
+    """The async front door: long-lived request threads per engine.
+    Coroutines submit whole predictions here and await the future; the
+    event loop never blocks and no thread is spawned per call. A
+    request's thread does its host work (pre- and post-processing, the
+    tile plan, the final divide) and otherwise waits for the engine's
+    tile stream, which alone talks to the device: requests in hand
+    overlap."""
 
     def __init__(self, name: str = "engine-dispatch"):
         self._name = name
@@ -197,18 +221,19 @@ class DispatchExecutor:
         with self._lock:
             if self._closed:
                 # terminal: a submit after close must not resurrect the
-                # executor (the new thread would leak — nothing closes
+                # executor (the new threads would leak — nothing closes
                 # this dispatcher twice). Callers racing an eviction get
                 # a clear, retryable error instead.
                 raise RuntimeError(f"dispatcher '{self._name}' is closed")
             if self._pool is None:
                 self._pool = ThreadPoolExecutor(
-                    max_workers=1, thread_name_prefix=self._name
+                    max_workers=REQUEST_THREADS, thread_name_prefix=self._name
                 )
             return self._pool.submit(fn, *args, **kwargs)
 
     def close(self) -> None:
-        """Terminal and idempotent; already-submitted work still runs."""
+        """Terminal and idempotent; already-submitted work still runs
+        (and fails where the stream it waits for has closed)."""
         with self._lock:
             self._closed = True
             pool, self._pool = self._pool, None
@@ -216,142 +241,462 @@ class DispatchExecutor:
             pool.shutdown(wait=False)
 
 
-_DONE = object()
+@dataclasses.dataclass
+class InFlight:
+    """One chunk handed to the device: its output (a future until
+    forced), its useful rows, when the dispatch ended and the wait for
+    it ended (``time.time_ns()``), and the stages it passed on the
+    issuing thread (put, dispatch, device_wait, d2h)."""
+
+    out: Any
+    n: int
+    dispatched_ns: int
+    ready_ns: int = 0
+    stages: list = dataclasses.field(default_factory=list)
 
 
-def run_pipeline(
-    descs: Iterable[Any],
-    *,
-    fill: Callable[[Any], Any],
-    dispatch: Callable[[Any, Any], Any],
-    force: Callable[[Any], Any],
-    stitch: Callable[[Any, Any], None],
-    depth: int,
-    stats: PipelineStats,
-    prefetch: Optional[int] = None,
-) -> None:
-    """Stream ``descs`` through fill -> dispatch -> force -> stitch.
+class TileJob:
+    """One tiled prediction in the stream's hands, created on the
+    request's own thread (inside its ``engine.predict`` stage: the
+    stream's threads record this job's stages in copies of that
+    context, so they chain under it). The engine's subclass gives
 
-    - ``fill(desc)`` (staging thread): host prep, returns the staged
-      payload.
-    - ``dispatch(desc, staged)`` (caller thread): hand the chunk to the
-      device, return a future-like handle WITHOUT blocking.
-    - ``force(handle)`` (caller thread): block until the device result
-      is on the host, return it (and account its own wait and copy).
-    - ``stitch(desc, host)`` (stitch thread): fold the result into the
-      caller's accumulator.
+    - ``key``: tiles of equal key (bucket, channels, dtype) share chunks;
+    - ``row_shape``, ``dtype``: one row of a chunk;
+    - ``tiles``: how many rows the job has;
+    - ``sizes``: the row counts the prediction would run alone;
+    - ``cut(first, rows)``: write tiles ``first...`` into the rows given;
+    - ``blend(first, rows)``: fold their outputs into the accumulator,
+      called in tile order.
 
-    At most ``depth`` dispatched-but-unforced chunks exist at any time
-    (the HBM bound) and at most ``prefetch`` staged chunks wait on the
-    host. Exceptions from any stage abort the pipeline and re-raise in
-    the caller. Returns when every desc has been stitched."""
-    depth = max(int(depth), 1)
-    prefetch = depth if prefetch is None else max(int(prefetch), 1)
-    cut_q: queue.Queue = queue.Queue(maxsize=prefetch)
-    stitch_q: queue.Queue = queue.Queue(maxsize=depth + 1)
-    stop = threading.Event()
-    errors: list[BaseException] = []
+    ``future`` resolves (to None) when the last row is blended;
+    ``compute_seconds`` is then the job's share of the device time of
+    every chunk it rode: a chunk's ``compute_seconds`` x its rows / the
+    chunk's useful rows, so padding is split the same way and the shares
+    of all jobs sum to the device's busy time."""
 
-    def _put(q: queue.Queue, item: Any) -> bool:
-        while not stop.is_set():
-            try:
-                q.put(item, timeout=0.05)
-                return True
-            except queue.Full:
-                continue
-        return False
+    key: tuple
+    row_shape: tuple
+    dtype: Any
+    tiles: int
+    sizes: frozenset
 
-    def cut_worker() -> None:
+    def __init__(self):
+        self.future: Future = Future()
+        self.next_tile = 0
+        self.blended = 0
+        self.compute_seconds = 0.0
+        self.cut_context = contextvars.copy_context()
+        self.issue_context = contextvars.copy_context()
+        self.stitch_context = contextvars.copy_context()
+        self.trace, self.parent_span = tracing.current_trace_and_span()
+
+    def cut(self, first: int, rows: np.ndarray) -> None:
+        raise NotImplementedError
+
+    def blend(self, first: int, rows: np.ndarray) -> None:
+        raise NotImplementedError
+
+
+class _Chunk:
+    """Rows of one or more jobs on their way through the device:
+    ``segments`` are (job, first tile, rows) in row order, ``sizes`` the
+    row counts its jobs would run alone."""
+
+    __slots__ = ("key", "buf", "rows", "segments", "sizes", "flight")
+
+    def __init__(self, key: tuple, buf: np.ndarray):
+        self.key = key
+        self.buf = buf
+        self.rows = 0
+        self.segments: list[tuple[TileJob, int, int]] = []
+        self.sizes: set[int] = set()
+        self.flight: Optional[InFlight] = None
+
+
+class _Direct:
+    """A prediction that needs no tiling, waiting for its turn on the
+    issuing thread."""
+
+    __slots__ = ("run", "context", "future")
+
+    def __init__(self, run: Callable[[], Any]):
+        self.run = run
+        self.context = contextvars.copy_context()
+        self.future: Future = Future()
+
+
+class TileStream:
+    """The engine's one tile stream and its three threads (module
+    docstring). ``dispatch(buf, n)`` hands a staged chunk with ``n``
+    useful rows to the device without blocking and returns its
+    ``InFlight``; ``force(flight)`` blocks until its rows are on the
+    host and returns them all.
+
+    **Row counts.** A chunk holds at most ``tile_batch`` useful rows and
+    runs at the smallest row count that one of its jobs would have run
+    alone and that holds its rows. So the stream compiles no program
+    that lone requests would not, and a lone request on an empty engine
+    runs exactly the chunks it always did.
+    """
+
+    def __init__(
+        self,
+        name: str,
+        *,
+        depth: int,
+        tile_batch: int,
+        chunk_rows: int,
+        stats: PipelineStats,
+        pool: StagingPool,
+        dispatch: Callable[[np.ndarray, int], InFlight],
+        force: Callable[[InFlight], np.ndarray],
+    ):
+        self._name = name
+        self._depth = max(int(depth), 1)
+        self._tile_batch = max(int(tile_batch), 1)
+        self._chunk_rows = chunk_rows  # rows of a staging buffer
+        self._stats = stats
+        self._pool = pool
+        self._dispatch = dispatch
+        self._force = force
+        # one condition guards everything below; every change notifies
+        self._cond = threading.Condition()
+        self._closed = False
+        self._threads: list[threading.Thread] = []
+        self._pending: set[TileJob] = set()       # enrolled, not yet blended
+        self._cutting: deque[TileJob] = deque()   # tiles left to cut
+        self._open: Optional[_Chunk] = None       # the partly filled chunk
+        # tiles in hand may still join the open chunk: a job has just
+        # enrolled, or the cut thread is at work
+        self._cutter_busy = False
+        self._staged: deque = deque()  # closed chunks and direct runs, in order
+        self._blends: deque = deque()  # read-back chunks for the stitch thread
+        self._busy_since_ns = 0     # when the stream last got a job in hand
+        self._last_ready_ns = 0
+
+    # ---- the requests' side -------------------------------------------------
+
+    def enrol(self, job: TileJob) -> Future:
+        """Join the stream: the job's tiles are cut in arrival order,
+        into the open chunk first. Returns ``job.future``."""
+        with self._cond:
+            self._ensure_running()
+            if not self._pending:
+                self._busy_since_ns = time.time_ns()
+            self._pending.add(job)
+            self._cutting.append(job)
+            # the open chunk waits for these tiles, not only for the cut
+            # thread to wake up and see them
+            self._cutter_busy = True
+            self._cond.notify_all()
+        return job.future
+
+    def run(self, fn: Callable[[], Any]) -> Future:
+        """Run ``fn`` (a prediction that needs no tiling) on the issuing
+        thread, in its turn, in a copy of the caller's context, with
+        nothing else in flight: as such predictions always ran."""
+        direct = _Direct(fn)
+        with self._cond:
+            self._ensure_running()
+            if self._open is not None and not self._cutter_busy:
+                # in arrival order behind the rows that wait (a chunk
+                # the cut thread is filling right now it overtakes)
+                self._close_chunk()
+            self._staged.append(direct)
+            self._cond.notify_all()
+        return direct.future
+
+    def _ensure_running(self) -> None:
+        """(Lock held.)"""
+        if self._closed:
+            raise RuntimeError(f"dispatcher '{self._name}' is closed")
+        if not self._threads:
+            for name, loop in (
+                ("pipeline-cut", self._cut_loop),
+                (f"{self._name}-device", self._issue_loop),
+                ("pipeline-stitch", self._stitch_loop),
+            ):
+                thread = threading.Thread(
+                    target=self._guarded, args=(loop,), name=name, daemon=True
+                )
+                thread.start()
+                self._threads.append(thread)
+
+    def close(self) -> None:
+        """Terminal and idempotent: the threads end, and whatever the
+        stream still had in hand fails with the error ``enrol`` raises
+        from now on."""
+        with self._cond:
+            if self._closed:
+                return
+            self._closed = True
+            threads, self._threads = self._threads, []
+            self._cond.notify_all()
+        for thread in threads:
+            if thread is not threading.current_thread():
+                thread.join(timeout=5.0)
+        error = RuntimeError(f"dispatcher '{self._name}' is closed")
+        with self._cond:
+            waiting = [e for e in self._staged if isinstance(e, _Direct)]
+            self._staged.clear()
+        for job in list(self._pending):
+            self._answer(job, error)
+        for direct in waiting:
+            direct.future.set_exception(error)
+
+    def _guarded(self, loop: Callable[[], None]) -> None:
         try:
-            for desc in descs:
-                if stop.is_set():
-                    return
-                with tracing.stage("engine.cut") as cut:
-                    staged = fill(desc)
-                stats.add(cut_seconds=cut.seconds)
-                if not _put(cut_q, (desc, staged)):
-                    return
-            _put(cut_q, _DONE)
-        except BaseException as exc:  # noqa: BLE001 — re-raised in caller
-            errors.append(exc)
-            stop.set()
+            loop()
+        except Exception:  # a fault of the stream's own: nobody would answer
+            logging.getLogger(__name__).exception(
+                "tile stream '%s': %s died; closing the stream",
+                self._name, threading.current_thread().name,
+            )
+            self.close()
 
-    def stitch_worker() -> None:
+    def _answer(self, job: TileJob, error: Optional[BaseException] = None) -> None:
+        """Resolve a job's future, once: its last row is blended, or it
+        failed (its remaining rows are then dropped)."""
+        with self._cond:
+            if job not in self._pending:
+                return
+            self._pending.remove(job)
+            if not self._pending:
+                self._stats.add(
+                    wall_seconds=(time.time_ns() - self._busy_since_ns) / 1e9
+                )
+        if error is None:
+            job.future.set_result(None)
+        else:
+            job.future.set_exception(error)
+
+    # ---- cut thread ---------------------------------------------------------
+
+    def _cut_loop(self) -> None:
+        cond = self._cond
+        while True:
+            with cond:
+                while True:
+                    if self._closed:
+                        return
+                    self._cutter_busy = True
+                    work = self._reserve()
+                    if work is not None:
+                        break
+                    # nothing to cut, or no room to cut into: the issuing
+                    # thread may have the open chunk as it stands
+                    self._cutter_busy = False
+                    cond.notify_all()
+                    cond.wait()
+            self._cut(*work)
+
+    def _reserve(self) -> Optional[tuple]:
+        """(Lock held.) The next rows to cut: the head job's next tiles
+        into the open chunk, a new one where there is none and room for
+        one. None when there is nothing to cut or no room."""
+        cutting = self._cutting
+        while cutting and cutting[0] not in self._pending:  # failed since
+            cutting.popleft()
+        if not cutting:
+            return None
+        job = cutting[0]
+        chunk = self._open
+        if chunk is not None and chunk.key != job.key:
+            self._close_chunk()  # tiles of another shape share no chunk
+            chunk = None
+        if chunk is None:
+            if len(self._staged) >= PREFETCH:
+                return None
+            chunk = self._open = _Chunk(
+                job.key,
+                self._pool.acquire(
+                    (self._chunk_rows, *job.row_shape), job.dtype
+                ),
+            )
+        chunk.sizes |= job.sizes
+        n = min(job.tiles - job.next_tile, self._capacity(chunk) - chunk.rows)
+        first = job.next_tile
+        job.next_tile += n
+        if job.next_tile == job.tiles:
+            cutting.popleft()
+        return job, chunk, first, n
+
+    def _capacity(self, chunk: _Chunk) -> int:
+        return min(self._tile_batch, max(chunk.sizes))
+
+    def _close_chunk(self) -> None:
+        """(Lock held.)"""
+        self._staged.append(self._open)
+        self._open = None
+
+    def _cut(self, job: TileJob, chunk: _Chunk, first: int, n: int) -> None:
+        error = None
         try:
-            while not stop.is_set():
+            job.cut_context.run(
+                self._cut_rows, job, first, chunk.buf[chunk.rows : chunk.rows + n]
+            )
+        except Exception as exc:
+            error = exc
+        with self._cond:
+            # the rows stand in the chunk either way; a failed job's are
+            # run and dropped
+            chunk.segments.append((job, first, n))
+            chunk.rows += n
+            if chunk.rows == self._capacity(chunk):
+                self._close_chunk()
+            self._cond.notify_all()
+        if error is not None:
+            self._answer(job, error)
+
+    def _cut_rows(self, job: TileJob, first: int, rows: np.ndarray) -> None:
+        with tracing.stage("engine.cut") as cut:
+            job.cut(first, rows)
+        self._stats.add(cut_seconds=cut.seconds)
+
+    # ---- issuing thread: the only one that talks to the device --------------
+
+    def _issue_loop(self) -> None:
+        cond = self._cond
+        window: deque[_Chunk] = deque()  # dispatched, not yet read back
+        while True:
+            entry = None
+            waited_from = 0
+            with cond:
+                while True:
+                    if self._closed:
+                        return
+                    place = len(window) < self._depth
+                    if place and self._staged:
+                        entry = self._staged.popleft()
+                        break
+                    if place and not self._cutter_busy:
+                        chunk = self._open
+                        if chunk is not None and chunk.rows:
+                            # late closing: a place is free, no full chunk
+                            # stands ready, and no tile in hand could
+                            # still join this one
+                            self._open = None
+                            entry = chunk
+                            break
+                    # read back when the window is full or nothing more
+                    # is coming; while the cut thread is at work the next
+                    # chunk goes first, so the device never waits for it
+                    if window and not (place and self._cutter_busy):
+                        break
+                    waited_from = waited_from or time.time_ns()
+                    cond.wait()
+                cond.notify_all()
+            if waited_from and isinstance(entry, _Chunk):
+                # idle with a job in hand: the cut thread's turn
+                tracing.record_stage(
+                    "engine.chunk_wait",
+                    max(waited_from, self._busy_since_ns), time.time_ns(),
+                    span=False,
+                )
+            if entry is None:
+                self._read_back(window.popleft())
+            elif isinstance(entry, _Chunk):
+                self._issue(entry, window)
+            else:
+                while window:
+                    self._read_back(window.popleft())
                 try:
-                    item = stitch_q.get(timeout=0.05)
-                except queue.Empty:
-                    continue
-                if item is _DONE:
-                    return
-                desc, host = item
-                with tracing.stage("engine.stitch") as blend:
-                    stitch(desc, host)
-                stats.add(stitch_seconds=blend.seconds)
-        except BaseException as exc:  # noqa: BLE001 — re-raised in caller
-            errors.append(exc)
-            stop.set()
+                    entry.future.set_result(entry.context.run(entry.run))
+                except Exception as exc:
+                    entry.future.set_exception(exc)
 
-    # each worker runs in a copy of the caller's context: its stages
-    # carry the caller's request number and land in a sampled request's
-    # tree (a Context can be entered by one thread at a time)
-    cut_t = threading.Thread(
-        target=contextvars.copy_context().run, args=(cut_worker,),
-        name="pipeline-cut", daemon=True,
-    )
-    stitch_t = threading.Thread(
-        target=contextvars.copy_context().run, args=(stitch_worker,),
-        name="pipeline-stitch", daemon=True,
-    )
-    cut_t.start()
-    stitch_t.start()
+    def _issue(self, chunk: _Chunk, window: deque) -> None:
+        rows = chunk.rows
+        size = min(s for s in chunk.sizes if s >= rows)
+        chunk.buf[rows:size] = 0  # stale rows of an earlier, fuller chunk
+        # a chunk's stages are recorded once, under its first job
+        head = chunk.segments[0][0]
+        try:
+            chunk.flight = head.issue_context.run(
+                self._dispatch, chunk.buf[:size], rows
+            )
+        except Exception as exc:
+            self._pool.release(chunk.buf)
+            self._to_stitch(chunk, None, exc)
+            return
+        window.append(chunk)
+        self._stats.add(chunks=1, chunks_shared=int(len(chunk.segments) > 1))
+        self._stats.observe_in_flight(len(window))
 
-    window: deque = deque()  # (desc, handle, dispatch_done_at)
-    last_force_done: Optional[float] = None
-    t_wall = time.perf_counter()
+    def _read_back(self, chunk: _Chunk) -> None:
+        flight = chunk.flight
+        head = chunk.segments[0][0]
+        host = error = None
+        try:
+            host = head.issue_context.run(self._force, flight)
+        except Exception as exc:
+            error = exc
+        self._pool.release(chunk.buf)
+        chunk.flight = None  # the device's copy goes now, not after the blend
+        if error is None:
+            # chunks execute one after another: this one had the device
+            # from its dispatch, or from when the one before it was
+            # ready, until it was ready itself
+            busy_from = max(flight.dispatched_ns, self._last_ready_ns)
+            compute = max(flight.ready_ns - busy_from, 0) / 1e9
+            self._last_ready_ns = flight.ready_ns
+            self._stats.add(compute_seconds=compute)
+            for job, _, n in chunk.segments:
+                job.compute_seconds += compute * n / chunk.rows
+                if job is not head and job.trace is not None and job.trace.sampled:
+                    # the shared chunk's device stages, as spans of a
+                    # sampled job that rode it without being its first
+                    for st in flight.stages:
+                        tracing.record_span(
+                            st.name, st.seconds, started_at=st.start_ns / 1e9,
+                            parent_id=job.parent_span, ctx=job.trace, **st.attrs,
+                        )
+        self._to_stitch(chunk, host, error)
 
-    def force_oldest() -> None:
-        nonlocal last_force_done
-        desc, handle, dispatched_at = window.popleft()
-        host = force(handle)
-        done = time.perf_counter()
-        busy_from = dispatched_at
-        if last_force_done is not None and last_force_done > busy_from:
-            busy_from = last_force_done
-        stats.add(compute_seconds=max(done - busy_from, 0.0))
-        last_force_done = done
-        _put(stitch_q, (desc, host))
+    def _to_stitch(self, chunk: _Chunk, host, error) -> None:
+        """Queue a chunk for the stitch thread; blocks while it is more
+        than a window behind (results on the host are bounded too)."""
+        with self._cond:
+            while len(self._blends) > self._depth and not self._closed:
+                self._cond.wait()
+            self._blends.append((chunk, host, error))
+            self._cond.notify_all()
 
-    try:
-        while not stop.is_set():
-            try:
-                item = cut_q.get(timeout=0.05)
-            except queue.Empty:
-                continue
-            if item is _DONE:
-                break
-            desc, staged = item
-            handle = dispatch(desc, staged)
-            window.append((desc, handle, time.perf_counter()))
-            stats.add(chunks=1)
-            stats.observe_in_flight(len(window))
-            if len(window) >= depth:
-                force_oldest()
-        while window and not stop.is_set():
-            force_oldest()
-        _put(stitch_q, _DONE)
-    except BaseException:
-        stop.set()
-        raise
-    finally:
-        # unbounded joins: both workers exit promptly once the stream
-        # ends or ``stop`` is set (their queue waits poll it), and the
-        # caller reads the stitch accumulator right after this returns —
-        # a timed-out join would hand back a result the stitch thread is
-        # still mutating
-        cut_t.join()
-        stitch_t.join()
-        stats.add(wall_seconds=time.perf_counter() - t_wall, runs=1)
-    if errors:
-        raise errors[0]
+    # ---- stitch thread ------------------------------------------------------
+
+    def _stitch_loop(self) -> None:
+        cond = self._cond
+        while True:
+            with cond:
+                while True:
+                    if self._closed:
+                        return
+                    if self._blends:
+                        break
+                    cond.wait()
+                chunk, host, error = self._blends.popleft()
+                cond.notify_all()
+            at = 0
+            for job, first, n in chunk.segments:
+                rows = None if host is None else host[at : at + n]
+                at += n
+                if job not in self._pending:
+                    continue  # failed already: its rows are dropped
+                # only the jobs that rode the chunk fail with it
+                failure = error
+                if failure is None:
+                    try:
+                        job.stitch_context.run(self._blend, job, first, rows)
+                    except Exception as exc:
+                        failure = exc
+                if failure is not None:
+                    self._answer(job, failure)
+                elif job.blended == job.tiles:
+                    self._answer(job)
+
+    def _blend(self, job: TileJob, first: int, rows: np.ndarray) -> None:
+        with tracing.stage("engine.stitch") as blend:
+            job.blend(first, rows)
+        job.blended += len(rows)
+        self._stats.add(stitch_seconds=blend.seconds)

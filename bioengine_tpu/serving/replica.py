@@ -436,7 +436,7 @@ class Replica(ReplicaStateMixin):
             t_exec = time.monotonic()
             # chip-seconds accumulate here, where app/deployment/method
             # labels exist: engines called (directly or through the
-            # batcher/dispatch thread) add wall x mesh-width into the
+            # batcher) add their device seconds x mesh-width into the
             # request-scoped accumulator. Batched flushes attribute the
             # whole batch's device time to the submitter whose context
             # the flush task inherited — totals stay exact, per-method

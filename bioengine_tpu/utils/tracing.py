@@ -233,8 +233,9 @@ def sampled() -> bool:
 class ChipSecondsAccumulator:
     """Mutable per-request device-cost sink. The replica installs one
     around instance execution; every engine ``predict`` underneath
-    (including on the dispatch thread, which runs each task in a copy
-    of the submitter's context) adds its wall seconds x mesh width.
+    (including on a request thread, which runs each task in a copy of
+    the submitter's context) adds the device seconds of its rows x
+    mesh width.
     Unlike spans this is NOT sampled — chip-seconds are the
     billing/scheduling signal and must be exact."""
 

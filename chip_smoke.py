@@ -533,7 +533,7 @@ async def serve(
             f"{batches} batches for {requests} batched requests",
         )
 
-        # one image above max_tile: _predict_tiled_pipelined
+        # one image above max_tile: tiled, through the engine's stream
         big = rng.normal(size=(1, cfg.big, cfg.big, 1)).astype(np.float32)
         reply = await conn.call(
             app_sid, "infer", model_id=MODEL_ID, inputs=big, sample_id="big"
@@ -767,7 +767,7 @@ async def worker_session(cfg: SmokeConfig, report: Report):
 
 def lingering_threads() -> list[str]:
     """Non-daemon threads that would keep the interpreter from exiting
-    by itself (the engine dispatch threads, if a close was missed)."""
+    by itself (an engine's request threads, if a close was missed)."""
     return [
         t.name
         for t in threading.enumerate()

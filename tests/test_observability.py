@@ -671,8 +671,9 @@ class TestStages:
     async def test_sampled_tree_holds_the_stages_under_engine_predict(self):
         """A sampled request's tree: engine.queue and engine.request
         under the caller's span, engine.predict inside the request,
-        the per-chunk stages (the cut and stitch threads' too) under
-        engine.predict, whose stage_seconds is their sums."""
+        the stages of the chunks it rode (recorded on the stream's
+        threads) under engine.predict, whose stage_seconds is their
+        sums and whose chip_seconds is its share of the device time."""
         import numpy as np
 
         eng = _stage_engine()
@@ -696,6 +697,21 @@ class TestStages:
             "engine.queue", "engine.request",
         ]
         (predict,) = root["children"][1]["children"]
+        self._check_predict_span(predict, ctx)
+        # alone, the request is billed every chunk whole
+        assert predict["attrs"]["stage_seconds"]["compute"] == pytest.approx(
+            eng.pipeline_stats.compute_seconds / 2, rel=0.9
+        )
+        # the same intervals stand on the timeline, sampled or not
+        (on_timeline,) = tracing.get_stages(
+            int(predict["started_at"] * 1e9) - 1000, name="engine.predict"
+        )
+        assert on_timeline["duration_s"] == pytest.approx(
+            predict["duration_s"], abs=1e-6
+        )
+
+    @staticmethod
+    def _check_predict_span(predict, ctx):
         assert predict["name"] == "engine.predict"
         own = {}
         for child in predict["children"]:
@@ -714,16 +730,93 @@ class TestStages:
             stage_seconds["device_wait"] + stage_seconds["d2h"], abs=2e-6
         )
         assert stage_seconds["compute"] > 0
+        # billed the device time of its rows, not the time it was in hand
         assert predict["attrs"]["chip_seconds"] == pytest.approx(
-            predict["duration_s"], abs=1e-5
+            stage_seconds["compute"] * predict["attrs"]["devices"], abs=2e-6
         )
-        # the same intervals stand on the timeline, sampled or not
-        (on_timeline,) = tracing.get_stages(
-            int(predict["started_at"] * 1e9) - 1000, name="engine.predict"
-        )
-        assert on_timeline["duration_s"] == pytest.approx(
-            predict["duration_s"], abs=1e-6
-        )
+        assert predict["attrs"]["chip_seconds"] <= predict["duration_s"] + 1e-5
+
+    async def test_two_sampled_requests_in_one_stream(self):
+        """Two requests in hand at once, sharing a chunk: their
+        engine.request spans overlap, each tree holds every stage of the
+        chunks its request rode (the shared chunk's device stages in
+        both), and the two bills sum to the device time."""
+        import asyncio
+        import threading
+
+        import numpy as np
+
+        eng = _stage_engine()
+        # 9 tiles each in chunks of 4: 4 | 4 | 1 + 3 | 4 | 2
+        images = [np.full((1, 16, 18, 1), v, np.float32) for v in (1.0, 3.0)]
+        gate = threading.Event()
+        sound = eng._stream._force
+
+        def held(flight):  # the first read-back waits for both to enrol
+            gate.wait(30)
+            return sound(flight)
+
+        eng._stream._force = held
+
+        async def ask(x):
+            ctx = tracing.maybe_start_trace(sample=True)
+            token = tracing.activate(ctx)
+            try:
+                with tracing.span("caller"):
+                    return ctx, await eng.predict_async(x)
+            finally:
+                tracing.deactivate(token)
+
+        try:
+            await eng.predict_async(images[0])  # compile
+            before = eng.pipeline_stats.as_dict()
+            busy = eng.pipeline_stats.compute_seconds
+            since = time.time_ns()
+            gate.clear()
+            asks = [asyncio.ensure_future(ask(x)) for x in images]
+            while len(eng._stream._pending) < 2 or eng._stream._cutter_busy:
+                await asyncio.sleep(0.002)
+            gate.set()
+            (ctx_a, out_a), (ctx_b, out_b) = await asyncio.gather(*asks)
+            after = eng.pipeline_stats.as_dict()
+            busy = eng.pipeline_stats.compute_seconds - busy
+        finally:
+            gate.set()
+            eng.close()
+        assert float(out_a.mean()) == pytest.approx(2.0, rel=1e-3)
+        assert float(out_b.mean()) == pytest.approx(6.0, rel=1e-3)
+        assert after["chunks_shared"] - before["chunks_shared"] == 1
+        bills, requests = [], []
+        for ctx in (ctx_a, ctx_b):
+            (root,) = tracing.build_trace_tree(ctx.trace_id)["tree"]
+            assert [c["name"] for c in root["children"]] == [
+                "engine.queue", "engine.request",
+            ]
+            request = root["children"][1]
+            requests.append(request)
+            (predict,) = request["children"]
+            self._check_predict_span(predict, ctx)
+            # three chunks each: the shared one is in both trees
+            puts = [c for c in predict["children"] if c["name"] == "engine.put"]
+            assert len(puts) == 3
+            bills.append(predict["attrs"]["chip_seconds"])
+        assert sum(bills) == pytest.approx(busy, abs=1e-5)
+        a, b = requests
+        assert b["started_at"] < a["started_at"] + a["duration_s"]
+        # every name, thread prefix and counter the benchmark's readers
+        # use is still there, with the requests overlapping
+        threads = {}
+        for s in tracing.get_stages(since):
+            threads.setdefault(s["name"], set()).add(s["thread"].split("-")[0])
+        for name in ("engine.queue", "engine.request", "engine.predict",
+                     "engine.put", "engine.dispatch", "engine.device_wait",
+                     "engine.d2h"):
+            assert threads[name] == {"dispatch"}, name
+        assert threads["engine.cut"] == {"pipeline"}
+        assert threads["engine.stitch"] == {"pipeline", "dispatch"}
+        for key in ("requests", "queue_seconds", "chunks", "chunks_shared",
+                    "rows_executed", "rows_useful", "d2h_seconds"):
+            assert key in after
 
     async def test_get_traces_returns_stages(self):
         from types import SimpleNamespace
@@ -816,8 +909,9 @@ class TestStages:
 
     async def test_model_runner_stages_cover_the_host_work(self, tmp_path):
         """runtime.assemble / runtime.split on the loop, runtime.preprocess
-        / runtime.postprocess on the dispatch thread inside
-        engine.request, their seconds in the engine's PipelineStats."""
+        / runtime.postprocess on the request's own thread inside
+        engine.request (not the one that talks to the device), their
+        seconds in the engine's PipelineStats."""
         import importlib.util
 
         import jax
@@ -877,6 +971,10 @@ class TestStages:
             assert request["start_ns"] <= stages[name]["start_ns"]
             assert stages[name]["end_ns"] <= request["end_ns"]
         assert request["thread"].startswith("dispatch-")
+        # the host work on either side of the engine is off the one
+        # thread that talks to the device
+        assert stages["engine.dispatch"]["thread"].endswith("-device")
+        assert stages["engine.dispatch"]["thread"] != request["thread"]
         loop_thread = stages["runtime.assemble"]["thread"]
         assert loop_thread == stages["runtime.split"]["thread"] != request["thread"]
         # in the order a request passes them

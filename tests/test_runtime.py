@@ -505,24 +505,73 @@ class TestFlows:
             assert max(ious) > 0.7
 
 
-class TestPipelinedEngine:
-    """The overlapped tiled pipeline (runtime/pipeline.py) against the
-    serial baseline: bit-identical results, a bounded in-flight window,
-    reusable staging buffers, and the async front door."""
+class _GatedEngine(InferenceEngine):
+    """An engine whose read-back waits for ``gate``: what is dispatched
+    stays in flight, and what is enrolled meanwhile piles up behind it,
+    so which rows share which chunk does not depend on thread timing."""
 
-    def _engine(self, apply_fn=None, **cfg_overrides):
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.gate = threading.Event()
+        self.gate.set()
+
+    def _force_chunk(self, flight):
+        self.gate.wait(30)
+        return super()._force_chunk(flight)
+
+    def served_together(self, inputs, plug):
+        """Futures of ``inputs``' predictions, all enrolled while
+        ``plug``'s two chunks hold the in-flight window: the stream has
+        every one of them in hand before it can issue another chunk."""
+        self.gate.clear()
+        before = self.pipeline_stats.chunks
+        futures = [self.submit(self.predict, plug)]
+        self.wait_for(lambda: self.pipeline_stats.chunks - before == 2)
+        for x in inputs:
+            futures.append(self.submit(self.predict, x))
+            self.wait_for(lambda: len(self._stream._pending) == len(futures))
+        self.wait_for(lambda: not self._stream._cutter_busy)
+        self.gate.set()
+        return futures[1:]
+
+    @staticmethod
+    def wait_for(condition, seconds=30):
+        deadline = time.monotonic() + seconds
+        while not condition():
+            assert time.monotonic() < deadline, "the stream stood still"
+            time.sleep(0.002)
+
+
+def _program_shapes(eng):
+    return set(eng.describe()["programs"]["compile_seconds"])
+
+
+class TestTileStream:
+    """The engine's one tile stream (runtime/pipeline.py) against the
+    serial baseline: bit-identical replies whoever shares a chunk, chunks
+    filled across the requests in hand and closed late, a bounded
+    in-flight window, reusable staging buffers, failures that stay with
+    the requests that rode the chunk, and the async front door."""
+
+    def _engine(self, apply_fn=None, cls=InferenceEngine, **cfg_overrides):
         cfg_kw = dict(
             max_tile=64, tile=48, tile_overlap=16, tile_batch=3,
             pipeline_depth=2,
         )
         cfg_kw.update(cfg_overrides)
-        return InferenceEngine(
+        return cls(
             "pipe",
             apply_fn or (lambda p, x: x * p["scale"] + 0.25),
             {"scale": jnp.asarray(1.7)},
             config=EngineConfig(**cfg_kw),
             cache=CompiledProgramCache(),
         )
+
+    @staticmethod
+    def _image(tiles_per_axis, channels=1):
+        # stride 32 over tile 48: 3, 4, 5 tiles an axis at 112, 144, 176 px
+        size = 48 + 32 * (tiles_per_axis - 1)
+        return np.random.rand(1, size, size, channels).astype(np.float32)
 
     def test_planar_identical_to_serial(self):
         # tile 48 buckets to 64: the staging-buffer pad margins are
@@ -552,6 +601,186 @@ class TestPipelinedEngine:
         np.testing.assert_allclose(piped, serial, rtol=0, atol=0)
         assert piped.shape == x.shape
 
+    @pytest.mark.parametrize("ndim", [4, 5])
+    def test_requests_served_together_reply_like_serial(self, ndim):
+        """9-, 16- and 25-tile inputs from several threads at once: each
+        reply is bit-identical to the serial path's, whoever shared its
+        chunks (5-D: a stack tiled in z as well)."""
+        if ndim == 4:
+            eng = self._engine(cls=_GatedEngine, tile_batch=16)
+            inputs = [self._image(n) for n in (3, 4, 5, 3, 5, 4)]
+        else:
+            eng = _GatedEngine(
+                "pipe3d", lambda p, x: x * 3.0 + 1.0, {},
+                config=EngineConfig(
+                    max_tile=32, tile=24, tile_overlap=8,
+                    max_tile_z=8, tile_z=6, tile_overlap_z=2,
+                    ladder_z=(2, 4, 6, 8), tile_batch=16,
+                ),
+                cache=CompiledProgramCache(),
+            )
+            inputs = [
+                np.random.rand(1, d, h, w, 1).astype(np.float32)
+                for d, h, w in ((13, 40, 50), (10, 40, 40), (13, 56, 40), (6, 40, 50))
+            ]
+        serial = [eng.predict_serial(x) for x in inputs]
+        replies = [None] * len(inputs)
+
+        def ask(i):
+            replies[i] = eng.predict(inputs[i])
+
+        threads = [
+            threading.Thread(target=ask, args=(i,)) for i in range(len(inputs))
+        ]
+        # a held read-back: the requests pile up behind the first chunks,
+        # so chunks are shared for certain
+        eng.gate.clear()
+        try:
+            for t in threads:
+                t.start()
+            eng.wait_for(lambda: len(eng._stream._pending) == len(inputs))
+            eng.gate.set()
+            for t in threads:
+                t.join(timeout=60)
+                assert not t.is_alive()
+        finally:
+            eng.gate.set()
+            eng.close()
+        for reply, want in zip(replies, serial):
+            np.testing.assert_allclose(reply, want, rtol=0, atol=0)
+        assert eng.pipeline_stats.chunks_shared >= 1
+
+    def test_chunks_are_filled_across_the_requests_in_hand(self):
+        """Three 9-tile requests: 48 rows one after the other, 32 when
+        the stream has all three in hand (16, then 11 padded to 16), in
+        no program that a lone request would not run."""
+        images = [self._image(3) for _ in range(3)]
+        alone = self._engine(tile_batch=16)
+        for x in images:
+            alone.predict(x)
+        assert (alone.pipeline_stats.rows_executed,
+                alone.pipeline_stats.chunks_shared) == (48, 0)
+
+        eng = self._engine(cls=_GatedEngine, tile_batch=16)
+        try:
+            futures = eng.served_together(images, plug=self._image(5))
+            before = eng.pipeline_stats.as_dict()   # the plug's two chunks
+            replies = [f.result(timeout=60) for f in futures]
+        finally:
+            eng.gate.set()
+            eng.close()
+        after = eng.pipeline_stats.as_dict()
+        assert [after[k] - before[k] for k in
+                ("chunks", "rows_executed", "rows_useful", "chunks_shared")
+                ] == [2, 32, 27, 2]
+        assert _program_shapes(eng) <= _program_shapes(alone)
+        assert eng.describe()["pipeline"]["chunks_shared"] == 2
+        for reply, x in zip(replies, images):
+            np.testing.assert_allclose(
+                reply, alone.predict_serial(x), rtol=0, atol=0
+            )
+
+    def test_a_chunk_runs_at_a_row_count_one_of_its_requests_runs_alone(self):
+        """6 tiles alone run 4 + 2 rows, 9 tiles 4 + 4 + 1; together
+        they fill chunks of 4 (a tail of 3 runs at 4): no chunk of 2 or
+        of 1, and no program that the two would not have built alone
+        (each request builds its own on its own thread as it arrives,
+        so the issuing thread never compiles)."""
+        six = np.random.rand(1, 112, 80, 1).astype(np.float32)   # 3 x 2 tiles
+        nine = self._image(3)
+        eng = self._engine(cls=_GatedEngine, tile_batch=4)
+        built_on, lookup = [], eng.cache.get_or_compile
+
+        def noting(key, build):
+            def noted():
+                built_on.append(threading.current_thread().name)
+                return build()
+
+            return lookup(key, noted)
+
+        eng.cache.get_or_compile = noting
+        try:
+            futures = eng.served_together([six, nine], plug=self._image(3))
+            before, since = eng.pipeline_stats.rows_executed, time.time_ns()
+            got = [f.result(timeout=60) for f in futures]
+        finally:
+            eng.gate.set()
+            eng.close()
+        # 15 tiles: 4 | 2 + 2 | 4 | 3 -> 4
+        assert eng.pipeline_stats.rows_executed - before == 16
+        puts = [
+            s for s in tracing.get_stages(since)
+            if s["name"] == "engine.put" and s["thread"] == "dispatch-pipe-device"
+        ]
+        assert [s["attrs"]["bytes"] for s in puts] == [4 * 64 * 64 * 4] * 4
+        np.testing.assert_allclose(got[0], eng.predict_serial(six), rtol=0, atol=0)
+        np.testing.assert_allclose(got[1], eng.predict_serial(nine), rtol=0, atol=0)
+        alone = self._engine(tile_batch=4)
+        alone.predict(six), alone.predict(nine)
+        assert _program_shapes(eng) <= _program_shapes(alone)
+        # built where the request is, not where the device is fed
+        assert len(built_on) == 3       # 4, 2 and 1 rows
+        assert all(name.startswith("dispatch-pipe_") for name in built_on)
+
+    def test_a_lone_request_runs_the_chunks_it_always_did(self):
+        eng = self._engine(tile_batch=16)
+        x = self._image(5)                  # 25 tiles: 16, then 9 padded to 16
+        try:
+            reply = eng.predict(x)
+        finally:
+            eng.close()
+        stats = eng.pipeline_stats
+        assert (stats.chunks, stats.chunks_shared) == (2, 0)
+        assert (stats.rows_executed, stats.rows_useful) == (32, 25)
+        np.testing.assert_allclose(reply, eng.predict_serial(x), rtol=0, atol=0)
+        # 9 tiles in chunks of 4: 4, 4 and a tail of 1, each at its own
+        # rung of the batch ladder, as the serial path runs them
+        small = self._engine(tile_batch=4)
+        small.predict(self._image(3))
+        assert (small.pipeline_stats.chunks, small.pipeline_stats.rows_executed) == (3, 9)
+        serial = self._engine(tile_batch=4)
+        serial.predict_serial(self._image(3))
+        assert _program_shapes(small) == _program_shapes(serial)
+
+    def test_the_open_chunk_is_closed_late(self):
+        """With two chunks in flight (a gated read-back keeps them
+        there) a 9-tile request opens a chunk, and a second one that
+        arrives later still joins it: 9 + 7 go as one full chunk."""
+        eng = self._engine(cls=_GatedEngine, tile_batch=16)
+        first, second, third = self._image(5), self._image(3), self._image(3)
+        try:
+            eng.predict(self._image(3))     # compile the one program
+            before = eng.pipeline_stats.as_dict()
+
+            def issued():
+                return eng.pipeline_stats.chunks - before["chunks"]
+
+            eng.gate.clear()
+            futures = [eng.submit(eng.predict, first)]   # 16 + 9, held in flight
+            eng.wait_for(lambda: issued() == 2)
+            futures.append(eng.submit(eng.predict, second))
+            eng.wait_for(lambda: len(eng._stream._pending) == 2)
+            time.sleep(0.05)                # its 9 rows wait in the open chunk
+            assert eng._stream._open.rows == 9
+            futures.append(eng.submit(eng.predict, third))
+            eng.wait_for(lambda: len(eng._stream._pending) == 3)
+            eng.wait_for(lambda: len(eng._stream._staged) == 1)
+            assert issued() == 2            # nothing went meanwhile
+            eng.gate.set()
+            replies = [f.result(timeout=60) for f in futures]
+        finally:
+            eng.gate.set()
+            eng.close()
+        after = eng.pipeline_stats.as_dict()
+        # 16, 9 -> 16, 9 + 7, 2 -> 16; one after the other it would be 5 chunks
+        assert after["chunks"] - before["chunks"] == 4
+        assert after["chunks_shared"] - before["chunks_shared"] == 1
+        assert after["rows_useful"] - before["rows_useful"] == 25 + 9 + 9
+        for reply, x in zip(replies, (first, second, third)):
+            np.testing.assert_allclose(
+                reply, eng.predict_serial(x), rtol=0, atol=0
+            )
+
     def test_staging_reuse_after_direct_path_poisoning(self):
         """A direct (non-tiled) predict shares the staging pool; its
         stale content in a reused buffer's pad margins must never leak
@@ -566,66 +795,165 @@ class TestPipelinedEngine:
         piped = eng.predict(x)
         np.testing.assert_allclose(piped, serial, rtol=0, atol=0)
 
-    def test_in_flight_window_bounded(self):
-        for depth in (1, 2, 3):
-            eng = self._engine(pipeline_depth=depth, tile_batch=1)
-            x = np.random.rand(1, 120, 120, 1).astype(np.float32)
-            out = eng.predict(x)
-            stats = eng.pipeline_stats
-            assert stats.chunks >= 4  # enough chunks to fill any window
-            assert stats.max_in_flight <= depth, (depth, stats.as_dict())
-            np.testing.assert_allclose(
-                out, x * 1.7 + 0.25, rtol=1e-4, atol=1e-5
-            )
-
-    def test_depth_zero_disables_pipeline(self):
-        eng = self._engine(pipeline_depth=0)
-        x = np.random.rand(2, 100, 90, 1).astype(np.float32)
+    @pytest.mark.parametrize("depth", [0, 1, 2, 3])
+    def test_in_flight_window_bounded(self, depth):
+        # a depth below 1 is a window of one chunk: the serial order,
+        # through the same stream (there is no second way to predict)
+        eng = self._engine(pipeline_depth=depth, tile_batch=1)
+        x = np.random.rand(1, 120, 120, 1).astype(np.float32)
         out = eng.predict(x)
-        np.testing.assert_allclose(
-            out, eng.predict_serial(x), rtol=0, atol=0
-        )
-        assert eng.pipeline_stats.runs == 0  # pipeline never engaged
+        stats = eng.pipeline_stats
+        assert stats.chunks >= 4  # enough chunks to fill any window
+        assert stats.max_in_flight <= max(depth, 1), (depth, stats.as_dict())
+        np.testing.assert_allclose(out, eng.predict_serial(x), rtol=0, atol=0)
 
     def test_staging_buffers_are_recycled(self):
+        from bioengine_tpu.runtime.pipeline import PREFETCH
+
         eng = self._engine()
         x = np.random.rand(4, 150, 150, 1).astype(np.float32)
         for _ in range(3):
             eng.predict(x)
-        # many chunks over many runs, but the pool only ever allocated
-        # what was concurrently outstanding (depth + prefetch bound)
+        # many chunks over many requests, but the pool only ever
+        # allocated what was concurrently outstanding: one shape of
+        # buffer, the window and the chunks cut ahead of it
         assert eng.pipeline_stats.chunks >= 12
-        cfg = eng.config
-        per_shape_bound = cfg.pipeline_depth + cfg.pipeline_prefetch + 2
-        # two shape keys (full chunks + the smaller trailing chunk)
-        assert eng._staging_pool.allocated <= 2 * per_shape_bound
+        assert eng._staging_pool.allocated <= eng.config.pipeline_depth + PREFETCH
 
     def test_stats_accounting(self):
         eng = self._engine()
         x = np.random.rand(2, 100, 100, 1).astype(np.float32)
         eng.predict(x)
         d = eng.pipeline_stats.as_dict()
-        assert d["runs"] == 1 and d["items"] == 2 and d["chunks"] > 0
+        assert d["items"] == 2 and d["chunks"] > 0 and d["chunks_shared"] == 0
         for stage in ("cut", "put", "dispatch", "readback", "stitch"):
             assert d[f"{stage}_seconds"] >= 0.0
         assert d["wall_seconds"] > 0
         assert 0.0 <= d["overlap_efficiency"] <= 1.5  # clock-skew slack
 
-    def test_error_in_model_propagates_and_pipeline_unwinds(self):
+    def test_error_in_model_fails_the_request_and_the_stream_goes_on(self):
         def bad_fn(params, x):
-            raise RuntimeError("trace-time boom")
+            if x.shape[-1] == 2:
+                raise RuntimeError("trace-time boom")
+            return x + 1.0
 
         eng = self._engine(apply_fn=bad_fn)
         with pytest.raises(RuntimeError, match="boom"):
-            eng.predict(np.random.rand(1, 100, 100, 1).astype(np.float32))
-        # the pipeline must be reusable after an aborted run
-        good = self._engine()
-        good.predict(np.random.rand(1, 100, 100, 1).astype(np.float32))
+            eng.predict(np.random.rand(1, 100, 100, 2).astype(np.float32))
+        # the same stream serves the next request
+        x = np.random.rand(1, 100, 100, 1).astype(np.float32)
+        np.testing.assert_allclose(eng.predict(x), x + 1.0, rtol=1e-6)
 
-    def test_global_output_raises_in_pipeline(self):
+    def test_a_failing_tile_fails_only_the_requests_that_rode_its_chunk(self):
+        def check(x):
+            if x.max() > 100:
+                raise ValueError("poisoned tile")
+            return x * 2.0
+
+        def apply_fn(p, x):
+            return jax.pure_callback(
+                check, jax.ShapeDtypeStruct(x.shape, x.dtype), x
+            )
+
+        eng = self._engine(apply_fn=apply_fn, cls=_GatedEngine, tile_batch=16)
+        poisoned, rider, clear = self._image(3), self._image(3), self._image(4)
+        poisoned[0, 0, 0, 0] = 1000.0
+        try:
+            # chunks: 9 poisoned + 7 of the rider | 2 of the rider + 14 | 2
+            futures = eng.served_together(
+                [poisoned, rider, clear], plug=self._image(5)
+            )
+            for f in futures[:2]:
+                with pytest.raises(Exception, match="poisoned tile"):
+                    f.result(timeout=60)
+            np.testing.assert_allclose(
+                futures[2].result(timeout=60), clear * 2.0, rtol=1e-6
+            )
+            # and the stream serves the next
+            np.testing.assert_allclose(eng.predict(rider), rider * 2.0, rtol=1e-6)
+        finally:
+            eng.gate.set()
+            eng.close()
+
+    def test_global_output_raises_in_the_stream(self):
         eng = self._engine(apply_fn=lambda p, x: jnp.mean(x, axis=(1, 2)))
         with pytest.raises(ValueError, match="dense spatial"):
             eng.predict(np.ones((1, 100, 100, 2), np.float32))
+
+    def test_close_fails_the_requests_in_hand_and_ends_the_threads(self):
+        eng = self._engine(cls=_GatedEngine, tile_batch=16)
+        eng.gate.clear()
+        futures = [eng.submit(eng.predict, self._image(n)) for n in (5, 4, 3)]
+        eng.wait_for(lambda: len(eng._stream._pending) == 3)
+        eng.wait_for(lambda: eng.pipeline_stats.chunks == 2)  # held in flight
+        # an input that needs no tiling, waiting for its turn behind them
+        futures.append(eng.submit(eng.predict, np.ones((1, 40, 40, 1), np.float32)))
+        eng.wait_for(lambda: any(
+            type(entry).__name__ == "_Direct" for entry in eng._stream._staged
+        ))
+        threads = [
+            t for t in threading.enumerate()
+            if t.name.startswith(("dispatch-pipe", "pipeline-"))
+            and t in eng._stream._threads + list(eng._dispatcher._pool._threads)
+        ]
+        assert {t.name for t in threads} >= {
+            "pipeline-cut", "dispatch-pipe-device", "pipeline-stitch",
+            "dispatch-pipe_0", "dispatch-pipe_3",
+        }
+        closer = threading.Thread(target=eng.close)
+        closer.start()
+        eng.wait_for(lambda: eng._stream._closed)
+        eng.gate.set()      # the issuing thread was held in a read-back
+        closer.join(timeout=30)
+        assert not closer.is_alive()
+        for f in futures:
+            with pytest.raises(RuntimeError, match="dispatcher 'dispatch-pipe' is closed"):
+                f.result(timeout=30)
+        with pytest.raises(RuntimeError, match="is closed"):
+            eng.submit(eng.predict, self._image(3))
+        with pytest.raises(RuntimeError, match="is closed"):
+            eng.predict(self._image(3))
+        eng.close()  # idempotent
+        for t in threads:
+            t.join(timeout=10)
+        assert not [t.name for t in threads if t.is_alive()]
+
+    def test_chip_seconds_are_shares_of_the_device_time(self):
+        """Requests served together are billed their rows' share of each
+        chunk they rode: the bills sum to the engine's device time."""
+        eng = self._engine(cls=_GatedEngine, tile_batch=16)
+        accounts = []
+
+        def accounted(x):
+            acc, token = tracing.start_chip_accounting()
+            try:
+                return eng.predict(x)
+            finally:
+                tracing.stop_chip_accounting(token)
+                accounts.append(acc)
+
+        try:
+            eng.predict(self._image(3))     # compile outside the bills
+            busy = eng.pipeline_stats.compute_seconds
+            eng.gate.clear()
+            futures = [
+                eng.submit(accounted, self._image(n)) for n in (5, 3, 3, 4)
+            ]
+            futures.append(
+                eng.submit(accounted, np.ones((2, 40, 40, 1), np.float32))
+            )
+            eng.wait_for(lambda: len(eng._stream._pending) == 4)
+            eng.gate.set()
+            for f in futures:
+                f.result(timeout=60)
+        finally:
+            eng.gate.set()
+            eng.close()
+        assert eng.pipeline_stats.chunks_shared >= 1
+        assert len(accounts) == 5 and all(acc.seconds > 0 for acc in accounts)
+        assert sum(acc.seconds for acc in accounts) == pytest.approx(
+            eng.pipeline_stats.compute_seconds - busy, rel=1e-6
+        )
 
     @pytest.mark.anyio
     async def test_predict_async_front_door(self):
@@ -635,8 +963,8 @@ class TestPipelinedEngine:
         try:
             x = np.random.rand(2, 100, 90, 1).astype(np.float32)
             serial = eng.predict_serial(x)
-            # concurrent async callers serialize on the dispatch thread
-            # and all come back correct
+            # concurrent async callers join the one stream and all come
+            # back correct
             outs = await asyncio.gather(
                 *(eng.predict_async(x) for _ in range(3))
             )
@@ -644,6 +972,27 @@ class TestPipelinedEngine:
                 np.testing.assert_allclose(out, serial, rtol=0, atol=0)
         finally:
             eng.close()
+
+    def test_only_the_issuing_thread_talks_to_the_device(self):
+        """Tiled or not, from a request thread or a caller's own: the
+        device's stages are all on the stream's issuing thread."""
+        eng = self._engine()
+        tiled = np.random.rand(1, 100, 90, 1).astype(np.float32)
+        direct = np.random.rand(2, 60, 60, 1).astype(np.float32)
+        since = time.time_ns()
+        try:
+            eng.predict(tiled), eng.predict(direct)
+            eng.submit(eng.predict, tiled).result(timeout=60)
+            eng.submit(eng.predict, direct).result(timeout=60)
+        finally:
+            eng.close()
+        on_device = [
+            s for s in tracing.get_stages(since)
+            if s["name"] in ("engine.put", "engine.dispatch",
+                             "engine.device_wait", "engine.d2h")
+        ]
+        assert len(on_device) >= 4 * 4
+        assert {s["thread"] for s in on_device} == {"dispatch-pipe-device"}
 
 
 class TestGlobalOutputGuard:
@@ -756,9 +1105,11 @@ class TestEngineStages:
     """The stage timeline (utils/tracing.py): one time_ns() pair per
     stage feeds the process-wide timeline and the PipelineStats sums."""
 
-    ON_DISPATCH = (
-        "engine.queue", "engine.request", "engine.predict", "engine.put",
-        "engine.dispatch", "engine.device_wait", "engine.d2h",
+    # the request's own thread: its stages from submit to the reply
+    ON_REQUEST = ("engine.queue", "engine.request", "engine.predict")
+    # the stream's issuing thread: the device's stages, and nothing else
+    ON_DEVICE = (
+        "engine.put", "engine.dispatch", "engine.device_wait", "engine.d2h",
     )
     SUMMED = {
         "engine.cut": "cut_seconds", "engine.put": "put_seconds",
@@ -768,10 +1119,10 @@ class TestEngineStages:
         "engine.queue": "queue_seconds",
     }
 
-    def _engine(self, **cfg_overrides):
+    def _engine(self, cls=InferenceEngine, **cfg_overrides):
         cfg_kw = dict(max_tile=64, tile=48, tile_overlap=16, tile_batch=16)
         cfg_kw.update(cfg_overrides)
-        return InferenceEngine(
+        return cls(
             "staged",
             lambda p, x: x * p["scale"] + 0.25,
             {"scale": jnp.asarray(1.7)},
@@ -785,68 +1136,133 @@ class TestEngineStages:
         return {f: getattr(stats, f) for f in stats._FIELDS}
 
     @staticmethod
-    def _stages_of(eng, since_ns):
-        return [
+    def _stages_of(eng, since_ns, also=()):
+        """The engine's stages since then, the issuing thread's idling
+        (``engine.chunk_wait``, no request's own stage) left out."""
+        stages = [
             s for s in tracing.get_stages(since_ns)
-            if s["thread"].startswith(("dispatch-staged", "pipeline-"))
+            if s["thread"].startswith(("dispatch-staged", "pipeline-", *also))
         ]
+        idling = [s for s in stages if s["name"] == "engine.chunk_wait"]
+        assert {s["thread"] for s in idling} <= {"dispatch-staged-device"}
+        return [s for s in stages if s not in idling]
 
     @pytest.mark.parametrize("path", ["tiled", "direct", "serial"])
     def test_a_prediction_leaves_its_stages(self, path):
-        eng = self._engine(pipeline_depth=0 if path == "serial" else 2)
+        eng = self._engine()
         size = 40 if path == "direct" else 112
         x = np.random.rand(1, size, size, 1).astype(np.float32)
+        me = threading.current_thread().name
+        if path == "serial":
+            def predict(x):
+                return eng.predict_serial(x)
+        else:
+            def predict(x):
+                return eng.submit(eng.predict, x).result(timeout=60)
         try:
-            eng.submit(eng.predict, x).result()  # compile outside the count
+            predict(x)  # compile outside the count
             before, since = self._sums(eng), time.time_ns()
-            out = eng.submit(eng.predict, x).result()
+            out = predict(x)
             after = self._sums(eng)
         finally:
             eng.close()
         np.testing.assert_allclose(out, x * 1.7 + 0.25, rtol=1e-4, atol=1e-5)
-        stages = self._stages_of(eng, since)
+        stages = self._stages_of(eng, since, also=(me,))
         by_name = {}
         for s in stages:
             by_name.setdefault(s["name"], []).append(s)
-        assert set(by_name) == set(self.ON_DISPATCH) | {
+        streamed = path != "serial"
+        assert set(by_name) == set(self.ON_DEVICE) | {
             "engine.cut", "engine.stitch"
-        }
-        (request,) = by_name["engine.request"]
-        (queue,) = by_name["engine.queue"]
-        assert request["request_seq"] > 0
-        for s in stages:
-            assert s["end_ns"] >= s["start_ns"]
-            assert s["request_seq"] == request["request_seq"]
-            if s is not queue:  # the wait ends where the request begins
-                assert request["start_ns"] <= s["start_ns"]
-                assert s["end_ns"] <= request["end_ns"]
-        assert queue["end_ns"] <= request["start_ns"]
-        for name in self.ON_DISPATCH:
-            assert {s["thread"] for s in by_name[name]} == {"dispatch-staged_0"}
-        helpers = path == "tiled"
+        } | (set(self.ON_REQUEST) if streamed else set())
+        if streamed:
+            (request,) = by_name["engine.request"]
+            (queue,) = by_name["engine.queue"]
+            assert request["request_seq"] > 0
+            for s in stages:
+                assert s["end_ns"] >= s["start_ns"]
+                assert s["request_seq"] == request["request_seq"]
+                if s is not queue:  # the wait ends where the request begins
+                    assert request["start_ns"] <= s["start_ns"]
+                    assert s["end_ns"] <= request["end_ns"]
+            assert queue["end_ns"] <= request["start_ns"]
+            for name in self.ON_REQUEST:
+                assert {s["thread"] for s in by_name[name]} == {
+                    "dispatch-staged_0"
+                }
+            assert after["requests"] - before["requests"] == 1
+        device = "dispatch-staged-device" if streamed else me
+        for name in self.ON_DEVICE:
+            assert {s["thread"] for s in by_name[name]} == {device}
+        # a tiled request's tiles are cut and blended beside the device
+        # (its own thread divides); a direct batch is filled and cropped
+        # where it runs
         assert {s["thread"] for s in by_name["engine.cut"]} == {
-            "pipeline-cut" if helpers else "dispatch-staged_0"
+            "pipeline-cut" if path == "tiled" else device
         }
-        assert {s["thread"] for s in by_name["engine.stitch"]} == {
-            "pipeline-stitch" if helpers else "dispatch-staged_0"
-        }
+        assert {s["thread"] for s in by_name["engine.stitch"]} == (
+            {"pipeline-stitch", "dispatch-staged_0"} if path == "tiled"
+            else {device}
+        )
         # one measurement: the timeline's durations ARE the sums' deltas
         for name, field in self.SUMMED.items():
-            assert sum(s["duration_s"] for s in by_name[name]) == pytest.approx(
-                after[field] - before[field], abs=1e-9
-            ), name
+            assert sum(
+                s["duration_s"] for s in by_name.get(name, ())
+            ) == pytest.approx(after[field] - before[field], abs=1e-9), name
         assert after["readback_seconds"] - before["readback_seconds"] == (
             pytest.approx(
                 after["device_wait_seconds"] - before["device_wait_seconds"]
                 + after["d2h_seconds"] - before["d2h_seconds"], abs=1e-9,
             )
         )
-        assert after["requests"] - before["requests"] == 1
         assert after["items"] - before["items"] == 1
         assert after["chunks"] - before["chunks"] == len(by_name["engine.put"])
-        assert after["runs"] - before["runs"] == (1 if path == "tiled" else 0)
+        assert after["chunks_shared"] == 0
         (put,) = by_name["engine.put"]
         assert put["attrs"]["bytes"] == after["h2d_bytes"] - before["h2d_bytes"]
+
+    def test_requests_in_hand_overlap(self):
+        """Two requests in one stream: their ``engine.request`` stages
+        overlap, the chunk they share is recorded once (under its first
+        request), and each keeps its own cut and stitch stages."""
+        eng = self._engine(cls=_GatedEngine)
+        images = [np.random.rand(1, 112, 112, 1).astype(np.float32) for _ in "ab"]
+        plug = np.random.rand(1, 176, 176, 1).astype(np.float32)
+        try:
+            eng.predict(images[0])  # compile
+            futures = eng.served_together(images, plug=plug)
+            since = time.time_ns()  # the plug's chunks are on the device
+            for f in futures:
+                f.result(timeout=60)
+        finally:
+            eng.gate.set()
+            eng.close()
+        stages = self._stages_of(eng, since)
+        plugged, first, second = sorted(
+            (s for s in stages if s["name"] == "engine.request"),
+            key=lambda s: s["start_ns"],
+        )
+        assert first["request_seq"] != second["request_seq"]
+        assert second["start_ns"] < first["end_ns"]  # both in hand at once
+        assert {first["thread"], second["thread"]} == {
+            "dispatch-staged_1", "dispatch-staged_2"
+        }
+        # 9 + 7 rows in one chunk, 2 in the next: two program calls
+        for name in ("engine.put", "engine.dispatch"):
+            calls = [s for s in stages if s["name"] == name]
+            assert [s["request_seq"] for s in calls] == [
+                first["request_seq"], second["request_seq"]
+            ]
+            assert {s["thread"] for s in calls} == {"dispatch-staged-device"}
+        blends = [
+            s for s in stages
+            if s["name"] == "engine.stitch" and s["thread"] == "pipeline-stitch"
+            and s["request_seq"] != plugged["request_seq"]
+        ]
+        assert [s["request_seq"] for s in blends] == [
+            first["request_seq"], second["request_seq"], second["request_seq"]
+        ]
+        assert eng.pipeline_stats.chunks_shared == 1
 
     def test_nine_tiles_in_a_chunk_of_sixteen(self):
         eng = self._engine()
@@ -856,7 +1272,10 @@ class TestEngineStages:
         assert (stats.rows_useful, stats.rows_executed) == (9, 16)
         assert stats.d2h_bytes == stats.h2d_bytes == 16 * 64 * 64 * 4
 
-    def test_engine_queue_counts_two_tasks_submitted_at_once(self):
+    def test_engine_queue_counts_the_wait_for_a_request_thread(self, monkeypatch):
+        from bioengine_tpu.runtime import pipeline
+
+        monkeypatch.setattr(pipeline, "REQUEST_THREADS", 1)
         eng = self._engine()
         since = time.time_ns()
         try:
@@ -870,13 +1289,29 @@ class TestEngineStages:
         ]
         assert len(queues) == 2
         assert queues[0]["request_seq"] != queues[1]["request_seq"]
-        # the second task waited for the dispatch thread while the first ran
+        # the second task waited for the one request thread while the
+        # first ran
         assert queues[1]["duration_s"] >= 0.04
         stats = eng.pipeline_stats
         assert stats.requests == 2
         assert stats.queue_seconds == pytest.approx(
             sum(s["duration_s"] for s in queues), abs=1e-9
         )
+
+    def test_requests_do_not_wait_for_each_other_to_be_taken_up(self):
+        eng = self._engine()
+        since = time.time_ns()
+        try:
+            slow = eng.submit(time.sleep, 0.2)
+            fast = eng.submit(lambda: threading.current_thread().name)
+            assert fast.result(timeout=0.15) == "dispatch-staged_1"
+            slow.result()
+        finally:
+            eng.close()
+        queues = [
+            s for s in self._stages_of(eng, since) if s["name"] == "engine.queue"
+        ]
+        assert max(s["duration_s"] for s in queues) < 0.1
 
     def test_timeline_is_bounded(self):
         now = time.time_ns()

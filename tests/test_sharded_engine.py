@@ -128,10 +128,90 @@ class TestMeshParity:
             e1.close()
             e4.close()
 
-    def test_dp4_serial_tiled_matches_too(self, unet, images):
-        cfg = EngineConfig(
-            max_tile=64, tile=48, tile_overlap=8, pipeline_depth=0
+    def test_dp4_requests_served_together_reply_like_serial(self, unet):
+        """On a dp mesh the stream's chunks stay dp-divisible (every row
+        count it may choose came off the batch ladder with the same dp),
+        and requests that share them still reply bit-identically to the
+        serial path on the same mesh; their bills sum to the device
+        time x mesh width."""
+        import threading
+        import time
+
+        from bioengine_tpu.utils import tracing
+
+        cfg = EngineConfig(max_tile=64, tile=48, tile_overlap=8, tile_batch=8)
+        e4 = _make_engine(unet, jax.devices()[:4], config=cfg)
+        rng = np.random.default_rng(3)
+        # 4, 6 and 9 tiles: alone 4 | 8 (6 padded) | 8 + 4 (1 padded)
+        inputs = [
+            rng.standard_normal((1, h, w, 1)).astype(np.float32)
+            for h, w in ((70, 70), (70, 100), (100, 100), (70, 70))
+        ]
+        plug = rng.standard_normal((1, 100, 100, 1)).astype(np.float32)
+        gate = threading.Event()
+        gate.set()
+        sound = e4._stream._force
+
+        def held(flight):  # what is dispatched stays in flight while it is shut
+            gate.wait(30)
+            return sound(flight)
+
+        e4._stream._force = held
+        accounts = []
+
+        def accounted(x):
+            acc, token = tracing.start_chip_accounting()
+            try:
+                return e4.predict(x)
+            finally:
+                tracing.stop_chip_accounting(token)
+                accounts.append(acc)
+
+        def wait_for(condition):
+            deadline = time.monotonic() + 60
+            while not condition():
+                assert time.monotonic() < deadline
+                time.sleep(0.002)
+
+        try:
+            serial = [e4.predict_serial(x) for x in inputs]
+            e4.predict(plug)  # compile outside the bills
+            busy = e4.pipeline_stats.compute_seconds
+            issued = e4.pipeline_stats.chunks
+            gate.clear()
+            # the plug's two chunks hold the window: the four requests
+            # enrol behind them, and which rows share which chunk is
+            # settled before another chunk can go
+            futures = [e4.submit(accounted, plug)]
+            wait_for(lambda: e4.pipeline_stats.chunks - issued == 2)
+            before = e4.pipeline_stats.as_dict()
+            for x in inputs:
+                futures.append(e4.submit(accounted, x))
+                wait_for(lambda: len(e4._stream._pending) == len(futures))
+            gate.set()
+            replies = [f.result(timeout=120) for f in futures][1:]
+            after = e4.pipeline_stats.as_dict()
+        finally:
+            gate.set()
+            e4.close()
+        for reply, want in zip(replies, serial):
+            np.testing.assert_allclose(reply, want, rtol=0, atol=0)
+        # 4 (alone it runs 4 rows: its chunk is full) | 6 + 2 | 7 + 1 |
+        # 3 -> 4: each chunk at a dp multiple off the ladder
+        assert after["chunks_shared"] - before["chunks_shared"] == 2
+        assert after["rows_useful"] - before["rows_useful"] == 23
+        assert after["rows_executed"] - before["rows_executed"] == 24
+        batch_dims = {
+            key[1] for key in e4.cache._programs
+            if key[-1].split("@")[0] == "dp4"
+        }
+        assert batch_dims <= {4, 8}
+        assert sum(acc.seconds for acc in accounts) == pytest.approx(
+            4 * (e4.pipeline_stats.compute_seconds - busy), rel=1e-6
         )
+
+    def test_dp4_serial_tiled_matches_too(self, unet, images):
+        cfg = EngineConfig(max_tile=64, tile=48, tile_overlap=8)
         e4 = _make_engine(unet, jax.devices()[:4], config=cfg)
         e1 = _make_engine(unet, jax.devices()[:1], config=cfg)
         try:
