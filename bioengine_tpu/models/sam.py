@@ -35,9 +35,21 @@ of the served step's device time, the score-size bias before them
 60-65 %; PERF.md section 6, PRs 31 and 27). Windows, other grids and
 other backends unpack to ``(B, heads, N, .)`` operands inside
 ``ops.attention``: the fused kernel on a TPU, its plain XLA reference
-elsewhere. Window partition is a reshape (no data movement beyond
-layout). Shapes are static per (H, W) bucket as everywhere else in the
-framework.
+elsewhere. A block's MLP half, ``x + lin2(gelu(lin1(norm2(x))))``, is
+one call of ``ops.mlp.mlp`` with the ``mlp_lin1`` / ``mlp_lin2``
+parameters: exact GELU in its erf form (what torch's ``nn.GELU()``
+states), evaluated once an element on the f32 accumulator; on a TPU,
+where the shapes have tiles (dim and hidden whole lane widths, the rows
+a multiple of a row tile: every cpsam width), one Pallas kernel that
+keeps the ``(rows, hidden)`` activation in VMEM, the plain XLA
+reference elsewhere. Written as two ``nn.Dense`` around
+``nn.gelu(approximate=False)``, XLA put jax's erfc form of the same
+function, a 72-operation chain with an ``exponential``, into the
+operand prologue of the second matmul: a fifth of the served step
+(PERF.md section 6, PR 36). Window partition is a reshape (no data
+movement beyond layout); a windowed block un-partitions before its MLP,
+which therefore sees ``(B, H, W, dim)`` like a global block's. Shapes
+are static per (H, W) bucket as everywhere else in the framework.
 """
 
 from __future__ import annotations
@@ -49,6 +61,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from bioengine_tpu.ops.attention import packed_attention
+from bioengine_tpu.ops.mlp import mlp
 
 
 def _resize_rel_pos(rel_pos: jnp.ndarray, needed: int) -> jnp.ndarray:
@@ -135,6 +148,27 @@ def _window_unpartition(x, ws: int, padded, orig):
     return x[:, :H, :W]
 
 
+class _DenseParams(nn.Module):
+    """An ``nn.Dense``'s parameters without its product: the same
+    names, shapes, dtype and initialisers under the same scope, so the
+    same tree and, for a seed, the same values. ``ops.mlp.mlp`` does the
+    multiplying."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, fan_in: int):
+        kernel = self.param(
+            "kernel", nn.linear.default_kernel_init,
+            (fan_in, self.features), jnp.float32,
+        )
+        bias = self.param(
+            "bias", nn.initializers.zeros_init(), (self.features,),
+            jnp.float32,
+        )
+        return kernel, bias
+
+
 class SAMBlock(nn.Module):
     dim: int
     num_heads: int
@@ -167,13 +201,12 @@ class SAMBlock(nn.Module):
         y = nn.LayerNorm(dtype=jnp.float32, name="norm2")(x).astype(
             self.dtype
         )
-        y = nn.Dense(
-            int(self.dim * self.mlp_ratio), dtype=self.dtype,
-            name="mlp_lin1",
-        )(y)
-        y = nn.gelu(y, approximate=False)
-        y = nn.Dense(self.dim, dtype=self.dtype, name="mlp_lin2")(y)
-        return x + y
+        hidden = int(self.dim * self.mlp_ratio)
+        w1, b1 = _DenseParams(hidden, name="mlp_lin1")(self.dim)
+        w2, b2 = _DenseParams(self.dim, name="mlp_lin2")(hidden)
+        # x + lin2(gelu(lin1(y))), exact GELU: one call, and which
+        # program runs it is read off the shapes there
+        return mlp(y, w1, b1, w2, b2, x)
 
 
 class SAMEncoder(nn.Module):

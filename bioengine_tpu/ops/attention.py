@@ -188,14 +188,18 @@ def packed_attention(
     return unpacked_attention(attention, qkv, rel_h, rel_w, grid, heads)
 
 
-def traced_paths(since: Optional[dict[str, int]] = None) -> dict[str, int]:
-    """``{"fused:1024": 24, ...}``: the counter's series as one dict, or
-    with ``since`` (an earlier reading) only what rose, by how much: the
-    attention calls of whatever was traced in between."""
-    now = {
-        f"{path}:{tokens}": int(child.value)
-        for (path, tokens), child in ATTENTION_TRACED.items()
-    }
+def counted(counter, since: Optional[dict[str, int]] = None) -> dict[str, int]:
+    """A two-label counter's series as one dict, ``{"<a>:<b>": n}``, or
+    with ``since`` (an earlier reading) only what rose, by how much:
+    the calls of whatever was traced in between."""
+    now = {f"{a}:{b}": int(child.value) for (a, b), child in counter.items()}
     if since is None:
         return now
     return {k: n - since.get(k, 0) for k, n in now.items() if n > since.get(k, 0)}
+
+
+def traced_paths(since: Optional[dict[str, int]] = None) -> dict[str, int]:
+    """``{"fused:1024": 24, ...}``: ``attention_traced_total``'s series
+    as one dict, or with ``since`` (an earlier reading) only what rose:
+    the attention calls of whatever was traced in between."""
+    return counted(ATTENTION_TRACED, since)

@@ -281,10 +281,10 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
     )
-    return _per_device(run, "flash_attention", q, k, v)
+    return per_device(run, "flash_attention", q, k, v)
 
 
-def _per_device(run, name, *operands, replicated=0):
+def per_device(run, name, *operands, replicated=0):
     """``run(*operands)``, per device. Mosaic calls cannot be partitioned
     automatically (lowering one inside a multi-device jit raises), so
     where the operands belong to a mesh (the engine's dp-sharded batch,
@@ -469,7 +469,7 @@ def _packed_attention(qkv, rel_h, rel_w, grid, heads, interpret):
     run = functools.partial(
         _packed_forward, grid=grid, heads=heads, interpret=interpret
     )
-    return _per_device(
+    return per_device(
         run, "packed_flash_attention", qkv, rel_h, rel_w, replicated=2
     )
 

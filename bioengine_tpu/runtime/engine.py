@@ -477,6 +477,8 @@ class InferenceEngine:
                 "attention_paths": {
                     k: v["attention_paths"] for k, v in mine.items()
                 },
+                # and the block MLPs, by path and rows: {"fused:16384": 24}
+                "mlp_paths": {k: v["mlp_paths"] for k, v in mine.items()},
                 "persistent_hits": sum(
                     1 for v in mine.values() if v["cache_hit"]
                 ),

@@ -18,7 +18,7 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Hashable, Optional
 
-from bioengine_tpu.ops.attention import traced_paths
+from bioengine_tpu.ops import attention as _attention, mlp as _mlp
 from bioengine_tpu.utils import flight, metrics
 from bioengine_tpu.utils import compile_cache as _compile_cache
 
@@ -60,8 +60,19 @@ class CacheStats:
     # rise over build()), same lifecycle. A served cpsam program that
     # reads ``xla`` on a TPU is running without its kernel.
     attention_paths: dict = field(default_factory=dict)
+    # the same for the block MLPs (``{"fused:16384": 24}``:
+    # mlp_traced_total's rise over build()), same lifecycle
+    mlp_paths: dict = field(default_factory=dict)
     # lifetime total, survives evictions
     cumulative_compile_seconds: float = 0.0
+
+    def forget(self, key: str) -> None:
+        """Drop an evicted program's per-key entries."""
+        for per_key in (
+            self.compile_seconds, self.cache_hit,
+            self.attention_paths, self.mlp_paths,
+        ):
+            per_key.pop(key, None)
 
     def as_dict(self) -> dict:
         total = self.hits + self.misses
@@ -164,14 +175,15 @@ class CompiledProgramCache:
                 if cache_dir
                 else None
             )
-            traced_before = traced_paths()
+            traced_before = (_attention.traced_paths(), _mlp.traced_paths())
             t0 = time.perf_counter()
             program = build()
             dt = time.perf_counter() - t0
             # another thread tracing a model meanwhile would be counted
             # here too; builds are rare enough for that to be an anomaly
             # worth seeing, not one worth a lock around tracing
-            attention = traced_paths(since=traced_before)
+            attention = _attention.traced_paths(since=traced_before[0])
+            mlps = _mlp.traced_paths(since=traced_before[1])
             # Tag disk/tier hits apart from real compiles. Primary
             # signal: a REAL compile persists a new cache entry while a
             # hit writes nothing (wall time alone can't separate them —
@@ -194,14 +206,13 @@ class CompiledProgramCache:
                 self.stats.compile_seconds[str(key)] = dt
                 self.stats.cache_hit[str(key)] = cache_hit
                 self.stats.attention_paths[str(key)] = attention
+                self.stats.mlp_paths[str(key)] = mlps
                 self.stats.cumulative_compile_seconds += dt
                 self._programs[key] = program
                 self._programs.move_to_end(key)
                 while len(self._programs) > self.max_programs:
                     victim, _ = self._programs.popitem(last=False)
-                    self.stats.compile_seconds.pop(str(victim), None)
-                    self.stats.cache_hit.pop(str(victim), None)
-                    self.stats.attention_paths.pop(str(victim), None)
+                    self.stats.forget(str(victim))
                     self.stats.evictions += 1
                     evicted.append(victim)
             flight.record(
@@ -210,6 +221,7 @@ class CompiledProgramCache:
                 seconds=round(dt, 3),
                 cache_hit=cache_hit,
                 attention_paths=attention,
+                mlp_paths=mlps,
             )
             for victim in evicted:
                 flight.record("program.evict", key=str(victim))
@@ -227,9 +239,10 @@ class CompiledProgramCache:
 
     def compile_info_snapshot(self) -> dict:
         """Per-key ``{"seconds": s, "cache_hit": bool, "attention_paths":
-        {...}}`` under the cache lock — the describe() view that tells a
-        tier/disk hit apart from a real compile, and a program with the
-        fused attention kernel from one without."""
+        {...}, "mlp_paths": {...}}`` under the cache lock — the
+        describe() view that tells a tier/disk hit apart from a real
+        compile, and a program with its fused kernels from one
+        without."""
         with self._lock:
             return {
                 k: {
@@ -238,6 +251,7 @@ class CompiledProgramCache:
                     "attention_paths": dict(
                         self.stats.attention_paths.get(k, {})
                     ),
+                    "mlp_paths": dict(self.stats.mlp_paths.get(k, {})),
                 }
                 for k, v in self.stats.compile_seconds.items()
             }
@@ -262,9 +276,7 @@ class CompiledProgramCache:
             victims = [k for k in self._programs if predicate(k)]
             for k in victims:
                 del self._programs[k]
-                self.stats.compile_seconds.pop(str(k), None)
-                self.stats.cache_hit.pop(str(k), None)
-                self.stats.attention_paths.pop(str(k), None)
+                self.stats.forget(str(k))
             self.stats.evictions += len(victims)
         for k in victims:
             flight.record("program.evict", key=str(k))

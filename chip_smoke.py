@@ -22,7 +22,8 @@ seconds and the ``device_kind`` it ran on:
   kernel   the Pallas attention kernel compiled by Mosaic at the
            ViT-B/14@448 and cpsam shapes (plain depth, the folded
            128/64 depth, and the packed call of the served program
-           over the qkv projection's layout), forward and gradient
+           over the qkv projection's layout) and the MLP kernel at the
+           served program's shape, forward and gradient
   stop     worker.stop(); no thread may outlive it
 
 Nothing is caught and continued: the first failed check raises, the
@@ -79,6 +80,9 @@ KERNEL_SHAPES = (
 # (B, heads, (H, W)) at 64-wide heads: what the served cpsam program runs
 # since PR 31, the packed call over the qkv projection's own layout
 PACKED_SHAPES = ((16, 16, (32, 32)),)
+# (shape of the tokens, hidden): the MLP half of a block of the served
+# cpsam program since PR 36, one kernel with the hidden activation in VMEM
+MLP_SHAPES = (((16, 32, 32, 1024), 4096),)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,6 +100,7 @@ class SmokeConfig:
     parity_with: Optional[Path] = None
     kernel_shapes: tuple = KERNEL_SHAPES
     packed_shapes: tuple = PACKED_SHAPES
+    mlp_shapes: tuple = MLP_SHAPES
 
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -640,10 +645,12 @@ def kernel(cfg: SmokeConfig, report: Report) -> None:
         reference_attention,
         unpacked_attention,
     )
+    from bioengine_tpu.ops.mlp import reference_mlp
     from bioengine_tpu.ops.pallas.attention import (
         flash_attention,
         packed_flash_attention,
     )
+    from bioengine_tpu.ops.pallas.mlp import fused_mlp
 
     on_chip = cfg.platform == "tpu"  # the rehearsal interprets the kernel
 
@@ -662,10 +669,10 @@ def kernel(cfg: SmokeConfig, report: Report) -> None:
 
         return jax.jit(jax.grad(total, argnums=(0, 1, 2)))
 
-    def packed_grads(fn):
+    def packed_grads(fn, argnums=(0, 1, 2)):
         return jax.jit(
             jax.grad(
-                lambda *a: fn(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+                lambda *a: fn(*a).astype(jnp.float32).sum(), argnums=argnums
             )
         )
 
@@ -739,6 +746,42 @@ def kernel(cfg: SmokeConfig, report: Report) -> None:
                     packed_grads(packed)(*operands),
                     packed_grads(plain)(*operands),
                     ("qkv", "rel_h", "rel_w"),
+                )
+            )
+            errors[tag] = [round(fwd, 5), round(grad, 5)]
+        for shape, hidden in cfg.mlp_shapes:
+            dim = shape[-1]
+            tag = f"mlp-{'x'.join(map(str, shape))}/{hidden}"
+            # y, w1, b1, w2, b2, shortcut: bf16 tokens, the f32 layers
+            # of the parameter tree
+            operands = jax.jit(
+                lambda: tuple(
+                    (scale * jax.random.normal(key, shape_, jnp.float32)).astype(dtype)
+                    for key, scale, shape_, dtype in zip(
+                        jax.random.split(jax.random.key(2), 6),
+                        (1.0, dim**-0.5, 0.5, hidden**-0.5, 0.5, 1.0),
+                        (shape, (dim, hidden), (hidden,), (hidden, dim), (dim,), shape),
+                        (jnp.bfloat16,) + (jnp.float32,) * 4 + (jnp.bfloat16,),
+                    )
+                )
+            )()
+            fused = functools.partial(fused_mlp, interpret=not on_chip)
+            lowered = fused_mlp.lower(*operands, interpret=not on_chip)
+            check(
+                not on_chip or "tpu_custom_call" in lowered.as_text(),
+                f"kernel {tag}: lowered module holds no Mosaic custom call",
+            )
+            fwd = assert_close(
+                fused(*operands), jax.jit(reference_mlp)(*operands),
+                f"kernel {tag} forward",
+            )
+
+            grad = max(
+                assert_close(g, w, f"kernel {tag} d{name}")
+                for g, w, name in zip(
+                    packed_grads(fused, tuple(range(6)))(*operands),
+                    packed_grads(reference_mlp, tuple(range(6)))(*operands),
+                    ("y", "w1", "b1", "w2", "b2", "shortcut"),
                 )
             )
             errors[tag] = [round(fwd, 5), round(grad, 5)]

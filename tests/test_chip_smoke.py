@@ -59,13 +59,15 @@ def test_kernel_phase_at_toy_size(tmp_path, capsys):
     """The kernel phase with the kernel interpreted: a plain shape whose
     N is no multiple of the block, and the folded form the served cpsam
     program ran (q/k depth != v depth, scale 1), forward and gradient,
-    both also causal; and the packed call it runs now, on a grid of
-    unequal extents."""
+    both also causal; the packed call it runs now, on a grid of
+    unequal extents; and the MLP kernel over three row tiles and three
+    hidden blocks."""
     cfg = chip_smoke.SmokeConfig(
         platform="cpu",
         out_dir=tmp_path,
         kernel_shapes=((1, 2, 100, 32, 32, None), (2, 2, 64, 48, 16, 1.0)),
         packed_shapes=((1, 2, (16, 48)),),
+        mlp_shapes=(((3, 8, 16, 128), 384),),
     )
     report = chip_smoke.Report()
     try:
@@ -78,5 +80,8 @@ def test_kernel_phase_at_toy_size(tmp_path, capsys):
         if line.startswith("[chip_smoke] kernel")
     ]
     assert " ok " in line
-    for tag in ("1x2x100x32/32", "2x2x64x48/16-causal", "packed-1x2x16x48"):
+    for tag in (
+        "1x2x100x32/32", "2x2x64x48/16-causal", "packed-1x2x16x48",
+        "mlp-3x8x16x128/384",
+    ):
         assert tag in line

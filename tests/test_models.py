@@ -298,6 +298,13 @@ def test_cpsam_checkpoint_conversion_matches_model_tree():
     np.testing.assert_array_equal(
         got["encoder/pos_embed"], sd["encoder.pos_embed"]
     )
+    np.testing.assert_array_equal(
+        got["encoder/block1/mlp_lin1/kernel"],
+        sd["encoder.blocks.1.mlp.lin1.weight"].T,
+    )
+    np.testing.assert_array_equal(
+        got["encoder/block1/mlp_lin2/bias"], sd["encoder.blocks.1.mlp.lin2.bias"]
+    )
 
     # converted params drive a real forward
     y = model.apply({"params": params}, jnp.ones((1, 32, 32, 3)) * 0.1)
@@ -425,6 +432,168 @@ def _sam_attention_5d(params, x, num_heads):
     out = (attn @ v).reshape(B, num_heads, H * W, hd)
     out = jnp.moveaxis(out, 1, 2).reshape(B, H, W, dim)
     return out @ params["proj"]["kernel"] + params["proj"]["bias"]
+
+
+def _sam_block_plain(params, x, num_heads):
+    """One global ``SAMBlock`` as segment-anything states it: two
+    pre-norm residual halves, the attention through the 5-D formulation
+    above, the MLP as ``lin2(GELU(lin1(.)))`` with jax's own exact GELU
+    (the erfc form). Shares no code with ``ops.mlp``."""
+
+    def norm(p, t):
+        mean = t.mean(-1, keepdims=True)
+        var = ((t - mean) ** 2).mean(-1, keepdims=True)
+        return (t - mean) / jnp.sqrt(var + 1e-6) * p["scale"] + p["bias"]
+
+    x = x + _sam_attention_5d(params["attn"], norm(params["norm1"], x), num_heads)
+    h = norm(params["norm2"], x) @ params["mlp_lin1"]["kernel"]
+    h = jax.nn.gelu(h + params["mlp_lin1"]["bias"], approximate=False)
+    return x + h @ params["mlp_lin2"]["kernel"] + params["mlp_lin2"]["bias"]
+
+
+class TestSamBlockMlp:
+    """``SAMBlock`` hands its MLP half to ``ops.mlp.mlp``: the block's
+    output, its parameter tree and which program ran are held here."""
+
+    DIM, HEADS, GRID = 128, 2, (8, 16)
+
+    @pytest.fixture(params=["cpu", "tpu-pretended"])
+    def backend(self, request, monkeypatch):
+        """The CPU the suite names, or ``jax.default_backend()`` saying
+        "tpu" with every kernel interpreted: at this width the MLP
+        kernel engages (256 rows, dim 128, hidden 512)."""
+        if request.param == "cpu":
+            return
+        from bioengine_tpu.ops.pallas import attention, mlp
+
+        for module, name in (
+            (attention, "flash_attention"),
+            (attention, "packed_flash_attention"),
+            (mlp, "fused_mlp"),
+        ):
+            monkeypatch.setattr(
+                module, name,
+                functools.partial(getattr(module, name), interpret=True),
+            )
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def _setup(self, dtype=jnp.float32):
+        from bioengine_tpu.models.sam import SAMBlock
+
+        rng = np.random.default_rng(7)
+        x = jnp.asarray(rng.normal(size=(2, *self.GRID, self.DIM)), dtype)
+        block = SAMBlock(self.DIM, self.HEADS, 4.0, 0, 16, dtype)
+        params = block.init(jax.random.key(0), x)["params"]
+        # biases, scales and tables initialise to constants: give every
+        # one something to get wrong
+        params = jax.tree.map(
+            lambda a: a + jnp.asarray(0.3 * rng.normal(size=a.shape), a.dtype)
+            if a.ndim == 1 or a.shape[0] == 31 else a,
+            params,
+        )
+        return block, params, x
+
+    def test_forward_equals_the_plain_block(self, backend):
+        block, params, x = self._setup()
+        got = block.apply({"params": params}, x)
+        want = _sam_block_plain(params, x, self.HEADS)
+        assert float(jnp.abs(want - x).max()) > 1.0
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-5)
+
+    def test_gradients_equal_the_plain_block_s(self, backend):
+        block, params, x = self._setup()
+        weights = jnp.asarray(
+            np.random.default_rng(4).normal(size=x.shape), jnp.float32
+        )
+        got = jax.grad(
+            lambda p: jnp.sum(block.apply({"params": p}, x) * weights)
+        )(params)
+        want = jax.grad(
+            lambda p: jnp.sum(_sam_block_plain(p, x, self.HEADS) * weights)
+        )(params)
+        for layer in ("mlp_lin1", "mlp_lin2", "norm2"):
+            for leaf, w in want[layer].items():
+                assert float(jnp.abs(w).max()) > 1e-3, (layer, leaf)
+                np.testing.assert_allclose(
+                    got[layer][leaf], w, atol=2e-4, rtol=1e-4,
+                    err_msg=f"{layer}.{leaf}",
+                )
+
+    def test_bf16_block_stays_within_bf16_of_the_plain_f32_block(self, backend):
+        block, params, x = self._setup(jnp.bfloat16)
+        got = block.apply({"params": params}, x)
+        assert got.dtype == jnp.bfloat16
+        want = _sam_block_plain(params, x.astype(jnp.float32), self.HEADS)
+        np.testing.assert_allclose(
+            got.astype(np.float32), want, atol=2.0**-4 * float(jnp.abs(want).max())
+        )
+
+    def test_parameter_tree_is_the_dense_layers(self):
+        """Names, shapes, dtypes and, for a seed, values of the two
+        ``nn.Dense`` layers the block held before it called ``ops.mlp``:
+        converted cpsam checkpoints load as before, and a run from a
+        seed starts from the same weights."""
+        from flax import linen as nn
+
+        class TwoDense(nn.Module):
+            @nn.compact
+            def __call__(self, y):
+                y = nn.Dense(4 * TestSamBlockMlp.DIM, name="mlp_lin1")(y)
+                return nn.Dense(TestSamBlockMlp.DIM, name="mlp_lin2")(y)
+
+        from bioengine_tpu.models.sam import SAMBlock
+
+        x = jnp.zeros((1, *self.GRID, self.DIM))
+        block = SAMBlock(self.DIM, self.HEADS, 4.0, 0, 16)
+        params = block.init(jax.random.key(5), x)["params"]
+        assert sorted(params) == [
+            "attn", "mlp_lin1", "mlp_lin2", "norm1", "norm2",
+        ]
+        dense = TwoDense().init(jax.random.key(5), x)["params"]
+        for layer in ("mlp_lin1", "mlp_lin2"):
+            assert sorted(params[layer]) == ["bias", "kernel"]
+            for leaf in ("kernel", "bias"):
+                assert params[layer][leaf].dtype == jnp.float32
+                # flax derives a parameter's key from its path: the same
+                # names under the same scope draw the same values
+                np.testing.assert_array_equal(
+                    params[layer][leaf], dense[layer][leaf]
+                )
+        assert float(jnp.std(params["mlp_lin1"]["kernel"])) > 0.05
+
+    def test_counter_reads_the_path_the_backend_dictates(self):
+        from bioengine_tpu.ops import mlp as mlp_ops
+
+        block, params, x = self._setup()
+        before = mlp_ops.traced_paths()
+        jax.jit(block.apply)({"params": params}, x)
+        assert mlp_ops.traced_paths(since=before) == {"xla:256": 1}
+
+    @pytest.mark.parametrize(
+        "dim,grid,window,path",
+        [
+            (128, (8, 16), 0, "fused:256"),
+            # a windowed block un-partitions before its MLP: the MLP sees
+            # the whole grid, as in a global block
+            (128, (16, 16), 4, "fused:512"),
+            (32, (8, 16), 0, "xla:256"),      # tier-1's toy widths
+            (128, (10, 10), 0, "xla:200"),    # rows no tile divides
+        ],
+    )
+    def test_on_a_tpu_the_shape_decides_the_path(
+        self, dim, grid, window, path, monkeypatch
+    ):
+        from bioengine_tpu.models.sam import SAMBlock
+        from bioengine_tpu.ops import mlp as mlp_ops
+
+        block = SAMBlock(dim, 2, 4.0, window, window or 16)
+        x = jax.ShapeDtypeStruct((2, *grid, dim), jnp.bfloat16)
+        params = jax.eval_shape(block.init, jax.random.key(0), x)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        before = mlp_ops.traced_paths()
+        out = jax.eval_shape(block.apply, params, x)
+        assert out.shape == x.shape and out.dtype == jnp.bfloat16
+        assert mlp_ops.traced_paths(since=before) == {path: 1}
 
 
 class TestFoldedRelPos:

@@ -5,6 +5,9 @@ Runs in interpreter mode because conftest names the CPU platform
 which chip_smoke.py checks on the chip.
 """
 
+import contextlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,7 +20,10 @@ from bioengine_tpu.ops.attention import (
     traced_paths,
     unpacked_attention,
 )
+from bioengine_tpu.ops import mlp as mlp_ops
+from bioengine_tpu.ops.mlp import gelu, mlp, reference_mlp
 from bioengine_tpu.ops.pallas import attention as kernel_module
+from bioengine_tpu.ops.pallas import mlp as mlp_kernel
 from bioengine_tpu.ops.pallas.attention import (
     _block_sizes,
     flash_attention,
@@ -25,6 +31,7 @@ from bioengine_tpu.ops.pallas.attention import (
     packed_flash_attention,
     packs,
 )
+from bioengine_tpu.ops.pallas.mlp import Tiles, fused_mlp, tiles
 
 
 def ref_attention(q, k, v, causal=False):
@@ -600,6 +607,332 @@ class TestPackedUnderGspmd:
             np.testing.assert_allclose(g, w, atol=1e-3, rtol=1e-4)
 
 
+def _mlp_operands(shape, hidden, dtype=jnp.float32, seed=17):
+    """What ``SAMBlock`` hands ``ops.mlp.mlp``: the normalised tokens,
+    the two layers as the parameter tree holds them (f32) and the
+    shortcut. Biases away from zero, so a dropped one is seen."""
+    dim = shape[-1]
+    rng = np.random.default_rng(seed)
+    return (
+        jnp.asarray(rng.normal(size=shape), dtype),
+        jnp.asarray(rng.normal(size=(dim, hidden)) * dim**-0.5, jnp.float32),
+        jnp.asarray(rng.normal(size=(hidden,)), jnp.float32),
+        jnp.asarray(rng.normal(size=(hidden, dim)) * hidden**-0.5, jnp.float32),
+        jnp.asarray(rng.normal(size=(dim,)), jnp.float32),
+        jnp.asarray(rng.normal(size=shape), dtype),
+    )
+
+
+MLP_OPERANDS = ("y", "w1", "b1", "w2", "b2", "shortcut")
+
+
+class TestGelu:
+    """``ops.mlp.gelu``: the one statement of the SAM block's exact GELU,
+    in its erf form, which the reference and the kernel both evaluate."""
+
+    @staticmethod
+    def _every_bf16():
+        bits = np.arange(65536, dtype=np.uint32) << 16
+        return bits.view(np.float32)
+
+    @pytest.mark.parametrize("jitted", [False, True])
+    def test_every_bf16_input_is_within_one_ulp_of_exact(self, jitted):
+        """All 65,536 bf16 values: the finite ones, rounded to bf16 as
+        the second product takes them, within one bf16 ulp of float64
+        exact GELU or 2**-20 absolute, whichever is larger; the far
+        negative tail is exactly -0."""
+        import math
+
+        x = self._every_bf16()
+        finite = np.isfinite(x)
+        fn = jax.jit(gelu) if jitted else gelu
+        got = fn(jnp.asarray(x).astype(jnp.bfloat16))
+        assert got.dtype == jnp.float32
+        got = np.asarray(
+            got.astype(jnp.bfloat16).astype(jnp.float32), np.float64
+        )[finite]
+        exact = np.array(
+            [0.5 * v * math.erfc(-v / math.sqrt(2)) for v in x[finite].astype(np.float64)]
+        )
+        with np.errstate(divide="ignore"):
+            ulp = np.where(
+                exact != 0, 2.0 ** (np.floor(np.log2(np.abs(exact))) - 7), 0
+            )
+        worst = np.abs(got - exact) / np.maximum(ulp, 2.0**-20)
+        assert worst.max() <= 1.0, x[finite][np.argmax(worst)]
+        far = x[finite] < -6
+        assert np.array_equal(got[far], np.zeros(far.sum()))
+        assert np.signbit(got[far]).all()
+
+    def test_non_finite_inputs_read_as_the_erfc_form_has_them(self):
+        """+inf -> +inf, -inf and NaN -> NaN, exactly what
+        ``nn.gelu(approximate=False)`` (0.5 x erfc(-x / sqrt 2)) gives."""
+        x = self._every_bf16()
+        odd = jnp.asarray(x[~np.isfinite(x)])
+        assert odd.shape == (256,)
+        np.testing.assert_array_equal(
+            gelu(odd), jax.nn.gelu(odd, approximate=False)
+        )
+
+    def test_equals_the_erfc_form_where_it_is_not_a_rounding(self):
+        """Against jax's own exact GELU in f32 over the range a network
+        visits: the same function, another way of writing it."""
+        x = jnp.linspace(-9.0, 9.0, 200001, dtype=jnp.float32)
+        np.testing.assert_allclose(
+            gelu(x), jax.nn.gelu(x, approximate=False), atol=1e-6, rtol=2e-6
+        )
+
+
+class TestFusedMlp:
+    """The MLP kernel, interpreted, against ``reference_mlp``. The f32
+    tolerance is the interpreter's: it evaluates the reciprocal estimate
+    in bf16, where the chip's is good to 1.6e-5 and the Newton step
+    takes it to 1.4e-7 (chip run of PR 36), so the kernel's erf is
+    within 2e-5 here and within an f32 ulp there."""
+
+    # shape of y, hidden -> the tiles in the comment
+    SHAPES = {
+        "one-tile": ((2, 64, 128), 256),            # (128, 256)
+        "three-row-tiles": ((3, 8, 16, 128), 384),  # (128, 128) x 3 x 3
+        "two-hidden-blocks": ((256, 256), 2048),    # (256, 1024) x 1 x 2
+    }
+
+    @pytest.mark.parametrize("case", list(SHAPES))
+    @pytest.mark.parametrize(
+        "dtype,atol", [(jnp.float32, 1e-4), (jnp.bfloat16, 6e-2)]
+    )
+    def test_matches_the_reference(self, case, dtype, atol):
+        shape, hidden = self.SHAPES[case]
+        operands = _mlp_operands(shape, hidden, dtype)
+        out = fused_mlp(*operands)
+        ref = reference_mlp(*operands)
+        assert out.shape == ref.shape == shape and out.dtype == dtype
+        assert float(jnp.abs(ref.astype(jnp.float32)).max()) > 3.0
+        np.testing.assert_allclose(
+            out.astype(np.float32), ref.astype(np.float32), atol=atol
+        )
+
+    def test_the_grid_is_what_the_case_says(self):
+        assert tiles(128, 128, 256, jnp.float32) == Tiles(128, 256)
+        assert tiles(384, 128, 384, jnp.float32) == Tiles(128, 128)
+        assert tiles(256, 256, 2048, jnp.float32) == Tiles(256, 1024)
+
+    @pytest.mark.parametrize("dropped", ["b1", "b2", "shortcut"])
+    def test_every_operand_is_seen(self, dropped):
+        """The comparison has teeth: with one bias or the shortcut
+        zeroed the reference moves far beyond the tolerance."""
+        operands = dict(zip(MLP_OPERANDS, _mlp_operands((2, 64, 128), 256)))
+        out = fused_mlp(*operands.values())
+        operands[dropped] = jnp.zeros_like(operands[dropped])
+        wrong = reference_mlp(*operands.values())
+        assert float(jnp.abs(out - wrong).max()) > 0.1
+
+    def test_gradients_are_the_reference_s(self):
+        operands = _mlp_operands((256, 128), 384)
+        weights = jnp.asarray(
+            np.random.default_rng(5).normal(size=(256, 128)), jnp.float32
+        )
+
+        def loss(fn):
+            return lambda *a: jnp.sum(fn(*a) * weights)
+
+        argnums = tuple(range(6))
+        got = jax.grad(loss(fused_mlp), argnums)(*operands)
+        want = jax.grad(loss(reference_mlp), argnums)(*operands)
+        for g, w, name in zip(got, want, MLP_OPERANDS):
+            assert g.shape == w.shape
+            assert float(jnp.abs(w).max()) > 1e-3, name
+            np.testing.assert_allclose(g, w, atol=1e-5, rtol=1e-5, err_msg=name)
+
+    def test_refuses_what_has_no_tiles(self):
+        operands = _mlp_operands((200, 128), 256)
+        with pytest.raises(ValueError, match="has no tiles"):
+            fused_mlp(*operands)
+
+    def test_interprets_only_under_explicit_cpu(self, cpu_not_asked_for):
+        from bioengine_tpu.utils.devices import NoAcceleratorError
+
+        operands = _mlp_operands((128, 256), 128)  # a shape no test traced
+        with pytest.raises(NoAcceleratorError, match="fused_mlp"):
+            fused_mlp(*operands)
+
+    # (rows, dim, hidden) -> tiles or None: whole lane widths, the rows a
+    # multiple of a row tile, the largest tiles that fit the VMEM asked for
+    @pytest.mark.parametrize(
+        "rows,dim,hidden,expected",
+        [
+            (16384, 1024, 4096, Tiles(1024, 1024)),  # the served program
+            (8192, 1024, 4096, Tiles(1024, 1024)),   # fine-tuning, batch 8
+            (1536, 1024, 4096, Tiles(512, 1024)),    # three row tiles
+            (1024, 768, 3072, Tiles(1024, 1024)),    # ViT-B widths
+            (1024, 1024, 4224, Tiles(1024, 128)),     # 33 lane widths
+            (16384, 1024, 4000, None),    # hidden off the lanes
+            (16384, 1000, 4096, None),    # dim off the lanes
+            (16384 + 64, 1024, 4096, None),  # rows no tile divides
+            (200, 128, 256, None),
+            (16384, 64, 256, None),       # a toy width
+        ],
+    )
+    def test_which_shapes_have_tiles(self, rows, dim, hidden, expected):
+        assert tiles(rows, dim, hidden, jnp.bfloat16) == expected
+        if expected is not None:
+            assert mlp_kernel._vmem_bytes(expected, dim, 2) <= mlp_kernel.VMEM_LIMIT
+
+
+class TestMlpDispatch:
+    """``ops.mlp.mlp``: the kernel where the backend is a TPU and the
+    shapes have tiles, the reference anywhere else; the counter says
+    which. Tracing alone counts, so most shapes are traced
+    (``eval_shape``), not run."""
+
+    @staticmethod
+    def _trace(shape, hidden, dtype=jnp.bfloat16):
+        dim = shape[-1]
+        shapes = (
+            jax.ShapeDtypeStruct(shape, dtype),
+            jax.ShapeDtypeStruct((dim, hidden), jnp.float32),
+            jax.ShapeDtypeStruct((hidden,), jnp.float32),
+            jax.ShapeDtypeStruct((hidden, dim), jnp.float32),
+            jax.ShapeDtypeStruct((dim,), jnp.float32),
+            jax.ShapeDtypeStruct(shape, dtype),
+        )
+        before = mlp_ops.traced_paths()
+        # a new function each time: ``eval_shape`` keeps its traces
+        out = jax.eval_shape(lambda *a: mlp(*a), *shapes)
+        assert out.shape == shape and out.dtype == dtype
+        return mlp_ops.traced_paths(since=before)
+
+    def test_cpu_takes_the_reference_and_counts_xla(self):
+        operands = _mlp_operands((2, 64, 128), 256)
+        before = mlp_ops.traced_paths()
+        out = mlp(*operands)
+        assert mlp_ops.traced_paths(since=before) == {"xla:128": 1}
+        np.testing.assert_array_equal(out, reference_mlp(*operands))
+        assert self._trace((16, 32, 32, 1024), 4096) == {"xla:16384": 1}
+
+    @pytest.mark.parametrize(
+        "shape,hidden,expected",
+        [
+            ((16, 32, 32, 1024), 4096, {"fused:16384": 1}),  # served
+            ((8, 32, 32, 1024), 4096, {"fused:8192": 1}),    # fine-tuning
+            ((2, 8, 8, 128), 512, {"fused:128": 1}),
+            ((16, 32, 32, 1024), 4000, {"xla:16384": 1}),    # hidden % 128
+            ((2, 10, 10, 128), 512, {"xla:200": 1}),         # no row tile
+            ((2, 32, 32, 32), 128, {"xla:2048": 1}),         # tier-1's widths
+        ],
+    )
+    def test_tpu_backend_fuses_where_the_shapes_have_tiles(
+        self, monkeypatch, shape, hidden, expected
+    ):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert self._trace(shape, hidden) == expected
+
+    def test_tpu_backend_runs_the_kernel(self, monkeypatch):
+        """The backend pretended, the kernel interpreted: the choice is
+        ``jax.default_backend()``'s and the shapes', nothing else's."""
+        calls = []
+
+        def interpreted(*operands, **kwargs):
+            calls.append(kwargs)
+            return fused_mlp(*operands, interpret=True, **kwargs)
+
+        operands = _mlp_operands((2, 64, 128), 256)
+        want = mlp(*operands)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(mlp_kernel, "fused_mlp", interpreted)
+        before = mlp_ops.traced_paths()
+        got = mlp(*operands)
+        assert mlp_ops.traced_paths(since=before) == {"fused:128": 1}
+        assert calls == [{}]
+        np.testing.assert_allclose(got, want, atol=1e-4)
+
+    def test_program_cache_keeps_the_rise_of_a_build(self, monkeypatch):
+        from bioengine_tpu.runtime.program_cache import CompiledProgramCache
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cache = CompiledProgramCache()
+
+        def build():
+            self._trace((16, 32, 32, 1024), 4096)
+            self._trace((16, 32, 32, 1024), 4096)
+            self._trace((2, 10, 10, 128), 512)
+
+        cache.get_or_compile(("three-blocks", 16384), build)
+        cache.get_or_compile(("none", 0), lambda: None)
+        info = cache.compile_info_snapshot()
+        assert info[str(("three-blocks", 16384))]["mlp_paths"] == {
+            "fused:16384": 2, "xla:200": 1,
+        }
+        assert info[str(("three-blocks", 16384))]["attention_paths"] == {}
+        assert info[str(("none", 0))]["mlp_paths"] == {}
+        cache.evict(lambda key: True)
+        assert cache.stats.mlp_paths == {}
+
+
+class TestMlpUnderGspmd:
+    """The MLP kernel under a CPU ``dp`` mesh: per shard, the weights
+    whole on every device, no collective."""
+
+    @staticmethod
+    def _operands(devices, batch=8):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        mesh = Mesh(np.array(devices), ("dp",))
+        plain = _mlp_operands((batch, 4, 32, 128), 256)
+        sharded = tuple(
+            jax.device_put(
+                a, NamedSharding(mesh, P("dp") if a.ndim == 4 else P())
+            )
+            for a in plain
+        )
+        return plain, sharded
+
+    def test_batch_sharded_runs_per_shard_with_no_collective(self, devices):
+        plain, sharded = self._operands(devices[:4])
+        fn = jax.jit(fused_mlp)
+        out = fn(*sharded)
+        assert out.sharding.spec[0] == "dp"
+        np.testing.assert_allclose(out, reference_mlp(*plain), atol=1e-4)
+        hlo = fn.lower(*sharded).compile().as_text()
+        assert "all-gather" not in hlo and "all-reduce" not in hlo
+
+    def test_batch_that_does_not_divide_takes_the_reference(
+        self, devices, monkeypatch
+    ):
+        """Six items over four devices: the kernel refuses, and ``mlp``
+        does not ask it, though a row tile divides the rows."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        mesh = Mesh(np.array(devices[:4]), ("dp",))
+        six = tuple(
+            jax.device_put(a, NamedSharding(mesh, P()))
+            for a in _mlp_operands((6, 4, 32, 128), 256)
+        )
+        assert tiles(6 * 4 * 32, 128, 256, jnp.float32) is not None
+        with pytest.raises(ValueError, match="does not divide"):
+            jax.jit(fused_mlp)(*six)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        before = mlp_ops.traced_paths()
+        jax.jit(mlp)(*six)
+        assert mlp_ops.traced_paths(since=before) == {"xla:768": 1}
+
+    def test_gradients_under_a_dp_mesh(self, devices):
+        plain, sharded = self._operands(devices[:4])
+
+        def loss(fn):
+            return lambda *a: jnp.sum(fn(*a) ** 2)
+
+        argnums = tuple(range(6))
+        got = jax.jit(jax.grad(loss(fused_mlp), argnums))(*sharded)
+        want = jax.grad(loss(reference_mlp), argnums)(*plain)
+        for g, w, name in zip(got, want, MLP_OPERANDS):
+            # sums over four shards round in another order than over one
+            np.testing.assert_allclose(
+                g, w, atol=2e-5 * float(jnp.abs(w).max()), rtol=1e-4,
+                err_msg=name,
+            )
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     """One described (not attached) v5e chip: the TPU compiler is
@@ -621,8 +954,8 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture()
-def no_compile_cache():
+@contextlib.contextmanager
+def _compile_cache_off():
     """A compile for a described chip is written to the persistent
     cache but cannot be read back without a chip; keep it out."""
     from jax.experimental.compilation_cache import compilation_cache
@@ -630,9 +963,17 @@ def no_compile_cache():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def no_compile_cache():
+    with _compile_cache_off():
+        yield
 
 
 class TestMosaicAcceptsTheServedShapes:
@@ -670,14 +1011,52 @@ class TestMosaicAcceptsTheServedShapes:
         )
         assert "tpu_custom_call" in compiled.as_text()
 
+    @pytest.mark.parametrize(
+        "shape",
+        [
+            (16, 32, 32, 1024),  # cpsam-vitl's served program: 16 tiles
+            (8, 32, 32, 1024),   # CpSAM under fine-tuning: batch 8 of 256 px
+        ],
+    )
+    def test_mlp_compiles_for_v5e(self, one_chip, no_compile_cache, shape):
+        """bf16 tokens, the f32 weights of the parameter tree, the VMEM
+        the tiles ask for: what Mosaic refuses here (a tile it cannot
+        lay out, more VMEM than the chip has) would have cost a chip
+        call."""
+        dim, hidden = shape[-1], 4 * shape[-1]
 
-class TestTheServedBlockHasNoRelayout:
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        compiled = (
+            jax.jit(lambda *a: fused_mlp(*a, interpret=False))
+            .trace(
+                spec(shape, jnp.bfloat16),
+                spec((dim, hidden), jnp.float32),
+                spec((hidden,), jnp.float32),
+                spec((hidden, dim), jnp.float32),
+                spec((dim,), jnp.float32),
+                spec(shape, jnp.bfloat16),
+            )
+            .lower(lowering_platforms=("tpu",))
+            .compile()
+        )
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text and "fused_mlp" in text
+
+
+class TestTheServedBlockIsItsKernels:
     """One cpsam block as the benchmark's configuration serves it,
-    compiled for a described v5e with the backend pretended a TPU: the
-    packed kernel is in it, and between the qkv projection and the
-    output projection nothing relays an attention operand. These passes
-    were 42 % of the served step (PERF.md section 6, PR 31); an edit that
-    brings one back fails here before it costs a chip run."""
+    compiled for a described v5e with the backend pretended a TPU (the
+    model code asks it). Read from the optimised program: the packed
+    attention kernel is in it and between the qkv projection and the
+    output projection nothing relays an attention operand (those passes
+    were 42 % of the served step; PERF.md section 6, PR 31); the MLP is
+    the one fused kernel, its hidden activation is nowhere in HBM, and
+    no matmul fusion carries an ``exponential`` (the erfc chain in
+    ``mlp_lin2``'s operand prologue and the bitmask beside ``mlp_lin1``
+    were a fifth of the step; PR 36). An edit that brings either back
+    fails here before it costs a chip run."""
 
     RELAYOUTS = (
         "copy bf16[16,1024,3,16,64]",
@@ -690,14 +1069,12 @@ class TestTheServedBlockHasNoRelayout:
         "copy bf16[16,1024,16,64]",
     )
 
-    def test_compiles_for_v5e_without_them(
-        self, one_chip, no_compile_cache, monkeypatch
-    ):
-        import re
-
+    @pytest.fixture(scope="class")
+    def served_block(self, one_chip):
+        """(optimised HLO text, attention paths, MLP paths) of one
+        compile, shared by the tests below."""
         from bioengine_tpu.models.sam import SAMBlock
 
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         block = SAMBlock(1024, 16, 4.0, 0, 32)
         params = jax.eval_shape(
             block.init, jax.random.key(0),
@@ -707,24 +1084,38 @@ class TestTheServedBlockHasNoRelayout:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
             (params, jax.ShapeDtypeStruct((16, 32, 32, 1024), jnp.bfloat16)),
         )
-        before = traced_paths()
-        text = (
-            jax.jit(block.apply)
-            .trace(*on_chip)
-            .lower(lowering_platforms=("tpu",))
-            .compile()
-            .as_text()
+        before = traced_paths(), mlp_ops.traced_paths()
+        with pytest.MonkeyPatch.context() as patch, _compile_cache_off():
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            text = (
+                jax.jit(block.apply)
+                .trace(*on_chip)
+                .lower(lowering_platforms=("tpu",))
+                .compile()
+                .as_text()
+            )
+        return (
+            text,
+            traced_paths(since=before[0]),
+            mlp_ops.traced_paths(since=before[1]),
         )
-        assert traced_paths(since=before) == {"packed:1024": 1}
-        assert "tpu_custom_call" in text and "packed_attention" in text
-        entry = text[text.index("ENTRY"):]
-        # "%name = type[shape]{layout} opcode(" -> "opcode-or-fusion-kind type[shape]"
-        produced = {
+
+    @staticmethod
+    def _produced(entry):
+        """"%name = type[shape]{layout} opcode(" of every instruction of
+        the entry computation -> "opcode-or-fusion-kind type[shape]"."""
+        return {
             f"{re.sub(r'[.][0-9]+$', '', name) if op == 'fusion' else op} {shape}"
             for name, shape, op in re.findall(
-                r"%([\w.\-]+) = (\w+\[[\d,]*\])\S* ([\w\-]+)\(", entry
+                r"%([\w.\-]+) = (\(?\w+\[[\d,]*\])\S* ([\w\-]+)\(", entry
             )
         }
+
+    def test_compiles_for_v5e_without_a_relayout(self, served_block):
+        text, attention_paths, _ = served_block
+        assert attention_paths == {"packed:1024": 1}
+        assert "tpu_custom_call" in text and "packed_attention" in text
+        produced = self._produced(text[text.index("ENTRY"):])
         # the pattern reads this dump: the qkv projection is in it
         assert any(p.endswith("bf16[16,32,32,3072]") for p in produced)
         assert not produced & set(self.RELAYOUTS)
@@ -734,3 +1125,21 @@ class TestTheServedBlockHasNoRelayout:
             p for p in produced
             if re.search(r"bf16\[16,(16,1024|1024,16|1024,3,16),", p)
         }
+
+    def test_the_mlp_is_one_kernel(self, served_block):
+        text, _, mlp_paths = served_block
+        assert mlp_paths == {"fused:16384": 1}
+        assert "fused_mlp" in text
+        entry = text[text.index("ENTRY"):]
+        calls = re.findall(r'custom_call_target="tpu_custom_call"', entry)
+        assert len(calls) == 2  # the packed attention and the MLP
+        produced = self._produced(entry)
+        assert any(p.endswith("bf16[16,32,32,3072]") for p in produced)
+        # the pre-activation, the packed sign bits beside it, the hidden
+        # activation: none of them exists outside the kernel
+        assert not {p for p in produced if "4096]" in p and "f32[" not in p}, produced
+        assert not {p for p in produced if "u32[16,32,4096]" in p}
+        # and no matmul fusion evaluates an erfc (its exponential)
+        for computation in text[:text.index("ENTRY")].split("\n\n"):
+            if " convolution(" in computation:
+                assert " exponential(" not in computation, computation[:200]
