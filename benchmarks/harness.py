@@ -31,8 +31,8 @@ Order of a run (``run_cell``):
             ``trace`` the profiler records a few seconds in its middle
   close     counters read, memory peak read, worker stopped (frees the
             program's state)
-  check     the plain reference over a sample of the window's own
-            replies, drawn from the seed, the largest request in it
+  check     the plain reference over the last replies of a sample of
+            the deck's requests, drawn from the seed, the largest in it
 """
 
 from __future__ import annotations
@@ -420,17 +420,22 @@ def per_layer(run: RunData) -> dict[str, float]:
 # ---- correctness -------------------------------------------------------------
 
 
-def draw_sample(kept: dict, per_size: int, seed: int) -> list[dict]:
-    """From the last reply each client got of each kind of request:
-    ``per_size`` clients of every kind, drawn from the seed. Every kind
-    is in it, so the largest is."""
+def draw_sample(plan, per_size: int, seed: int) -> list[tuple[int, int]]:
+    """The requests ``correct`` compares: ``per_size`` of every kind,
+    each a (client, position in its deck) drawn from the seed before the
+    run. Every kind is in it, so the largest is; and two runs of one seed
+    compare the same inputs, however their replies were timed (the last
+    reply a client got may be of another request in a faster run)."""
     rng = np.random.default_rng(abs(int(seed)) + 1)
-    sample = []
-    for kind in sorted({k for _, k in kept}):
-        clients = sorted(c for c, k in kept if k == kind)
-        for c in rng.permutation(clients)[:per_size]:
-            sample.append(kept[(int(c), kind)])
-    return sample
+    items: dict[Any, list[tuple[int, int]]] = {}
+    for c, deck in enumerate(plan.clients):
+        for position, request in enumerate(deck):
+            items.setdefault(request.kind, []).append((c, position))
+    return [
+        items[kind][i]
+        for kind in sorted(items)
+        for i in rng.permutation(len(items[kind]))[:per_size]
+    ]
 
 
 def not_comparable(limits: dict[str, float]) -> dict[str, float]:
@@ -508,9 +513,10 @@ class StallWatch(threading.Thread):
 async def serve_window(
     cell: Cell, seed: int, seconds: float, trace: bool, platform: str,
     out_dir: Path, t_process_start: float, compile_counter: CompileCounter,
-) -> tuple[RunData, float, dict, dict, tuple[int, int]]:
+) -> tuple[RunData, float, list, dict, tuple[int, int]]:
     """Set-up, warm-up and the measured window. Returns (run data,
-    setup_s, the kept replies, the input pool, memory peaks)."""
+    setup_s, the last reply of each request of the sample, ``None``
+    where none came, the input pool, memory peaks)."""
     path = cell.path
     generator = importlib.import_module(
         f"benchmarks.generators.{cell.traffic['generator']}"
@@ -529,7 +535,9 @@ async def serve_window(
         platform, out_dir / "workspace", len(plan.clients)
     )
     requests: list[dict] = []
-    kept: dict[tuple[int, Any], dict] = {}
+    # the last reply of each request of the sample, by (client, position)
+    drawn = draw_sample(plan, int(cell.traffic["check_per_size"]), seed)
+    kept: dict[tuple[int, int], dict] = {}
     try:
         app_id, app_sid = await deploy(
             admin, worker_sid, REPO / cell.config["deployment"]["app"],
@@ -557,9 +565,11 @@ async def serve_window(
                 output = reply.pop("output")
                 # end, ok, server_ms, and what a streamed path stamped
                 record.update(reply)
-                kept[(c, request.kind)] = {
-                    "kind": request.kind, "request": request, "output": output,
-                }
+                item = (c, n % len(plan.clients[c]))
+                if item in drawn:
+                    kept[item] = {
+                        "kind": request.kind, "request": request, "output": output,
+                    }
             except Exception as exc:  # noqa: BLE001 — counted, never hidden
                 record["end"] = time.perf_counter()
                 record["error"] = f"{type(exc).__name__}: {exc}"[:300]
@@ -693,7 +703,7 @@ async def serve_window(
         counters={"start": opened["counters"], "end": end_counters},
         compiles_in_window=int(compiles), trace=traced,
     )
-    return run, setup_s, kept, plan.pool, peak
+    return run, setup_s, [kept.get(item) for item in drawn], plan.pool, peak
 
 
 def reduce_window_trace(run: RunData, device: dict) -> dict:
@@ -742,7 +752,7 @@ def run_cell(
     device = device_gate(platform, cell.chips)
     compile_counter = CompileCounter()
     try:
-        run, setup_s, kept, pool, peak = asyncio.run(
+        run, setup_s, replies, pool, peak = asyncio.run(
             serve_window(
                 cell, seed, seconds, trace, platform, out_dir,
                 t_process_start, compile_counter,
@@ -784,10 +794,12 @@ def run_cell(
 
     # the program's state is freed (worker stopped): now the reference
     t_check = time.perf_counter()
-    sample = draw_sample(kept, int(cell.traffic["check_per_size"]), seed)
+    sample = [entry for entry in replies if entry is not None]
     limits = cell.config["limits"]
+    # a request of the sample that was never answered is not comparable
     readings = (
-        cell.path.compare(cell, seed, sample, pool) if sample
+        cell.path.compare(cell, seed, sample, pool)
+        if sample and len(sample) == len(replies)
         else not_comparable(limits)
     )
     checks = judge(readings, limits, len(sample))

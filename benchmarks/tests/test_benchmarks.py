@@ -8,6 +8,7 @@ have; nothing here prints or asserts a device metric.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import json
 import re
@@ -198,6 +199,56 @@ def test_images_follow_the_seed_and_the_order_of_sizes_does_not():
     assert sorted(sizes(a)[0]) == [(1, 64)] * 4 + [(1, 96)] * 3 + [(1, 128)] * 3
     other = closed_loop.plan({**traffic, "order": traffic["order"] + 1}, config, SEED)
     assert sizes(other) != sizes(a)
+
+
+def test_the_sample_is_drawn_by_deck_item_from_the_seed():
+    """``correct`` compares the last reply of (client, position in its
+    deck) items drawn from the seed before the run: two plans of one seed
+    draw the same items, which send the same inputs however the replies
+    are timed, and every kind is in the sample ``per_size`` times."""
+    traffic = json.loads((BENCH / "traffic" / "fov.json").read_text())
+    traffic["deck"] = [{**e, "size": e["size"] // 8} for e in traffic["deck"]]
+    a, b = (closed_loop.plan(traffic, {"in_channels": 3}, SEED) for _ in "ab")
+    drawn = harness.draw_sample(a, 2, SEED)
+    assert drawn == harness.draw_sample(b, 2, SEED)
+    assert [a.clients[c][p] for c, p in drawn] == [b.clients[c][p] for c, p in drawn]
+    assert [a.clients[c][p].kind for c, p in drawn] == sorted(closed_loop.kinds(traffic) * 2)
+    assert len(set(drawn)) == len(drawn)
+    assert harness.draw_sample(a, 2, SEED + 1) != drawn
+
+
+def test_a_request_of_the_sample_never_answered_is_not_correct(monkeypatch, tmp_path):
+    """The sample holds a position no client reaches: nothing to compare
+    there, so the run is not correct, whatever the others read."""
+    drawn = harness.draw_sample
+    monkeypatch.setattr(
+        harness, "draw_sample", lambda plan, n, seed: [*drawn(plan, n, seed), (0, 10**6)]
+    )
+    line = harness.run_cell(
+        toy_cell(), SEED + 5, 1.0, False, platform="cpu", out_dir=tmp_path
+    )
+    assert line["correct"] is False and line["failed"] == 0 < line["attempted"]
+    assert line["checks"]["rel_l2"][0] == harness.NOT_COMPARABLE
+
+
+def test_the_tail_reader_reads_every_request_of_the_window():
+    """``request_p95_ms`` is the 95th percentile over the window's
+    requests, a failed one counted as the window's length, and reads
+    nothing where the window holds no request."""
+    from benchmarks.layer_metrics import request_p95_ms
+
+    def record(end, latency, ok=True):
+        return dict(start=end - latency / 1000, end=end, latency_ms=latency, ok=ok, work=1.0)
+
+    inside = [record(1.0 + i / 100, 100.0 + i) for i in range(19)]
+    run = harness.RunData(
+        cell=None, seconds=2.0, window=(1.0, 3.0), counters={},
+        compiles_in_window=0,
+        requests=[record(0.5, 9999.0), *inside, record(2.9, 50.0, ok=False)],
+    )
+    latencies = [100.0 + i for i in range(19)] + [2000.0]
+    assert request_p95_ms.read(run) == window_metrics.percentile(latencies, 95)
+    assert request_p95_ms.read(dataclasses.replace(run, requests=[])) is None
 
 
 def test_the_window_opens_on_clients_in_their_stride():
@@ -432,10 +483,9 @@ def test_rehearsal_prints_the_contract_s_line(toy_runs):
         for name in ("rel_l2", "max_err"):
             value, limit = line["checks"][name]
             assert 0 < value <= limit
-    assert set(plain["metrics"]) == {
-        "throughput_mpx_s", "latency_p50_ms", "latency_p95_ms", "setup_s",
-    }
-    assert plain["metrics"]["latency_p95_ms"]["value"] >= plain["metrics"]["latency_p50_ms"]["value"]
+    assert set(plain["metrics"]) == {"throughput_mpx_s", "latency_p50_ms", "setup_s"}
+    # the tail is the traced line's, under its per-layer name
+    assert traced["metrics"]["request_p95_ms"]["unit"] == "ms"
     assert all(m["value"] > 0 for m in plain["metrics"].values())
 
 
@@ -443,9 +493,10 @@ def test_counter_readers_read_and_trace_readers_stay_silent_off_the_chip(toy_run
     _, traced = toy_runs
     got = traced["metrics"]
     # no device plane on a CPU: nothing under a device metric's name
-    assert set(got) == {"batch_occupancy", "compiles_in_window"}
+    assert set(got) == {"batch_occupancy", "compiles_in_window", "request_p95_ms"}
     assert got["compiles_in_window"]["value"] == 0
     assert got["batch_occupancy"]["value"] >= 1
+    assert got["request_p95_ms"]["value"] > 0
     assert "busy_s" not in traced["device"] and "breakdown" not in traced
 
 
@@ -657,8 +708,8 @@ def test_the_token_path_prints_its_own_line(token_runs):
         value, limit = line["checks"]["logit_gap"]
         assert 0 <= value <= limit and line["checks"]["compared"] == 4
         assert json.loads(json.dumps(line)) == line
-    # latencies and set-up as every path; no throughput in the image path's unit
-    assert set(plain["metrics"]) == {"latency_p50_ms", "latency_p95_ms", "setup_s"}
+    # latency and set-up as every path; no throughput in the image path's unit
+    assert set(plain["metrics"]) == {"latency_p50_ms", "setup_s"}
     assert all(m["value"] > 0 for m in plain["metrics"].values())
     # none of the image path's counter metrics, no device metric off the chip
     assert set(traced["metrics"]) == {"tokens_per_s", "first_item_ms", "compiles_in_window"}
