@@ -123,14 +123,24 @@ def resolve_devices(
 def _weakly(method: Callable) -> Callable:
     """``method`` of an object this reference does not keep alive."""
     ref = weakref.WeakMethod(method)
-    return lambda *args: ref()(*args)
+    return lambda *args, **kwargs: ref()(*args, **kwargs)
 
 
-# the device seconds billed to the prediction the current context is in:
-# a one-item list that ``predict`` sets and whatever runs its chunks
-# adds to (the issuing thread through its copy of the context)
-_bill: contextvars.ContextVar[Optional[list]] = contextvars.ContextVar(
-    "bioengine_engine_bill", default=None
+class _Prediction:
+    """The prediction the current context is in, which ``predict`` sets:
+    the device seconds billed to it, which whatever runs its chunks adds
+    to (the issuing thread through its copy of the context), and the
+    attrs of its ``engine.predict`` stage."""
+
+    __slots__ = ("seconds", "attrs")
+
+    def __init__(self, attrs: dict):
+        self.seconds = 0.0
+        self.attrs = attrs
+
+
+_prediction: contextvars.ContextVar[Optional[_Prediction]] = (
+    contextvars.ContextVar("bioengine_engine_prediction", default=None)
 )
 
 
@@ -637,10 +647,13 @@ class InferenceEngine:
         request-scoped accounting accumulator (utils/tracing.py) on
         every call, sampled or not: cost is exact, only spans are
         sampled. Summed over the requests served they are the device's
-        busy time x mesh width, however the requests shared chunks."""
+        busy time x mesh width, however the requests shared chunks.
+
+        A tiled prediction's stage, sampled or not, carries its time in
+        the tile stream: ``tiles``, the ``chunks`` its rows rode,
+        ``stream_wait_s`` (enrolment to the dispatch of its first chunk)
+        and ``stream_run_s`` (from there to its last row blended)."""
         width = len(self.devices)
-        billed = [0.0]
-        token = _bill.set(billed)
         whole = tracing.stage(
             "engine.predict",
             model=self.model_id,
@@ -648,17 +661,19 @@ class InferenceEngine:
             mesh=self._mesh_key,
             devices=width,
         )
+        current = _Prediction(whole.attrs)
+        token = _prediction.set(current)
         try:
             with whole:
                 out = self._predict_impl(images)
                 if whole.span is not None:
                     whole.attrs["stage_seconds"] = self._stage_seconds(
-                        whole.span, billed[0]
+                        whole.span, current.seconds
                     )
             return out
         finally:
-            _bill.reset(token)
-            chip_seconds = billed[0] * width
+            _prediction.reset(token)
+            chip_seconds = current.seconds * width
             if whole.span is not None:
                 whole.attrs["chip_seconds"] = round(chip_seconds, 6)
             tracing.add_chip_seconds(chip_seconds)
@@ -692,6 +707,14 @@ class InferenceEngine:
                 self._program((rows, *job.row_shape), job.dtype)
             self._stream.enrol(job).result()
             self._bill(job.compute_seconds)
+            current = _prediction.get()
+            if current is not None:
+                current.attrs.update(
+                    tiles=job.tiles,
+                    chunks=job.chunks,
+                    stream_wait_s=job.stream_wait_seconds,
+                    stream_run_s=job.stream_run_seconds,
+                )
             with tracing.stage("engine.stitch") as divide:
                 out = job.acc / job.weight
             self.pipeline_stats.add(stitch_seconds=divide.seconds)
@@ -703,9 +726,9 @@ class InferenceEngine:
 
     @staticmethod
     def _bill(compute_seconds: float) -> None:
-        bill = _bill.get()
-        if bill is not None:
-            bill[0] += compute_seconds
+        current = _prediction.get()
+        if current is not None:
+            current.seconds += compute_seconds
 
     def predict_serial(self, images: np.ndarray) -> np.ndarray:
         """The strictly serial path, on the caller's thread: one chunk
@@ -785,16 +808,17 @@ class InferenceEngine:
 
     # ---- one chunk on the device (every path's put/dispatch/wait/d2h) -------
 
-    def _dispatch_chunk(self, buf: np.ndarray, n: int) -> InFlight:
+    def _dispatch_chunk(self, buf: np.ndarray, n: int, **attrs: Any) -> InFlight:
         """Hand one staged chunk (``n`` useful rows) to the device
         WITHOUT blocking: ``engine.put`` is the ``device_put`` call (H2D
         enqueue and linearize), ``engine.dispatch`` the program lookup,
-        the params gate and the jitted call."""
+        the params gate and the jitted call; ``attrs`` (the stream's
+        account of the chunk) go on the dispatch stage."""
         with tracing.stage("engine.put", bytes=buf.nbytes) as put:
             # staged host chunks become sharded arrays on a mesh engine
             # (single-device put on 1 chip)
             dev = self._put(buf)
-        with tracing.stage("engine.dispatch") as dispatch:
+        with tracing.stage("engine.dispatch", **attrs) as dispatch:
             program = self._program(buf.shape, buf.dtype)
             # the gate sits AFTER compile: under streamed loading the
             # first request's compile overlaps the weight transfer, and
@@ -814,9 +838,8 @@ class InferenceEngine:
     def _force_chunk(self, flight: InFlight) -> np.ndarray:
         """Block until a dispatched chunk is on the host:
         ``engine.device_wait`` is the wait for the device,
-        ``engine.d2h`` the copy of the ready result. ``readback_seconds``
-        keeps its meaning, the two together. Returns every row, padding
-        included."""
+        ``engine.d2h`` the copy of the ready result. Returns every row,
+        padding included."""
         out = flight.out
         with tracing.stage("engine.device_wait") as wait:
             out.block_until_ready()
@@ -827,7 +850,6 @@ class InferenceEngine:
         self.pipeline_stats.add(
             device_wait_seconds=wait.seconds,
             d2h_seconds=d2h.seconds,
-            readback_seconds=wait.seconds + d2h.seconds,
             d2h_bytes=host.nbytes,
         )
         return host

@@ -93,25 +93,38 @@ class PipelineStats:
     means the device never waited on the host. On CPU backends XLA
     dispatch is near-synchronous, so the numbers are informational.
 
-    Every ``*_seconds`` sum but ``compute`` and ``wall`` is fed by one
-    ``tracing.stage`` of the same name (``cut_seconds`` by
-    ``engine.cut``, ``queue_seconds`` by ``engine.queue``,
-    ``preprocess_seconds`` by ``runtime.preprocess``, ...);
-    ``readback_seconds`` is ``device_wait_seconds`` + ``d2h_seconds``
-    (mostly the wait for the device, not host work). The direct and
-    serial paths feed the same fields as the stream, one chunk per
-    program call. ``wall_seconds`` is, for the stream, the time it had a
-    request in hand. ``rows_executed`` are the batch rows of every
-    program call, padding rows included; ``rows_useful`` the tiles or
-    items asked for; ``chunks_shared`` the stream's chunks that held
-    rows of more than one request.
+    Every ``*_seconds`` sum but ``compute``, ``wall`` and the two
+    ``stream_*`` is fed by one ``tracing.stage`` of the same name
+    (``cut_seconds`` by ``engine.cut``, ``queue_seconds`` by
+    ``engine.queue``, ``preprocess_seconds`` by ``runtime.preprocess``,
+    ...). The direct and serial paths feed the same fields as the
+    stream, one chunk per program call. ``wall_seconds`` is, for the
+    stream, the time it had a request in hand. ``rows_executed`` are the
+    batch rows of every program call, padding rows included;
+    ``rows_useful`` the tiles or items asked for; ``chunks_shared`` the
+    stream's chunks that held rows of more than one request.
+
+    The stream counts each of its chunks by why it closed
+    (``chunks_closed_<reason>``, the four reasons of
+    ``TileStream._close_chunk``; they sum to the stream's chunks, all
+    of ``chunks`` where no prediction ran direct or serial) and, for
+    each tiled request it answered (``jobs``), the chunks its rows rode
+    (``job_chunks``), its wait from enrolment to the dispatch of its
+    first chunk (``stream_wait_seconds``) and the time from there to
+    its answer (``stream_run_seconds``).
     """
 
     _FIELDS = (
         "chunks",
         "chunks_shared",
+        "chunks_closed_full",
+        "chunks_closed_key",
+        "chunks_closed_direct",
+        "chunks_closed_late",
         "items",
         "requests",
+        "jobs",
+        "job_chunks",
         "queue_seconds",
         "preprocess_seconds",
         "postprocess_seconds",
@@ -121,9 +134,10 @@ class PipelineStats:
         "compute_seconds",
         "device_wait_seconds",
         "d2h_seconds",
-        "readback_seconds",
         "stitch_seconds",
         "wall_seconds",
+        "stream_wait_seconds",
+        "stream_run_seconds",
         "h2d_bytes",
         "d2h_bytes",
         "rows_executed",
@@ -273,7 +287,12 @@ class TileJob:
     ``compute_seconds`` is then the job's share of the device time of
     every chunk it rode: a chunk's ``compute_seconds`` x its rows / the
     chunk's useful rows, so padding is split the same way and the shares
-    of all jobs sum to the device's busy time."""
+    of all jobs sum to the device's busy time. ``chunks`` is how many
+    chunks it rode; ``enrolled_ns``, ``first_dispatch_ns`` and
+    ``answered_ns`` (``time.time_ns()``) when it joined the stream, when
+    the dispatch of its first chunk ended and when it was answered;
+    ``request_seq`` the number of the request it belongs to (the
+    ``engine.dispatch`` stage of every chunk lists those of its jobs)."""
 
     key: tuple
     row_shape: tuple
@@ -286,10 +305,21 @@ class TileJob:
         self.next_tile = 0
         self.blended = 0
         self.compute_seconds = 0.0
+        self.chunks = 0
+        self.enrolled_ns = self.first_dispatch_ns = self.answered_ns = 0
+        self.request_seq = tracing.current_request_seq()
         self.cut_context = contextvars.copy_context()
         self.issue_context = contextvars.copy_context()
         self.stitch_context = contextvars.copy_context()
         self.trace, self.parent_span = tracing.current_trace_and_span()
+
+    @property
+    def stream_wait_seconds(self) -> float:
+        return (self.first_dispatch_ns - self.enrolled_ns) / 1e9
+
+    @property
+    def stream_run_seconds(self) -> float:
+        return (self.answered_ns - self.first_dispatch_ns) / 1e9
 
     def cut(self, first: int, rows: np.ndarray) -> None:
         raise NotImplementedError
@@ -300,10 +330,11 @@ class TileJob:
 
 class _Chunk:
     """Rows of one or more jobs on their way through the device:
-    ``segments`` are (job, first tile, rows) in row order, ``sizes`` the
-    row counts its jobs would run alone."""
+    ``segments`` are (job, first tile, rows) in row order, one a job,
+    ``sizes`` the row counts its jobs would run alone, ``closed`` why it
+    closed (``TileStream._close_chunk``)."""
 
-    __slots__ = ("key", "buf", "rows", "segments", "sizes", "flight")
+    __slots__ = ("key", "buf", "rows", "segments", "sizes", "flight", "closed")
 
     def __init__(self, key: tuple, buf: np.ndarray):
         self.key = key
@@ -312,6 +343,7 @@ class _Chunk:
         self.segments: list[tuple[TileJob, int, int]] = []
         self.sizes: set[int] = set()
         self.flight: Optional[InFlight] = None
+        self.closed = ""
 
 
 class _Direct:
@@ -328,10 +360,13 @@ class _Direct:
 
 class TileStream:
     """The engine's one tile stream and its three threads (module
-    docstring). ``dispatch(buf, n)`` hands a staged chunk with ``n``
-    useful rows to the device without blocking and returns its
-    ``InFlight``; ``force(flight)`` blocks until its rows are on the
-    host and returns them all.
+    docstring). ``dispatch(buf, n, **attrs)`` hands a staged chunk with
+    ``n`` useful rows to the device without blocking and returns its
+    ``InFlight``; ``attrs`` go on its ``engine.dispatch`` stage:
+    ``rows``, ``capacity``, ``closed`` (why it closed) and ``requests``
+    (the ``request_seq`` of each job with rows in it, the first job's
+    first). ``force(flight)`` blocks until its rows are on the host and
+    returns them all.
 
     **Row counts.** A chunk holds at most ``tile_batch`` useful rows and
     runs at the smallest row count that one of its jobs would have run
@@ -349,7 +384,7 @@ class TileStream:
         chunk_rows: int,
         stats: PipelineStats,
         pool: StagingPool,
-        dispatch: Callable[[np.ndarray, int], InFlight],
+        dispatch: Callable[..., InFlight],
         force: Callable[[InFlight], np.ndarray],
     ):
         self._name = name
@@ -382,8 +417,9 @@ class TileStream:
         into the open chunk first. Returns ``job.future``."""
         with self._cond:
             self._ensure_running()
+            job.enrolled_ns = time.time_ns()
             if not self._pending:
-                self._busy_since_ns = time.time_ns()
+                self._busy_since_ns = job.enrolled_ns
             self._pending.add(job)
             self._cutting.append(job)
             # the open chunk waits for these tiles, not only for the cut
@@ -402,7 +438,7 @@ class TileStream:
             if self._open is not None and not self._cutter_busy:
                 # in arrival order behind the rows that wait (a chunk
                 # the cut thread is filling right now it overtakes)
-                self._close_chunk()
+                self._close_chunk("direct")
             self._staged.append(direct)
             self._cond.notify_all()
         return direct.future
@@ -456,16 +492,27 @@ class TileStream:
             self.close()
 
     def _answer(self, job: TileJob, error: Optional[BaseException] = None) -> None:
-        """Resolve a job's future, once: its last row is blended, or it
-        failed (its remaining rows are then dropped)."""
+        """Resolve a job's future, once: its last row is blended (the
+        job's time in the stream is then counted), or it failed (its
+        remaining rows are then dropped)."""
         with self._cond:
             if job not in self._pending:
                 return
             self._pending.remove(job)
-            if not self._pending:
-                self._stats.add(
-                    wall_seconds=(time.time_ns() - self._busy_since_ns) / 1e9
+            now = time.time_ns()
+            deltas = {}
+            if error is None:
+                job.answered_ns = now
+                deltas.update(
+                    jobs=1,
+                    job_chunks=job.chunks,
+                    stream_wait_seconds=job.stream_wait_seconds,
+                    stream_run_seconds=job.stream_run_seconds,
                 )
+            if not self._pending:
+                deltas["wall_seconds"] = (now - self._busy_since_ns) / 1e9
+            if deltas:
+                self._stats.add(**deltas)
         if error is None:
             job.future.set_result(None)
         else:
@@ -503,7 +550,7 @@ class TileStream:
         job = cutting[0]
         chunk = self._open
         if chunk is not None and chunk.key != job.key:
-            self._close_chunk()  # tiles of another shape share no chunk
+            self._close_chunk("key")  # tiles of another shape share no chunk
             chunk = None
         if chunk is None:
             if len(self._staged) >= PREFETCH:
@@ -525,8 +572,13 @@ class TileStream:
     def _capacity(self, chunk: _Chunk) -> int:
         return min(self._tile_batch, max(chunk.sizes))
 
-    def _close_chunk(self) -> None:
-        """(Lock held.)"""
+    def _close_chunk(self, reason: str) -> None:
+        """(Lock held.) The open chunk goes to the issuing thread:
+        ``full`` (its rows reached its capacity), ``key`` (the next
+        job's tiles are of another key), ``direct`` (a prediction that
+        needs no tiling overtakes it). The fourth reason, ``late``, is
+        the issuing thread's own (``_issue_loop``)."""
+        self._open.closed = reason
         self._staged.append(self._open)
         self._open = None
 
@@ -544,7 +596,7 @@ class TileStream:
             chunk.segments.append((job, first, n))
             chunk.rows += n
             if chunk.rows == self._capacity(chunk):
-                self._close_chunk()
+                self._close_chunk("full")
             self._cond.notify_all()
         if error is not None:
             self._answer(job, error)
@@ -577,6 +629,7 @@ class TileStream:
                             # stands ready, and no tile in hand could
                             # still join this one
                             self._open = None
+                            chunk.closed = "late"
                             entry = chunk
                             break
                     # read back when the window is full or nothing more
@@ -610,18 +663,28 @@ class TileStream:
         rows = chunk.rows
         size = min(s for s in chunk.sizes if s >= rows)
         chunk.buf[rows:size] = 0  # stale rows of an earlier, fuller chunk
-        # a chunk's stages are recorded once, under its first job
+        # a chunk's stages are recorded once, under its first job; its
+        # dispatch names every job's request
         head = chunk.segments[0][0]
         try:
-            chunk.flight = head.issue_context.run(
-                self._dispatch, chunk.buf[:size], rows
+            chunk.flight = flight = head.issue_context.run(
+                self._dispatch, chunk.buf[:size], rows,
+                rows=rows, capacity=self._capacity(chunk), closed=chunk.closed,
+                requests=[job.request_seq for job, _, _ in chunk.segments],
             )
         except Exception as exc:
             self._pool.release(chunk.buf)
             self._to_stitch(chunk, None, exc)
             return
         window.append(chunk)
-        self._stats.add(chunks=1, chunks_shared=int(len(chunk.segments) > 1))
+        for job, _, _ in chunk.segments:
+            job.chunks += 1
+            job.first_dispatch_ns = job.first_dispatch_ns or flight.dispatched_ns
+        self._stats.add(
+            chunks=1,
+            chunks_shared=int(len(chunk.segments) > 1),
+            **{f"chunks_closed_{chunk.closed}": 1},
+        )
         self._stats.observe_in_flight(len(window))
 
     def _read_back(self, chunk: _Chunk) -> None:

@@ -689,7 +689,7 @@ class Replica(ReplicaStateMixin):
         # deployments that run the overlapped inference pipeline expose
         # a sync ``pipeline_stats()`` (e.g. model-runner's
         # RuntimeDeployment); surface it so the controller's
-        # get_app_status shows cut/put/compute/readback/stitch seconds
+        # get_app_status shows cut/put/compute/device_wait/stitch seconds
         # and overlap efficiency per replica
         stats_fn = getattr(self.instance, "pipeline_stats", None)
         if callable(stats_fn):

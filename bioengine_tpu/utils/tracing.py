@@ -420,6 +420,13 @@ def begin_request() -> int:
     return seq
 
 
+def current_request_seq() -> int:
+    """The ``request_seq`` the current context's stages carry (0 outside
+    a request): what an object made inside a request keeps to name it
+    later on another thread."""
+    return _request_seq.get()
+
+
 def _annotation(name: str):
     """``jax.profiler.TraceAnnotation(name)``: the stage's name on the
     host plane of a profiler trace (host level >= 1), a flag check while
