@@ -846,6 +846,7 @@ class InferenceEngine:
         with tracing.stage("engine.d2h", bytes=out.nbytes) as d2h:
             host = np.asarray(out)
         flight.ready_ns = wait.end_ns
+        flight.waited_ns = wait.end_ns - wait.start_ns
         flight.stages += (wait, d2h)
         self.pipeline_stats.add(
             device_wait_seconds=wait.seconds,

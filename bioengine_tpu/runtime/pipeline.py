@@ -25,9 +25,29 @@ one's of the same chunk shape), so the padding rows of one request
 carry another's tiles. At most ``depth`` chunks are in flight (the HBM
 bound). An open, partly filled chunk is closed late: it keeps taking
 rows of newly enrolled requests until the issuing thread has a free
-place in its window and no full chunk to put there; then it goes,
-padded, exactly as a lone request's tail always did. No timer holds a
-place for a request that has not arrived.
+place in its window and no full chunk to put there, and then, while a
+chunk is still in flight, until that chunk's device deadline; then it
+goes, padded, as a lone request's tail always did.
+
+**The device-deadline hold.** The stream times each chunk it reads
+back: its device time, from when it had the device (its dispatch, or
+the previous chunk's end) to its end, kept per program (key and row
+count). The newest in-flight chunk's predicted end is ``max(its
+dispatch, the previous chunk's end)`` plus its program's device time;
+its deadline is that end less the lead: the mean put + dispatch of a
+program call (``PipelineStats``) and ``HOLD_MARGIN_NS``. A partly filled open chunk goes at the first of: no
+chunk in flight (the device is dry), the deadline, or a deadline that
+is unknown (a program not timed yet) or already past. Until then it
+closes ``full``, ``key`` or ``direct`` as before, and the rows of a
+request that enrols meanwhile join it. While it holds, the issuing
+thread reads nothing back (that would block until the in-flight chunk
+ends); it waits for the deadline on the stream's condition. So **a held
+chunk starts on the device no later than the late rule would have
+started it, give or take the lead's error**: its head request loses
+nothing, and the rows that ride with it gain a chunk. A read-back that
+finds its chunk already done has seen no end and teaches no device
+time: the program's time is forgotten, and the next chunk behind it
+goes at once and is timed afresh.
 
 Every stage is measured once, by ``tracing.stage`` (utils/tracing.py):
 the interval the ``PipelineStats`` sums receive is the one the
@@ -57,6 +77,15 @@ from bioengine_tpu.utils import metrics, tracing
 # host chunks cut ahead of the issuing thread, the open one included:
 # with the in-flight window that is every buffer a stream holds
 PREFETCH = 2
+# a held chunk goes this long before the put + dispatch it needs, so
+# that its rows are on the device as the chunk in flight ends: the
+# host-to-device copy goes on after the put call returns (a 12.6 MB
+# chunk of 16 x 256 x 256 x 3 f32 lands 4.0-5.3 ms after its put call
+# began on a TPU v5e, of which the put + dispatch cover about 1.4)
+HOLD_MARGIN_NS = 4_000_000
+# a read-back that waited less than this found its chunk already done:
+# it saw no end, so it teaches no device time
+FOUND_DONE_NS = 500_000
 
 
 def _collect_pipelines(instances: list) -> list:
@@ -93,8 +122,8 @@ class PipelineStats:
     means the device never waited on the host. On CPU backends XLA
     dispatch is near-synchronous, so the numbers are informational.
 
-    Every ``*_seconds`` sum but ``compute``, ``wall`` and the two
-    ``stream_*`` is fed by one ``tracing.stage`` of the same name
+    Every ``*_seconds`` sum but ``compute``, ``wall``, ``hold`` and the
+    two ``stream_*`` is fed by one ``tracing.stage`` of the same name
     (``cut_seconds`` by ``engine.cut``, ``queue_seconds`` by
     ``engine.queue``, ``preprocess_seconds`` by ``runtime.preprocess``,
     ...). The direct and serial paths feed the same fields as the
@@ -111,7 +140,12 @@ class PipelineStats:
     each tiled request it answered (``jobs``), the chunks its rows rode
     (``job_chunks``), its wait from enrolment to the dispatch of its
     first chunk (``stream_wait_seconds``) and the time from there to
-    its answer (``stream_run_seconds``).
+    its answer (``stream_run_seconds``). ``chunks_held`` are the chunks
+    the late rule would have sent at once and the device-deadline hold
+    kept (module docstring), ``chunks_held_filled`` those of them that
+    took rows of a request that enrolled during the hold, and
+    ``hold_seconds`` the time they were held (the sum of their
+    ``engine.dispatch`` stages' ``held_ms``).
     """
 
     _FIELDS = (
@@ -121,6 +155,8 @@ class PipelineStats:
         "chunks_closed_key",
         "chunks_closed_direct",
         "chunks_closed_late",
+        "chunks_held",
+        "chunks_held_filled",
         "items",
         "requests",
         "jobs",
@@ -138,6 +174,7 @@ class PipelineStats:
         "wall_seconds",
         "stream_wait_seconds",
         "stream_run_seconds",
+        "hold_seconds",
         "h2d_bytes",
         "d2h_bytes",
         "rows_executed",
@@ -259,13 +296,15 @@ class DispatchExecutor:
 class InFlight:
     """One chunk handed to the device: its output (a future until
     forced), its useful rows, when the dispatch ended and the wait for
-    it ended (``time.time_ns()``), and the stages it passed on the
-    issuing thread (put, dispatch, device_wait, d2h)."""
+    it ended (``time.time_ns()``), how long that wait took, and the
+    stages it passed on the issuing thread (put, dispatch, device_wait,
+    d2h)."""
 
     out: Any
     n: int
     dispatched_ns: int
     ready_ns: int = 0
+    waited_ns: int = 0
     stages: list = dataclasses.field(default_factory=list)
 
 
@@ -331,10 +370,15 @@ class TileJob:
 class _Chunk:
     """Rows of one or more jobs on their way through the device:
     ``segments`` are (job, first tile, rows) in row order, one a job,
-    ``sizes`` the row counts its jobs would run alone, ``closed`` why it
-    closed (``TileStream._close_chunk``)."""
+    ``sizes`` the row counts its jobs would run alone, ``size`` the one
+    it runs at, ``closed`` why it closed (``TileStream._close_chunk``),
+    ``held_ns`` when the device-deadline hold began to keep it (0: never)
+    and ``held_rows`` its rows then."""
 
-    __slots__ = ("key", "buf", "rows", "segments", "sizes", "flight", "closed")
+    __slots__ = (
+        "key", "buf", "rows", "segments", "sizes", "size", "flight", "closed",
+        "held_ns", "held_rows",
+    )
 
     def __init__(self, key: tuple, buf: np.ndarray):
         self.key = key
@@ -342,8 +386,10 @@ class _Chunk:
         self.rows = 0
         self.segments: list[tuple[TileJob, int, int]] = []
         self.sizes: set[int] = set()
+        self.size = 0
         self.flight: Optional[InFlight] = None
         self.closed = ""
+        self.held_ns = self.held_rows = 0
 
 
 class _Direct:
@@ -363,10 +409,11 @@ class TileStream:
     docstring). ``dispatch(buf, n, **attrs)`` hands a staged chunk with
     ``n`` useful rows to the device without blocking and returns its
     ``InFlight``; ``attrs`` go on its ``engine.dispatch`` stage:
-    ``rows``, ``capacity``, ``closed`` (why it closed) and ``requests``
+    ``rows``, ``capacity``, ``closed`` (why it closed), ``requests``
     (the ``request_seq`` of each job with rows in it, the first job's
-    first). ``force(flight)`` blocks until its rows are on the host and
-    returns them all.
+    first) and ``held_ms`` (how long the device-deadline hold kept it).
+    ``force(flight)`` blocks until its rows are on the host, sets its
+    ``ready_ns`` and ``waited_ns``, and returns them all.
 
     **Row counts.** A chunk holds at most ``tile_batch`` useful rows and
     runs at the smallest row count that one of its jobs would have run
@@ -409,6 +456,9 @@ class TileStream:
         self._blends: deque = deque()  # read-back chunks for the stitch thread
         self._busy_since_ns = 0     # when the stream last got a job in hand
         self._last_ready_ns = 0
+        # the device time of each program (key, row count), as the
+        # issuing thread last saw one end: the hold's clock
+        self._device_ns: dict[tuple, int] = {}
 
     # ---- the requests' side -------------------------------------------------
 
@@ -622,16 +672,28 @@ class TileStream:
                     if place and self._staged:
                         entry = self._staged.popleft()
                         break
-                    if place and not self._cutter_busy:
-                        chunk = self._open
-                        if chunk is not None and chunk.rows:
-                            # late closing: a place is free, no full chunk
-                            # stands ready, and no tile in hand could
-                            # still join this one
+                    chunk = self._open
+                    if (
+                        place and not self._cutter_busy
+                        and chunk is not None and chunk.rows
+                    ):
+                        # a place is free, no full chunk stands ready, and
+                        # no tile in hand could still join this one: it
+                        # goes late now unless the chunk in flight keeps
+                        # the device busy past the lead (module docstring)
+                        now = time.time_ns()
+                        deadline = self._deadline(window)
+                        if deadline is None or now >= deadline:
                             self._open = None
                             chunk.closed = "late"
                             entry = chunk
                             break
+                        if not chunk.held_ns:
+                            chunk.held_ns, chunk.held_rows = now, chunk.rows
+                        # held: no read-back (it would block past the
+                        # deadline); rows that enrol meanwhile join it
+                        cond.wait((deadline - now) / 1e9)
+                        continue
                     # read back when the window is full or nothing more
                     # is coming; while the cut thread is at work the next
                     # chunk goes first, so the device never waits for it
@@ -659,18 +721,39 @@ class TileStream:
                 except Exception as exc:
                     entry.future.set_exception(exc)
 
+    def _deadline(self, window: deque) -> Optional[int]:
+        """When the open chunk must go for the device not to run dry
+        behind the chunks in ``window`` (``time.time_ns()``): their
+        predicted end less the lead: the mean put + dispatch of a
+        program call and ``HOLD_MARGIN_NS``. None where nothing is in
+        flight or a program in flight has not been timed yet."""
+        if not window:
+            return None
+        stats = self._stats
+        lead = (stats.put_seconds + stats.dispatch_seconds) / stats.chunks
+        end = self._last_ready_ns
+        for flying in window:
+            run = self._device_ns.get((flying.key, flying.size))
+            if run is None:
+                return None
+            end = max(flying.flight.dispatched_ns, end) + run
+        return end - int(lead * 1e9) - HOLD_MARGIN_NS
+
     def _issue(self, chunk: _Chunk, window: deque) -> None:
         rows = chunk.rows
-        size = min(s for s in chunk.sizes if s >= rows)
+        chunk.size = size = min(s for s in chunk.sizes if s >= rows)
         chunk.buf[rows:size] = 0  # stale rows of an earlier, fuller chunk
         # a chunk's stages are recorded once, under its first job; its
         # dispatch names every job's request
         head = chunk.segments[0][0]
+        start = time.time_ns()
+        held = (start - chunk.held_ns) / 1e9 if chunk.held_ns else 0.0
         try:
             chunk.flight = flight = head.issue_context.run(
                 self._dispatch, chunk.buf[:size], rows,
                 rows=rows, capacity=self._capacity(chunk), closed=chunk.closed,
                 requests=[job.request_seq for job, _, _ in chunk.segments],
+                held_ms=held * 1e3,
             )
         except Exception as exc:
             self._pool.release(chunk.buf)
@@ -683,6 +766,9 @@ class TileStream:
         self._stats.add(
             chunks=1,
             chunks_shared=int(len(chunk.segments) > 1),
+            chunks_held=int(bool(chunk.held_ns)),
+            chunks_held_filled=int(bool(chunk.held_ns) and rows > chunk.held_rows),
+            hold_seconds=held,
             **{f"chunks_closed_{chunk.closed}": 1},
         )
         self._stats.observe_in_flight(len(window))
@@ -704,6 +790,12 @@ class TileStream:
             busy_from = max(flight.dispatched_ns, self._last_ready_ns)
             compute = max(flight.ready_ns - busy_from, 0) / 1e9
             self._last_ready_ns = flight.ready_ns
+            program = (chunk.key, chunk.size)
+            if flight.waited_ns >= FOUND_DONE_NS:
+                self._device_ns[program] = flight.ready_ns - busy_from
+            else:
+                # done before the wait began: an end not seen
+                self._device_ns.pop(program, None)
             self._stats.add(compute_seconds=compute)
             for job, _, n in chunk.segments:
                 job.compute_seconds += compute * n / chunk.rows

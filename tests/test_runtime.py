@@ -781,6 +781,136 @@ class TestTileStream:
                 reply, eng.predict_serial(x), rtol=0, atol=0
             )
 
+    # the program of the tests' 1-channel tiles (48 px, bucketed to 64)
+    # at 16 rows, as the stream keys its device times
+    PROGRAM = (((64, 64, 1), "<f4"), 16)
+
+    def _held_behind_its_first_chunk(self, eng, first):
+        """``first`` (25 tiles) on an engine whose program is timed at a
+        minute: its first 16 rows go at once and stay in flight (a gated
+        read-back keeps them there), its other 9 are held in the open
+        chunk for their deadline. Returns the stats before and the time
+        since."""
+        stream = eng._stream
+        eng.predict(self._image(3))     # compile, time a put + dispatch
+        before, since = TestEngineStages._sums(eng), time.time_ns()
+        stream._device_ns[self.PROGRAM] = 60 * 10**9
+        eng.gate.clear()
+        future = eng.submit(eng.predict, first)
+        eng.wait_for(lambda: stream._open is not None and stream._open.held_ns)
+        assert eng.pipeline_stats.chunks - before["chunks"] == 1
+        assert stream._open.rows == 9
+        return before, since, future
+
+    def test_a_held_chunk_takes_the_rows_of_a_request_that_enrols_meanwhile(self):
+        """One chunk in flight and a place free in the window: the 9-row
+        open chunk is held for the chunk in flight's deadline, and a
+        7-tile request that enrols during the hold fills it: 16 | 9 + 7,
+        both full, where the late rule ran 16 | 9 | 7."""
+        eng = self._engine(cls=_GatedEngine, tile_batch=16)
+        first = self._image(5)
+        seven = np.random.rand(1, 240, 48, 1).astype(np.float32)   # 7 x 1 tiles
+        try:
+            before, since, future = self._held_behind_its_first_chunk(eng, first)
+            futures = [future, eng.submit(eng.predict, seven)]
+            eng.wait_for(lambda: eng.pipeline_stats.chunks - before["chunks"] == 2)
+            eng.gate.set()
+            replies = [f.result(timeout=60) for f in futures]
+        finally:
+            eng.gate.set()
+            eng.close()
+        after = TestEngineStages._sums(eng)
+        chunks = self._dispatches(since)
+        assert [(d["closed"], d["rows"], d["capacity"]) for d in chunks] == [
+            ("full", 16, 16), ("full", 16, 16)
+        ]
+        assert [len(d["requests"]) for d in chunks] == [1, 2]
+        assert chunks[0]["held_ms"] == 0 and chunks[1]["held_ms"] > 0
+        assert {k: after[k] - before[k] for k in (
+            "chunks", "chunks_held", "chunks_held_filled", "chunks_closed_late",
+            "rows_executed", "rows_useful",
+        )} == {
+            "chunks": 2, "chunks_held": 1, "chunks_held_filled": 1,
+            "chunks_closed_late": 0, "rows_executed": 32, "rows_useful": 32,
+        }
+        assert after["hold_seconds"] - before["hold_seconds"] == pytest.approx(
+            chunks[1]["held_ms"] / 1e3, abs=1e-9
+        )
+        for reply, x in zip(replies, (first, seven)):
+            np.testing.assert_allclose(
+                reply, eng.predict_serial(x), rtol=0, atol=0
+            )
+
+    def test_a_held_chunk_that_nothing_joins_goes_late_at_its_deadline(self):
+        """Held behind its first chunk, a 25-tile request's 9-row tail
+        goes ``late`` when its deadline comes (brought forward here: the
+        program timed anew at nothing), having taken no rows meanwhile."""
+        eng = self._engine(cls=_GatedEngine, tile_batch=16)
+        first = self._image(5)
+        stream = eng._stream
+        try:
+            before, since, future = self._held_behind_its_first_chunk(eng, first)
+            with stream._cond:
+                stream._device_ns[self.PROGRAM] = 0
+                stream._cond.notify_all()
+            eng.wait_for(lambda: eng.pipeline_stats.chunks - before["chunks"] == 2)
+            eng.gate.set()
+            reply = future.result(timeout=60)
+        finally:
+            eng.gate.set()
+            eng.close()
+        after = TestEngineStages._sums(eng)
+        chunks = self._dispatches(since)
+        assert [(d["closed"], d["rows"]) for d in chunks] == [
+            ("full", 16), ("late", 9)
+        ]
+        assert chunks[0]["held_ms"] == 0 and chunks[1]["held_ms"] > 0
+        assert [after[k] - before[k] for k in (
+            "chunks_held", "chunks_held_filled", "chunks_closed_late"
+        )] == [1, 0, 1]
+        assert after["hold_seconds"] - before["hold_seconds"] == pytest.approx(
+            chunks[1]["held_ms"] / 1e3, abs=1e-9
+        )
+        np.testing.assert_allclose(reply, eng.predict_serial(first), rtol=0, atol=0)
+
+    @pytest.mark.parametrize("case", ["dry", "untimed"])
+    def test_nothing_is_held_where_the_device_is_dry_or_the_program_untimed(
+        self, case
+    ):
+        """``dry``: a lone 9-tile request on an idle engine, its program
+        timed at a minute, goes at once (nothing is in flight).
+        ``untimed``: a 25-tile request's 9-row tail, behind its first 16
+        in flight, whose program no read-back has timed yet, goes at
+        once."""
+        eng = self._engine(cls=_GatedEngine, tile_batch=16)
+        x = self._image(3) if case == "dry" else self._image(5)
+        try:
+            if case == "dry":
+                eng.predict(x)
+                before, since = TestEngineStages._sums(eng), time.time_ns()
+                eng._stream._device_ns[self.PROGRAM] = 60 * 10**9
+                reply = eng.submit(eng.predict, x).result(timeout=30)
+            else:
+                before, since = TestEngineStages._sums(eng), time.time_ns()
+                eng.gate.clear()
+                future = eng.submit(eng.predict, x)
+                eng.wait_for(lambda: eng.pipeline_stats.chunks == 2)
+                assert self.PROGRAM not in eng._stream._device_ns
+                eng.gate.set()
+                reply = future.result(timeout=60)
+        finally:
+            eng.gate.set()
+            eng.close()
+        after = TestEngineStages._sums(eng)
+        chunks = self._dispatches(since)
+        assert [(d["closed"], d["rows"], d["held_ms"]) for d in chunks][-1] == (
+            "late", 9, 0.0
+        )
+        assert [after[k] - before[k] for k in (
+            "chunks_held", "chunks_held_filled", "hold_seconds"
+        )] == [0, 0, 0]
+        np.testing.assert_allclose(reply, eng.predict_serial(x), rtol=0, atol=0)
+
     @staticmethod
     def _dispatches(since):
         """The stream's chunks since then, as their ``engine.dispatch``
@@ -847,6 +977,15 @@ class TestTileStream:
         }
         assert sum(self._closed(before, after).values()) == (
             after["chunks"] - before["chunks"]
+        )
+        # the hold's account: its time is its chunks' ``held_ms``
+        held, filled = (
+            after[k] - before[k] for k in ("chunks_held", "chunks_held_filled")
+        )
+        assert 0 <= filled <= held <= after["chunks"] - before["chunks"]
+        assert held == sum(d["held_ms"] > 0 for d in chunks)
+        assert after["hold_seconds"] - before["hold_seconds"] == pytest.approx(
+            sum(d["held_ms"] for d in chunks) / 1e3, abs=1e-9
         )
         assert after["jobs"] - before["jobs"] == 3
         assert after["job_chunks"] - before["job_chunks"] == sum(
