@@ -833,7 +833,10 @@ class InferenceEngine:
             rows_executed=buf.shape[0],
             rows_useful=n,
         )
-        return InFlight(out, n, dispatch.end_ns, stages=[put, dispatch])
+        return InFlight(
+            out, n, dispatch.end_ns, issue_ns=dispatch.end_ns - put.start_ns,
+            stages=[put, dispatch],
+        )
 
     def _force_chunk(self, flight: InFlight) -> np.ndarray:
         """Block until a dispatched chunk is on the host:

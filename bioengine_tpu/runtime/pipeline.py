@@ -31,23 +31,31 @@ goes, padded, as a lone request's tail always did.
 
 **The device-deadline hold.** The stream times each chunk it reads
 back: its device time, from when it had the device (its dispatch, or
-the previous chunk's end) to its end, kept per program (key and row
-count). The newest in-flight chunk's predicted end is ``max(its
-dispatch, the previous chunk's end)`` plus its program's device time;
-its deadline is that end less the lead: the mean put + dispatch of a
-program call (``PipelineStats``) and ``HOLD_MARGIN_NS``. A partly filled open chunk goes at the first of: no
-chunk in flight (the device is dry), the deadline, or a deadline that
-is unknown (a program not timed yet) or already past. Until then it
-closes ``full``, ``key`` or ``direct`` as before, and the rows of a
-request that enrols meanwhile join it. While it holds, the issuing
-thread reads nothing back (that would block until the in-flight chunk
-ends); it waits for the deadline on the stream's condition. So **a held
-chunk starts on the device no later than the late rule would have
-started it, give or take the lead's error**: its head request loses
-nothing, and the rows that ride with it gain a chunk. A read-back that
-finds its chunk already done has seen no end and teaches no device
-time: the program's time is forgotten, and the next chunk behind it
-goes at once and is timed afresh.
+the previous chunk's end) to its end, and its lead, the put + dispatch
+it took on the issuing thread; it keeps the last ``TIMED_CHUNKS`` of
+each program (key and row count). The newest in-flight chunk's
+predicted end is ``max(its dispatch, the previous chunk's end)`` plus
+the smallest device time its program has kept; the deadline is that end
+less the smallest lead kept and the host-to-device copy that outlasts
+the put call (``H2D_NS_PER_BYTE`` times the open chunk's bytes). A
+reading errs long far more often than short (a compile in the dispatch,
+a pause of the host in the wait, a chunk that found the device idle and
+waited for its rows), and a long one would hold past the device's end:
+so the smallest of the last few is taken, and one long reading is never
+used. A partly filled open chunk goes at the first of: no chunk in
+flight (the device is dry), the deadline, or a deadline that is unknown
+(a program not timed yet) or already past. Until then it closes
+``full``, ``key`` or ``direct`` as before, and the rows of a request
+that enrols meanwhile join it. While it holds, the issuing thread reads
+nothing back (that would block until the in-flight chunk ends); it
+waits for the deadline on the stream's condition. So **a held chunk
+starts on the device no later than the late rule would have started
+it, give or take the lead's error**: its head request loses nothing,
+and the rows that ride with it gain a chunk. A read-back that finds its
+chunk already done has seen no end and teaches no device time: the
+program's readings are forgotten, so a hold that overshot the end does
+not repeat, and the next chunk behind it goes at once and is timed
+afresh.
 
 Every stage is measured once, by ``tracing.stage`` (utils/tracing.py):
 the interval the ``PipelineStats`` sums receive is the one the
@@ -77,12 +85,14 @@ from bioengine_tpu.utils import metrics, tracing
 # host chunks cut ahead of the issuing thread, the open one included:
 # with the in-flight window that is every buffer a stream holds
 PREFETCH = 2
-# a held chunk goes this long before the put + dispatch it needs, so
-# that its rows are on the device as the chunk in flight ends: the
+# the readings of each program that the hold takes its smallest from
+TIMED_CHUNKS = 4
+# a held chunk goes this much earlier for each byte it puts: the
 # host-to-device copy goes on after the put call returns (a 12.6 MB
 # chunk of 16 x 256 x 256 x 3 f32 lands 4.0-5.3 ms after its put call
-# began on a TPU v5e, of which the put + dispatch cover about 1.4)
-HOLD_MARGIN_NS = 4_000_000
+# began on a TPU v5e, of which the put + dispatch cover about 1.4; 4.0 ms
+# over its bytes is 0.32 ns a byte)
+H2D_NS_PER_BYTE = 0.32
 # a read-back that waited less than this found its chunk already done:
 # it saw no end, so it teaches no device time
 FOUND_DONE_NS = 500_000
@@ -295,14 +305,15 @@ class DispatchExecutor:
 @dataclasses.dataclass
 class InFlight:
     """One chunk handed to the device: its output (a future until
-    forced), its useful rows, when the dispatch ended and the wait for
-    it ended (``time.time_ns()``), how long that wait took, and the
-    stages it passed on the issuing thread (put, dispatch, device_wait,
-    d2h)."""
+    forced), its useful rows, when the dispatch ended, how long its put
+    + dispatch took, when the wait for it ended (``time.time_ns()``) and
+    how long that wait took, and the stages it passed on the issuing
+    thread (put, dispatch, device_wait, d2h)."""
 
     out: Any
     n: int
     dispatched_ns: int
+    issue_ns: int = 0
     ready_ns: int = 0
     waited_ns: int = 0
     stages: list = dataclasses.field(default_factory=list)
@@ -408,7 +419,7 @@ class TileStream:
     """The engine's one tile stream and its three threads (module
     docstring). ``dispatch(buf, n, **attrs)`` hands a staged chunk with
     ``n`` useful rows to the device without blocking and returns its
-    ``InFlight``; ``attrs`` go on its ``engine.dispatch`` stage:
+    ``InFlight``, ``issue_ns`` set; ``attrs`` go on its ``engine.dispatch`` stage:
     ``rows``, ``capacity``, ``closed`` (why it closed), ``requests``
     (the ``request_seq`` of each job with rows in it, the first job's
     first) and ``held_ms`` (how long the device-deadline hold kept it).
@@ -456,9 +467,10 @@ class TileStream:
         self._blends: deque = deque()  # read-back chunks for the stitch thread
         self._busy_since_ns = 0     # when the stream last got a job in hand
         self._last_ready_ns = 0
-        # the device time of each program (key, row count), as the
-        # issuing thread last saw one end: the hold's clock
-        self._device_ns: dict[tuple, int] = {}
+        # the last (device time, put + dispatch) readings of each program
+        # (key, row count), as the issuing thread saw its chunks end: the
+        # hold's clock
+        self._timed: dict[tuple, deque] = {}
 
     # ---- the requests' side -------------------------------------------------
 
@@ -682,7 +694,7 @@ class TileStream:
                         # goes late now unless the chunk in flight keeps
                         # the device busy past the lead (module docstring)
                         now = time.time_ns()
-                        deadline = self._deadline(window)
+                        deadline = self._deadline(window, chunk)
                         if deadline is None or now >= deadline:
                             self._open = None
                             chunk.closed = "late"
@@ -721,23 +733,25 @@ class TileStream:
                 except Exception as exc:
                     entry.future.set_exception(exc)
 
-    def _deadline(self, window: deque) -> Optional[int]:
-        """When the open chunk must go for the device not to run dry
+    def _deadline(self, window: deque, chunk: _Chunk) -> Optional[int]:
+        """When the open ``chunk`` must go for the device not to run dry
         behind the chunks in ``window`` (``time.time_ns()``): their
-        predicted end less the lead: the mean put + dispatch of a
-        program call and ``HOLD_MARGIN_NS``. None where nothing is in
-        flight or a program in flight has not been timed yet."""
-        if not window:
-            return None
-        stats = self._stats
-        lead = (stats.put_seconds + stats.dispatch_seconds) / stats.chunks
+        predicted end less the lead, the smallest put + dispatch kept
+        for the newest one's program and the copy of ``chunk``'s bytes
+        (module docstring). None where nothing is in flight or a program
+        in flight has not been timed yet."""
         end = self._last_ready_ns
+        timed = None
         for flying in window:
-            run = self._device_ns.get((flying.key, flying.size))
-            if run is None:
+            timed = self._timed.get((flying.key, flying.size))
+            if not timed:
                 return None
+            run = min(device for device, _ in timed)
             end = max(flying.flight.dispatched_ns, end) + run
-        return end - int(lead * 1e9) - HOLD_MARGIN_NS
+        if timed is None:
+            return None
+        lead = min(issue for _, issue in timed)
+        return end - lead - int(chunk.buf.nbytes * H2D_NS_PER_BYTE)
 
     def _issue(self, chunk: _Chunk, window: deque) -> None:
         rows = chunk.rows
@@ -792,10 +806,13 @@ class TileStream:
             self._last_ready_ns = flight.ready_ns
             program = (chunk.key, chunk.size)
             if flight.waited_ns >= FOUND_DONE_NS:
-                self._device_ns[program] = flight.ready_ns - busy_from
+                self._timed.setdefault(
+                    program, deque(maxlen=TIMED_CHUNKS)
+                ).append((flight.ready_ns - busy_from, flight.issue_ns))
             else:
-                # done before the wait began: an end not seen
-                self._device_ns.pop(program, None)
+                # done before the wait began: an end not seen, and maybe
+                # a hold that overshot it
+                self._timed.pop(program, None)
             self._stats.add(compute_seconds=compute)
             for job, _, n in chunk.segments:
                 job.compute_seconds += compute * n / chunk.rows

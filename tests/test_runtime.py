@@ -1,11 +1,13 @@
 import threading
 import time
+from collections import deque
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bioengine_tpu.runtime import pipeline
 from bioengine_tpu.runtime.buckets import (
     bucket_batch,
     bucket_dim,
@@ -515,9 +517,15 @@ class _GatedEngine(InferenceEngine):
         self.gate = threading.Event()
         self.gate.set()
 
+    # set: every read-back reads as one that found its chunk done
+    found_done = False
+
     def _force_chunk(self, flight):
         self.gate.wait(30)
-        return super()._force_chunk(flight)
+        host = super()._force_chunk(flight)
+        if self.found_done:
+            flight.waited_ns = 0
+        return host
 
     def served_together(self, inputs, plug):
         """Futures of ``inputs``' predictions, all enrolled while
@@ -782,8 +790,13 @@ class TestTileStream:
             )
 
     # the program of the tests' 1-channel tiles (48 px, bucketed to 64)
-    # at 16 rows, as the stream keys its device times
+    # at 16 rows, as the stream keys its readings
     PROGRAM = (((64, 64, 1), "<f4"), 16)
+
+    @staticmethod
+    def _timed(*readings):
+        """A program's kept (device time, put + dispatch) readings."""
+        return deque(readings, maxlen=pipeline.TIMED_CHUNKS)
 
     def _held_behind_its_first_chunk(self, eng, first):
         """``first`` (25 tiles) on an engine whose program is timed at a
@@ -792,9 +805,9 @@ class TestTileStream:
         chunk for their deadline. Returns the stats before and the time
         since."""
         stream = eng._stream
-        eng.predict(self._image(3))     # compile, time a put + dispatch
+        eng.predict(self._image(3))     # compile the one program
         before, since = TestEngineStages._sums(eng), time.time_ns()
-        stream._device_ns[self.PROGRAM] = 60 * 10**9
+        stream._timed[self.PROGRAM] = self._timed((60 * 10**9, 0))
         eng.gate.clear()
         future = eng.submit(eng.predict, first)
         eng.wait_for(lambda: stream._open is not None and stream._open.held_ns)
@@ -844,14 +857,14 @@ class TestTileStream:
     def test_a_held_chunk_that_nothing_joins_goes_late_at_its_deadline(self):
         """Held behind its first chunk, a 25-tile request's 9-row tail
         goes ``late`` when its deadline comes (brought forward here: the
-        program timed anew at nothing), having taken no rows meanwhile."""
+        program read anew at nothing), having taken no rows meanwhile."""
         eng = self._engine(cls=_GatedEngine, tile_batch=16)
         first = self._image(5)
         stream = eng._stream
         try:
             before, since, future = self._held_behind_its_first_chunk(eng, first)
             with stream._cond:
-                stream._device_ns[self.PROGRAM] = 0
+                stream._timed[self.PROGRAM].append((0, 0))
                 stream._cond.notify_all()
             eng.wait_for(lambda: eng.pipeline_stats.chunks - before["chunks"] == 2)
             eng.gate.set()
@@ -888,14 +901,14 @@ class TestTileStream:
             if case == "dry":
                 eng.predict(x)
                 before, since = TestEngineStages._sums(eng), time.time_ns()
-                eng._stream._device_ns[self.PROGRAM] = 60 * 10**9
+                eng._stream._timed[self.PROGRAM] = self._timed((60 * 10**9, 0))
                 reply = eng.submit(eng.predict, x).result(timeout=30)
             else:
                 before, since = TestEngineStages._sums(eng), time.time_ns()
                 eng.gate.clear()
                 future = eng.submit(eng.predict, x)
                 eng.wait_for(lambda: eng.pipeline_stats.chunks == 2)
-                assert self.PROGRAM not in eng._stream._device_ns
+                assert self.PROGRAM not in eng._stream._timed
                 eng.gate.set()
                 reply = future.result(timeout=60)
         finally:
@@ -910,6 +923,84 @@ class TestTileStream:
             "chunks_held", "chunks_held_filled", "hold_seconds"
         )] == [0, 0, 0]
         np.testing.assert_allclose(reply, eng.predict_serial(x), rtol=0, atol=0)
+
+    def test_a_read_back_that_finds_its_chunk_done_forgets_the_program(self):
+        """A held chunk filled and sent, its read-backs find their chunks
+        done: they saw no end, so the program's readings are forgotten,
+        and the next request's 9-row tail, behind its first 16 in
+        flight, goes at once, late and unheld."""
+        eng = self._engine(cls=_GatedEngine, tile_batch=16)
+        first, third = self._image(5), self._image(5)
+        seven = np.random.rand(1, 240, 48, 1).astype(np.float32)   # 7 x 1 tiles
+        stream = eng._stream
+        try:
+            before, since, future = self._held_behind_its_first_chunk(eng, first)
+            eng.found_done = True
+            futures = [future, eng.submit(eng.predict, seven)]
+            eng.wait_for(lambda: eng.pipeline_stats.chunks - before["chunks"] == 2)
+            eng.gate.set()
+            for f in futures:
+                f.result(timeout=60)
+            assert self.PROGRAM not in stream._timed
+            eng.gate.clear()
+            futures.append(eng.submit(eng.predict, third))
+            eng.wait_for(lambda: eng.pipeline_stats.chunks - before["chunks"] == 4)
+            eng.gate.set()
+            replies = [f.result(timeout=60) for f in futures]
+        finally:
+            eng.gate.set()
+            eng.close()
+        after = TestEngineStages._sums(eng)
+        chunks = self._dispatches(since)
+        assert [(d["closed"], d["rows"], d["held_ms"] > 0) for d in chunks] == [
+            ("full", 16, False), ("full", 16, True),
+            ("full", 16, False), ("late", 9, False),
+        ]
+        assert [after[k] - before[k] for k in (
+            "chunks_held", "chunks_held_filled", "chunks_closed_late"
+        )] == [1, 1, 1]
+        for reply, x in zip(replies, (first, seven, third)):
+            np.testing.assert_allclose(
+                reply, eng.predict_serial(x), rtol=0, atol=0
+            )
+
+    def test_the_deadline_is_the_end_in_flight_less_the_lead(self):
+        """Known answers of ``TileStream._deadline``: the chunks in
+        flight end one after another, each after the smallest device
+        time its program kept, and the open chunk goes the smallest put
+        + dispatch kept for the newest one's program and its bytes' copy
+        earlier. A long reading (a host pause in the wait, a compile in
+        the dispatch) beside shorter ones is never used."""
+        eng = self._engine(tile_batch=16)
+        stream = eng._stream
+        ms = 10**6
+        key = self.PROGRAM[0]
+
+        def chunk(size=16, dispatched_ns=0):
+            c = pipeline._Chunk(key, np.zeros((16, 64, 64, 1), np.float32))
+            c.size = size
+            c.flight = pipeline.InFlight(None, size, dispatched_ns)
+            return c
+
+        opened = chunk()
+        copy = int(16 * 64 * 64 * 4 * pipeline.H2D_NS_PER_BYTE)
+        try:
+            stream._last_ready_ns = 3 * ms
+            stream._timed[(key, 16)] = self._timed(
+                (10 * ms, 2 * ms), (50 * ms, 1_500_000), (12 * ms, 30 * ms)
+            )
+            one = deque([chunk(dispatched_ns=1 * ms)])
+            assert stream._deadline(one, opened) == 3 * ms + 10 * ms - 1_500_000 - copy
+            one[0].flight.dispatched_ns = 5 * ms    # dispatched to a dry device
+            assert stream._deadline(one, opened) == 5 * ms + 10 * ms - 1_500_000 - copy
+            stream._timed[(key, 8)] = self._timed((5 * ms, 1 * ms))
+            two = deque([chunk(dispatched_ns=1 * ms), chunk(8, 4 * ms)])
+            assert stream._deadline(two, opened) == 13 * ms + 5 * ms - 1 * ms - copy
+            assert stream._deadline(deque(), opened) is None    # the device is dry
+            two.append(chunk(4, 6 * ms))                        # never timed
+            assert stream._deadline(two, opened) is None
+        finally:
+            eng.close()
 
     @staticmethod
     def _dispatches(since):
